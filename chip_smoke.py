@@ -1,0 +1,552 @@
+"""chip_smoke.py — the quickest proof the system still starts on the chip.
+
+One process, plain ``python chip_smoke.py`` from the root of a checkout, on
+a machine with a TPU. It drives the two main paths once through the entry
+points a user calls, at the full width of models the repo ships (random
+weights from a seed), and checks what comes out by the repo's own means:
+
+  P0 device     platform must be ``tpu``; versions, device_kind, peak table,
+                compile-cache directory
+  P1 trainer    ResNet-50 224x224 b64 bf16 through ``net.fit(iterator)``
+  P2 server     the ``lm_serve`` GPT over the socket: KerasServer +
+                KerasClient.generate against singleton ``greedy_generate``
+  P3 kernels    char-LSTM and GPT trainers with the compiled Pallas kernels
+                (Mosaic custom call present in the lowered step) and kernel
+                vs XLA-reference parity at aligned and unaligned shapes
+  P4 multichip  ResNet-50 over ``ParallelTrainer`` on four chips, when the
+                machine has them
+
+Exit 0 only if every phase passed; the last line of stdout is then
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
+With no accelerator the script exits non-zero and prints no result.
+
+``--dry-cpu`` is for the CPU sandbox and the tier-1 test: tiny shapes,
+Pallas kernels in interpret mode, every line prefixed ``[DRY-CPU]`` and no
+result line. It is never the default and proves nothing about the chip.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import sys
+import threading
+import time
+import traceback
+
+import numpy as np
+
+DRY = "--dry-cpu" in sys.argv[1:]
+TAG = "[DRY-CPU] " if DRY else ""
+OUT_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                       "chiprun_out", "chip_smoke")
+
+
+def say(msg: str) -> None:
+    print(f"{TAG}{msg}", flush=True)
+
+
+def check(cond: bool, what: str) -> None:
+    if not cond:
+        raise AssertionError(what)
+
+
+# ---------------------------------------------------------------------------
+# shared: train a few steps through the public fit(iterator)
+# ---------------------------------------------------------------------------
+
+def _compiles() -> float:
+    from deeplearning4j_tpu.profiling.metrics import get_registry
+    return get_registry().counter("jax_compile_total").value
+
+
+def _fit_steps(net, batches, fit, warmup: int = 2) -> dict:
+    """``fit(iterator)`` over ``warmup`` batches, then over the rest with
+    the compile counter watched. Loss per step comes from a listener (a
+    host read of the loss ends each step, so the times are whole steps)."""
+    import jax
+
+    from deeplearning4j_tpu.datasets.iterator import ListDataSetIterator
+    from deeplearning4j_tpu.optimize.listeners import (
+        CollectScoresIterationListener)
+
+    scores = CollectScoresIterationListener()
+    net.set_listeners(scores)
+    t0 = time.perf_counter()
+    fit(ListDataSetIterator(batches[:warmup]))
+    jax.block_until_ready(net.params)
+    warm_s = time.perf_counter() - t0
+    compiles = _compiles()
+    t0 = time.perf_counter()
+    fit(ListDataSetIterator(batches[warmup:]))
+    jax.block_until_ready(net.params)
+    step_ms = 1e3 * (time.perf_counter() - t0) / (len(batches) - warmup)
+    losses = [s for _, s in scores.scores]
+    check(len(losses) == len(batches), f"{len(losses)} steps ran, "
+          f"{len(batches)} batches fed")
+    check(all(np.isfinite(losses)), f"non-finite loss: {losses}")
+    check(_compiles() == compiles,
+          f"{_compiles() - compiles:.0f} compilation(s) after warm-up")
+    return {"losses": [round(float(l), 4) for l in losses],
+            "warmup_s": round(warm_s, 1), "step_ms": round(step_ms, 2)}
+
+
+def _prefetched(net, dtype):
+    """``net.fit`` fed through the device-prefetch iterator."""
+    from deeplearning4j_tpu.datasets.iterator import DevicePrefetchIterator
+    return lambda it: net.fit(DevicePrefetchIterator(it, dtype=dtype))
+
+
+def _image_batches(n, batch, side, classes, dtype=np.float32, seed=0):
+    from deeplearning4j_tpu.datasets.dataset import DataSet
+    rng = np.random.default_rng(seed)
+    out = [DataSet(
+        rng.normal(size=(batch, side, side, 3)).astype(dtype),
+        np.eye(classes, dtype=dtype)[rng.integers(0, classes, batch)])
+        for _ in range(2)]
+    return [out[i % 2] for i in range(n)]
+
+
+def _char_batches(n, batch, seq_len, vocab, seed=0):
+    """One-hot char windows with next-char targets."""
+    from deeplearning4j_tpu.datasets.dataset import DataSet
+    rng = np.random.default_rng(seed)
+    eye = np.eye(vocab, dtype=np.float32)
+    out = []
+    for _ in range(n):
+        ids = rng.integers(0, vocab, (batch, seq_len + 1))
+        out.append(DataSet(eye[ids[:, :-1]], eye[ids[:, 1:]]))
+    return out
+
+
+# ---------------------------------------------------------------------------
+# P0 device
+# ---------------------------------------------------------------------------
+
+def p0_device() -> dict:
+    if DRY:
+        os.environ["DL4J_TPU_PALLAS"] = "interpret"
+    elif "DL4J_TPU_PALLAS" in os.environ:
+        raise SystemExit("chip_smoke: DL4J_TPU_PALLAS is set; the smoke "
+                         "must pick the kernel path from the platform")
+    from importlib import metadata
+
+    import jax
+    import jaxlib
+
+    from deeplearning4j_tpu.native_loader import load_native
+    from deeplearning4j_tpu.profiling import CompileWatcher
+    from deeplearning4j_tpu.profiling.cost import peak_flops
+    from deeplearning4j_tpu.util.compile_cache import use_compile_cache
+
+    cache_dir = use_compile_cache()
+    devices = jax.devices()
+    dev = devices[0]
+    if dev.platform != "tpu" and not DRY:
+        raise SystemExit(f"chip_smoke: no TPU (jax found {len(devices)}x "
+                         f"{dev.platform}); nothing was run")
+    try:
+        libtpu = metadata.version("libtpu")
+    except metadata.PackageNotFoundError:
+        libtpu = "absent"
+    peak = peak_flops(dev.device_kind)   # unknown accelerator: raises
+    CompileWatcher().install()
+    native = "built" if load_native("dataloader") is not None else \
+        "absent (pure-Python readers)"
+    say(f"P0 device: platform={dev.platform} device_kind={dev.device_kind} "
+        f"count={len(devices)} jax={jax.__version__} "
+        f"jaxlib={jaxlib.__version__} libtpu={libtpu} peak_flops={peak} "
+        f"compile_cache={cache_dir} native_lib={native}")
+    return {"platform": dev.platform, "kind": dev.device_kind,
+            "count": len(devices)}
+
+
+# ---------------------------------------------------------------------------
+# P1 trainer: ResNet-50, the north-star path
+# ---------------------------------------------------------------------------
+
+def _resnet():
+    import jax
+
+    from deeplearning4j_tpu.models.resnet import resnet50
+    from deeplearning4j_tpu.nn.graph import ComputationGraph
+    side = 32 if DRY else 224
+    t0 = time.perf_counter()
+    net = ComputationGraph(resnet50(
+        height=side, width=side, dtype="bfloat16", updater="nesterovs",
+        learning_rate=0.1)).init()
+    jax.block_until_ready(net.params)
+    return side, net, round(time.perf_counter() - t0, 1)
+
+
+def p1_trainer() -> dict:
+    import jax
+    side, net, init_s = _resnet()
+    batch = 2 if DRY else 64
+    rec = _fit_steps(net, _image_batches(5, batch, side, 1000),
+                     _prefetched(net, "bfloat16"))
+    rec["init_s"] = init_s
+    dev = jax.devices()[0]
+    check(all(leaf.devices() == {dev}
+              for leaf in jax.tree_util.tree_leaves(net.params)),
+          f"parameters not resident on {dev}")
+    rec["memory_stats"] = dev.memory_stats() or {}
+    rec["peak_bytes_in_use"] = rec["memory_stats"].get("peak_bytes_in_use")
+    check(DRY or rec["peak_bytes_in_use"], "device reports no peak memory")
+    say(f"P1 trainer: ResNet-50 {side}x{side} b{batch} bf16 via net.fit — "
+        f"losses {rec['losses']}, init {init_s}s, warm-up "
+        f"{rec['warmup_s']}s, step "
+        f"{rec['step_ms']} ms, 0 compiles after warm-up, params on {dev}, "
+        f"peak_bytes_in_use={rec['peak_bytes_in_use']}")
+    return rec
+
+
+# ---------------------------------------------------------------------------
+# P2 token server
+# ---------------------------------------------------------------------------
+
+def p2_server() -> dict:
+    from deeplearning4j_tpu.analysis.memory import default_kv_page_len
+    from deeplearning4j_tpu.keras.server import KerasClient, KerasServer
+    from deeplearning4j_tpu.models.gpt import gpt_decoder, greedy_generate
+    from deeplearning4j_tpu.nn.graph import ComputationGraph
+    from deeplearning4j_tpu.profiling.metrics import get_registry
+    from deeplearning4j_tpu.util.serializer import ModelSerializer
+
+    V, L, width = (13, 16, dict(d_model=16, n_heads=2, n_layers=2)) if DRY \
+        else (64, 128, dict(d_model=128, n_heads=4, n_layers=4))
+    net = ComputationGraph(gpt_decoder(V, L, seed=11, **width)).init()
+    max_new, page = L // 4, default_kv_page_len(L)
+    rng = np.random.default_rng(9)
+    draw = lambda n: rng.integers(0, V, n).tolist()
+    prefix = draw(2 * page)              # two full KV pages, shared
+    short, mid, tail = L // 8, L // 4 + L // 16, page // 4
+    # six concurrent requests of three prompt lengths, two sharing the
+    # page-aligned prefix, one sampled; then the two repeats
+    prompts = [prefix + draw(tail), prefix + draw(tail), draw(short),
+               draw(mid), draw(mid), draw(short)]
+    sampling = {"temperature": 0.8, "seed": 7}
+    wave1 = [(p, None) for p in prompts[:5]] + [(prompts[5], sampling)]
+    wave2 = [(prompts[3], None), (prompts[5], sampling)]
+    refs = [greedy_generate(net, p, max_new) for p in prompts[:5]]
+
+    baseline = set(threading.enumerate())
+    os.makedirs(OUT_DIR, exist_ok=True)
+    model = os.path.join(OUT_DIR, "gpt_serve.zip")
+    ModelSerializer.write_model(net, model)
+    srv = KerasServer(max_concurrency=8, queue_depth=16,
+                      max_batch=4 if DRY else 16)
+    answers, errors = {}, []
+
+    def ask(i, prompt, sampling, delay_s):
+        time.sleep(delay_s)
+        cli = KerasClient(srv.host, srv.port)
+        try:
+            kw = {"sampling": sampling} if sampling else {}
+            answers[i] = cli.generate(prompt, max_new, model=model,
+                                      **kw)["tokens"]
+        except Exception as e:  # noqa: BLE001 — reported by the phase
+            errors.append(f"request {i}: {type(e).__name__}: {e}")
+        finally:
+            cli.close()
+
+    def wave(reqs, first, stagger_s):
+        threads = [threading.Thread(
+            target=ask, args=(first + k, p, s, stagger_s * k), daemon=True)
+            for k, (p, s) in enumerate(reqs)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(600.0)
+        check(not any(t.is_alive() for t in threads), "a client hung")
+
+    try:
+        # staggered, so the first prefix owner registers its pages
+        # before its twin is admitted
+        wave(wave1, 0, 0.2)
+        prefills = srv._gen.stats()["prefill_steps"]
+        wave(wave2, len(wave1), 0.0)
+        stats = srv._gen.stats()
+    finally:
+        srv.drain(grace_s=5.0)
+    check(not errors, "; ".join(errors))
+    for i, ref in enumerate(refs):
+        check(answers[i] == ref, f"request {i}: served {answers[i]} != "
+              f"greedy_generate {ref}")
+    check(answers[6] == refs[3], "the exact repeat answered differently")
+    check(answers[5] == answers[7] and len(answers[5]) == max_new,
+          f"sampled pair differs: {answers[5]} vs {answers[7]}")
+    decode_steps = get_registry().counter("serving_decode_steps_total").value
+    check(decode_steps > 0, "no decode step ran")
+    check(stats["kv_pages_shared"] >= 2,
+          f"kv_pages_shared={stats['kv_pages_shared']}")
+    check(stats["prefill_steps"] == prefills,
+          f"repeats prefilled again ({prefills} -> "
+          f"{stats['prefill_steps']})")
+    deadline = time.monotonic() + 10.0
+    while set(threading.enumerate()) - baseline and \
+            time.monotonic() < deadline:
+        time.sleep(0.05)
+    leaked = set(threading.enumerate()) - baseline
+    check(not leaked,
+          f"threads left after drain: {sorted(t.name for t in leaked)}")
+    rec = {"requests": len(answers), "decode_steps": int(decode_steps),
+           "kv_pages_shared": stats["kv_pages_shared"],
+           "prefill_steps": stats["prefill_steps"],
+           "compiles": stats["compiles"], "compile_s": stats["compile_s"]}
+    say(f"P2 server: gpt_decoder({V}, {L}, {width}) over KerasServer — "
+        f"8 requests, 5 greedy == greedy_generate, repeat identical, "
+        f"sampled pair identical; {rec}; threads back to baseline")
+    return rec
+
+
+# ---------------------------------------------------------------------------
+# P3 kernels: parity against the XLA reference, then the trainers
+# ---------------------------------------------------------------------------
+
+def _parity(name, kernel, reference, args, cot, tol) -> dict:
+    """Kernel against its XLA reference, outputs and gradients of
+    ``sum(first output * cot)`` w.r.t. every argument, max abs error.
+
+    The reference runs at HIGHEST matmul precision. The kernel runs
+    twice. Traced under ``default_matmul_precision("highest")`` its dots
+    lower to Mosaic's fp32 contract precision, and it must meet ``tol``
+    — the logic check: a real bug (gate order, stale carry, wrong mask)
+    is O(0.1-1). At the default precision — what the trainers run —
+    Mosaic's f32 dot is a bf16 MXU pass, exactly like XLA's own default
+    on a TPU (measured on the v5e, jax 0.9.0: both drift ~1e-2 from the
+    HIGHEST reference), so there the bound is relative: no worse than
+    4x what XLA's default-precision run of the reference costs."""
+    import jax
+    import jax.numpy as jnp
+
+    def run(fn, precision):
+        loss = lambda *a: jnp.sum(fn(*a)[0] * cot)
+        with jax.default_matmul_precision(precision):
+            return (*jax.jit(fn)(*args), *jax.jit(
+                jax.grad(loss, argnums=tuple(range(len(args)))))(*args))
+
+    def err(got, ref):
+        return max(float(jnp.max(jnp.abs(a - b))) for a, b in zip(got, ref))
+
+    ref = run(reference, "highest")
+    rec = {"fp32": err(run(kernel, "highest"), ref),
+           "default": err(run(kernel, "default"), ref),
+           "xla_default": err(run(reference, "default"), ref)}
+    check(rec["fp32"] < tol, f"{name}: max_abs_err={rec['fp32']:.3e} at "
+          f"fp32 contract precision (tol {tol:.1e})")
+    check(rec["default"] < 4 * rec["xla_default"] + tol,
+          f"{name}: max_abs_err={rec['default']:.3e} at default precision "
+          f"against {rec['xla_default']:.3e} for XLA's own default")
+    return {k: float(f"{v:.2e}") for k, v in rec.items()}
+
+
+def lstm_parity(B, T, F, H) -> dict:
+    """Pallas fused LSTM (forward and custom-VJP backward) against a
+    ``lax.scan`` of the same cell. Rounding drift accumulates along the
+    recurrence, hence a T-proportional bound."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.ops.pallas_kernels import fused_lstm
+
+    rng = np.random.default_rng(7)
+    shapes = ((B, T, F), (F, 4 * H), (H, 4 * H), (4 * H,), (B, H), (B, H))
+    args = [jnp.asarray(rng.normal(size=s).astype(np.float32) * 0.1)
+            for s in shapes]
+    cot = jnp.asarray(rng.normal(size=(B, T, H)).astype(np.float32) * 0.1)
+
+    def kernel(x, w, rw, b, h0, c0):
+        return fused_lstm(x, w, rw, b, None, h0, c0, forget_bias=1.0,
+                          interpret=DRY)
+
+    def scan_ref(x, w, rw, b, h0, c0):
+        xz = (x.reshape(B * T, F) @ w + b).reshape(B, T, 4 * H)
+
+        def step(carry, z_t):
+            h, c = carry
+            z = z_t + h @ rw
+            i = jax.nn.sigmoid(z[:, :H])
+            f = jax.nn.sigmoid(z[:, H:2 * H] + 1.0)
+            g = jnp.tanh(z[:, 2 * H:3 * H])
+            o = jax.nn.sigmoid(z[:, 3 * H:])
+            c2 = f * c + i * g
+            h2 = o * jnp.tanh(c2)
+            return (h2, c2), h2
+
+        (hT, cT), ys = jax.lax.scan(step, (h0, c0), jnp.swapaxes(xz, 0, 1))
+        return jnp.swapaxes(ys, 0, 1), hT, cT
+
+    return _parity(f"fused_lstm B={B} T={T} F={F} H={H}", kernel, scan_ref,
+                   args, cot, tol=max(1e-3, 2.5e-4 * T))
+
+
+def attention_parity(B, H, T, D) -> dict:
+    """Pallas flash attention (forward and FA2 backward, causal) against
+    ``attention_reference``."""
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.nn.layers.attention import attention_reference
+    from deeplearning4j_tpu.ops.pallas_attention import flash_attention
+
+    rng = np.random.default_rng(11)
+    q, k, v, cot = (jnp.asarray(rng.normal(size=(B, H, T, D))
+                                .astype(np.float32)) for _ in range(4))
+    return _parity(
+        f"flash_attention B={B} H={H} T={T} D={D}",
+        lambda q, k, v: (flash_attention(q, k, v, causal=True,
+                                         interpret=DRY),),
+        lambda q, k, v: (attention_reference(q, k, v, causal=True),),
+        (q, k, v), cot, tol=5e-4)
+
+
+def _lowered_step_text(net, batch) -> str:
+    from deeplearning4j_tpu.profiling.cost import step_example_args
+    return net._train_step_fn.lower(
+        *step_example_args(net, batch)).as_text()
+
+
+def _kernel_trainer(name, net, batches) -> dict:
+    from deeplearning4j_tpu.profiling.metrics import get_registry
+    rec = _fit_steps(net, batches, _prefetched(net, None))
+    calls = _lowered_step_text(net, batches[0]).count("tpu_custom_call")
+    check(DRY or calls > 0, f"{name}: no Mosaic custom call in the lowered "
+          "train step — the XLA path stood in for the kernel")
+    gated = get_registry().labeled_counter(
+        "pallas_gate_fallbacks_total").value
+    check(gated == 0, f"{name}: a shape gate sent {gated:.0f} layer(s) to "
+          "the XLA path")
+    rec["tpu_custom_calls"] = calls
+    say(f"P3 kernels: {name} via net.fit — losses {rec['losses']}, step "
+        f"{rec['step_ms']} ms, {calls} tpu_custom_call in the lowered "
+        "train step" + (" (interpret mode: none expected)" if DRY else ""))
+    return rec
+
+
+def p3_kernels() -> dict:
+    from deeplearning4j_tpu import InputType, NeuralNetConfiguration
+    from deeplearning4j_tpu.models.gpt import gpt_decoder
+    from deeplearning4j_tpu.nn.graph import ComputationGraph
+    from deeplearning4j_tpu.nn.layers import GravesLSTM, RnnOutputLayer
+    from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+
+    # (B, T, F, H) and (B, H, T, D): tile-aligned, then the pad path
+    parity = {f"fused_lstm{shape}": lstm_parity(*shape)
+              for shape in ((8, 16, 128, 128), (6, 16, 72, 200))}
+    parity.update({f"flash_attention{shape}": attention_parity(*shape)
+                   for shape in ((2, 2, 256, 128), (2, 2, 40, 24))})
+    rec = {"parity_max_abs_err": parity}
+    say("P3 kernels: parity vs HIGHEST-precision XLA reference, outputs "
+        f"and gradients, max abs err {parity}")
+
+    hidden, T, K, B = (32, 8, 16, 4) if DRY else (256, 64, 96, 32)
+    lstm = MultiLayerNetwork(
+        NeuralNetConfiguration.builder().seed(7)
+        .updater("rmsprop", learning_rate=1e-3).weight_init("xavier").list()
+        .layer(GravesLSTM(n_out=hidden, activation="tanh"))
+        .layer(GravesLSTM(n_out=hidden, activation="tanh"))
+        .layer(RnnOutputLayer(n_out=K, activation="softmax", loss="mcxent"))
+        .set_input_type(InputType.recurrent(K, T)).build()).init()
+    rec["char_lstm"] = _kernel_trainer(
+        f"char-LSTM 2xGravesLSTM({hidden}) T={T} b{B} f32", lstm,
+        _char_batches(5, B, T, K))
+
+    V, T, B, width = (16, 8, 4, dict(d_model=32, n_heads=2, n_layers=2)) \
+        if DRY else (96, 128, 32, dict(d_model=256, n_heads=8, n_layers=4))
+    gpt = ComputationGraph(gpt_decoder(V, T, seed=7, **width)).init()
+    rec["gpt"] = _kernel_trainer(
+        f"gpt_decoder({V}, {T}, {width}) b{B} f32", gpt,
+        _char_batches(5, B, T, V))
+    return rec
+
+
+# ---------------------------------------------------------------------------
+# P4 four chips: data parallelism over ICI
+# ---------------------------------------------------------------------------
+
+def p4_multichip() -> dict:
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.parallel.mesh import MeshContext
+    from deeplearning4j_tpu.parallel.trainer import ParallelTrainer
+
+    devices = jax.devices()
+    if len(devices) < 4:
+        say(f"multichip: not run, {len(devices)} device")
+        return {"run": False}
+    side, net, _ = _resnet()
+    trainer = ParallelTrainer(net, MeshContext.create(n_data=4))
+    batch = 8 if DRY else 256
+    batches = _image_batches(5, batch, side, 1000, dtype=jnp.bfloat16)
+    rec = _fit_steps(net, batches, trainer.fit)
+    feats = trainer.mesh.shard_batch(jnp.asarray(batches[0].features))
+    check(feats.sharding.spec[0] == "data"
+          and len({s.device for s in feats.addressable_shards}) == 4
+          and feats.addressable_shards[0].data.shape[0] == batch // 4,
+          f"feature batch not sharded over 'data': {feats.sharding}")
+    four = set(devices[:4])
+    for leaf in jax.tree_util.tree_leaves(net.params):
+        check({s.device for s in leaf.addressable_shards} == four,
+              f"a parameter has no live shard on every chip: {leaf.sharding}")
+    in_use = {str(d): (d.memory_stats() or {}).get("bytes_in_use")
+              for d in devices[:4]}
+    check(DRY or all(in_use.values()), f"idle chip: {in_use}")
+    rec["bytes_in_use"] = in_use
+    say(f"P4 multichip: ResNet-50 {side}x{side} global b{batch} bf16 via "
+        f"ParallelTrainer(n_data=4) — losses {rec['losses']}, step "
+        f"{rec['step_ms']} ms, batch sharded over 'data' "
+        f"({batch // 4}/chip), parameter shards live on 4 chips, "
+        f"bytes_in_use {in_use}")
+    return rec
+
+
+# ---------------------------------------------------------------------------
+
+PHASES = (("P1 trainer", p1_trainer), ("P2 server", p2_server),
+          ("P3 kernels", p3_kernels), ("P4 multichip", p4_multichip))
+
+
+def main() -> int:
+    from deeplearning4j_tpu.profiling.metrics import get_registry
+
+    t_start = time.perf_counter()
+    device = p0_device()          # no TPU: SystemExit, nothing printed after
+    summary = {"device": device}
+    failed = []
+    for name, phase in PHASES:
+        t0 = time.perf_counter()
+        try:
+            summary[name] = phase()
+        except Exception:  # noqa: BLE001 — a failed phase fails the run
+            traceback.print_exc()
+            last = traceback.format_exc().strip().splitlines()[-1]
+            say(f"{name}: FAILED ({last[:300]})")
+            failed.append(name)
+        say(f"{name}: {time.perf_counter() - t0:.1f}s")
+    reg = get_registry()
+    summary["compile"] = {
+        "compiles": reg.counter("jax_compile_total").value,
+        "compile_s": round(reg.counter("jax_compile_seconds_total").value, 1),
+        "cache_hits": reg.counter("jax_compile_cache_hits_total").value,
+        "cache_misses": reg.counter("jax_compile_cache_misses_total").value,
+    }
+    summary["wall_s"] = round(time.perf_counter() - t_start, 1)
+    summary["failed"] = failed
+    say(f"compile: {summary['compile']}; wall {summary['wall_s']}s")
+    os.makedirs(OUT_DIR, exist_ok=True)
+    with open(os.path.join(OUT_DIR, "summary.json"), "w") as f:
+        json.dump(summary, f, indent=1, sort_keys=True)
+        f.write("\n")
+    if failed:
+        say(f"chip_smoke FAILED: {failed}")
+        return 1
+    if DRY:
+        say("dry run passed on the CPU; this says nothing about the chip")
+        return 0
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

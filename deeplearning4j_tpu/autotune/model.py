@@ -38,7 +38,7 @@ Two census sources feed it:
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import Dict, List, Optional, Tuple
 
 #: fwd+bwd+update FLOPs per parameter per example for the config-only
@@ -82,22 +82,20 @@ class Hardware:
     @staticmethod
     def detect() -> "Hardware":
         """The current backend's constants. TPU ICI is ~100 GB/s per
-        chip per direction on recent generations; the CPU 'mesh' of
-        forced host devices exchanges via plain memcpy, modeled at host
-        memory bandwidth (50 GB/s) — collectives stay visible in the
-        ranking but cannot dominate it the way a real wire would."""
+        chip per direction on recent generations. The CPU backend gets
+        the :meth:`reference` profile — a ranking constant, not a peak:
+        its 'mesh' of forced host devices exchanges via plain memcpy,
+        modeled at host memory bandwidth so collectives stay visible in
+        the ranking but cannot dominate it the way a real wire would."""
+        import jax
+
         from deeplearning4j_tpu.profiling.cost import peak_flops
-        try:
-            import jax
-            dev = jax.devices()[0]
-            kind = str(getattr(dev, "device_kind", dev.platform))
-            accel = dev.platform not in ("cpu",)
-        except Exception:  # noqa: BLE001 — model must work chip-less
-            kind, accel = "cpu", False
-        return Hardware(
-            peak_flops=peak_flops(kind) or 1e12,
-            ici_bytes_per_s=100e9 if accel else 50e9,
-            is_accelerator=accel, device_kind=kind)
+        dev = jax.devices()[0]
+        kind = str(dev.device_kind)
+        if dev.platform == "cpu":
+            return replace(Hardware.reference(), device_kind=kind)
+        return Hardware(peak_flops=peak_flops(kind), ici_bytes_per_s=100e9,
+                        is_accelerator=True, device_kind=kind)
 
     @staticmethod
     def reference() -> "Hardware":

@@ -11,7 +11,7 @@ remaining job is validating **custom gradients** (Pallas kernels with
 custom_vjp, hand-coded CD gradients, masking/loss edge semantics) and
 guarding against layer-math regressions. TPU f32 is too noisy for ε=1e-6
 (SURVEY §7 hard part 4), so checks run on CPU under
-``jax.experimental.enable_x64`` exactly as the reference runs f64 on CPU.
+``jax.enable_x64`` exactly as the reference runs f64 on CPU.
 """
 
 from __future__ import annotations
@@ -22,10 +22,7 @@ from typing import Optional
 import jax
 import numpy as np
 
-try:
-    enable_x64 = jax.enable_x64
-except AttributeError:  # jax < 0.5 ships it under experimental
-    from jax.experimental import enable_x64
+enable_x64 = jax.enable_x64  # re-exported: tests scope f64 with it
 
 logger = logging.getLogger("deeplearning4j_tpu")
 

@@ -237,8 +237,13 @@ class SelfAttentionLayer(BaseLayerConf):
         # MXU-native flash attention when the Pallas kernel applies
         from deeplearning4j_tpu.ops.pallas_attention import (
             attention_mode, flash_attention, flash_ok)
+        from deeplearning4j_tpu.ops.pallas_kernels import count_gate_fallback
         amode = attention_mode()
-        if amode != "off" and flash_ok(x.shape[1], self.head_dim):
+        use_flash = amode != "off" and flash_ok(
+            x.shape[1], self.head_dim, q.dtype.itemsize)
+        if amode != "off" and not use_flash:
+            count_gate_fallback(self, "flash_attention")
+        if use_flash:
             out = flash_attention(q, k, v, causal=self.causal,
                                   kv_mask=mask,
                                   interpret=amode == "interpret")

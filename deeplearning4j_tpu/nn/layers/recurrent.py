@@ -130,26 +130,22 @@ class LSTM(BaseLayerConf):
         ref: ConvolutionLayer.java:55-77): use the Pallas fused kernel when
         the configuration matches what the kernel hardcodes.
 
-        Non-tile-aligned H/B no longer fall back to scan: ``fused_lstm``
+        Non-tile-aligned H/B do not fall back to scan: ``fused_lstm``
         pads to the (8, 128) tile grid and slices outputs (exact — see its
-        docstring), so real user shapes engage the kernel (VERDICT r3 #3).
-        Only the VMEM-residency bound remains, computed on PADDED sizes."""
+        docstring). What remains is the VMEM-residency bound
+        (``lstm_vmem_bytes``, which counts the double-buffered blocks on
+        padded sizes); a shape past it trains on the scan path and is
+        counted in ``pallas_gate_fallbacks_total``."""
         from deeplearning4j_tpu.ops import pallas_kernels
         mode = pallas_kernels.lstm_mode()
         if (mode == "off" or mask is not None
                 or self.gate_activation != "sigmoid"
                 or (self.activation or "tanh") != "tanh"):
             return False
-        if mode == "compiled":
-            # VMEM residency gate: the kernel keeps RW [Hp, 4Hp] plus the
-            # (h, c) carries and one [Bp, 4Hp] slice on-chip; past ~12MB
-            # (of 16MB v5e VMEM) Mosaic spills or fails to allocate —
-            # fall back to scan rather than risk it
-            Hp = pallas_kernels._round_up(self.n_out or 128, 128)
-            bp = pallas_kernels._round_up(batch or 8, 8)
-            vmem = 4 * (Hp * 4 * Hp + 2 * bp * Hp + 2 * bp * 4 * Hp)
-            if vmem > 12 * 1024 * 1024:
-                return False
+        need = pallas_kernels.lstm_vmem_bytes(batch or 8, self.n_out or 128)
+        if mode == "compiled" and need > pallas_kernels.VMEM_GATE_BYTES:
+            pallas_kernels.count_gate_fallback(self, "fused_lstm")
+            return False
         return True
 
     def scan(self, params: Params, x: Array, carry, mask: Optional[Array],

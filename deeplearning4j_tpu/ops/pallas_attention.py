@@ -37,16 +37,20 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
 from deeplearning4j_tpu.ops.pallas_kernels import (
-    _HAVE_PALLAS, _round_up, lstm_mode,
+    VMEM_GATE_BYTES, _round_up, lstm_mode, vmem_limit,
 )
 
-if _HAVE_PALLAS:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
 NEG_INF = -1e30
-_BLK = 128  # q/k block = MXU tile width
+# q/k block = MXU tile width. Per-row vectors (lse, rowsum(dO*O)) travel
+# as [G, Tp, _BLK] arrays too, the value repeated along the lane axis: a
+# [_BLK, _BLK] block satisfies the (8, 128) tiling and has the shape of
+# the score tile, so the backward subtracts it from the scores
+# elementwise with no column-to-lane relayout inside the kernel.
+_BLK = 128
 
 
 def attention_mode() -> str:
@@ -55,14 +59,38 @@ def attention_mode() -> str:
     return lstm_mode()
 
 
-def flash_ok(T: int, D: int = 128, vmem_budget: int = 6 * 2 ** 20) -> bool:
-    """VMEM residency gate: the kernel keeps the K and V panels
-    [Tp, Dp] f32 for one (batch, head) on-chip — both padded dims
-    count (a 1024-wide head at long T must fall back to the XLA path,
-    not die in Mosaic)."""
+def flash_vmem_bytes(T: int, D: int = 128, itemsize: int = 4) -> int:
+    """VMEM the largest of the three kernels (dk/dv) asks for, counting
+    what Pallas allocates: every BlockSpec operand is double-buffered,
+    the whole-sequence operands are [Tp, Dp] panels (K and V in the
+    forward and dq kernels, Q and dO in dk/dv) and [Tp, _BLK] f32 row
+    vectors (lse, rowsum(dO*O)), and the loop body holds a handful of
+    [_BLK, _BLK] / [_BLK, Dp] f32 tiles."""
     Tp = _round_up(T, _BLK)
     Dp = _round_up(D, _BLK)
-    return 2 * Tp * Dp * 4 <= vmem_budget
+    panels = 2 * Tp * Dp * itemsize            # two whole-sequence panels
+    rows = 2 * Tp * _BLK * 4                   # lse + dvec, whole sequence
+    blocks = 4 * _BLK * Dp * itemsize + 8 * _BLK  # k, v in; dk, dv out; bias
+    tiles = 8 * _BLK * max(_BLK, Dp) * 4       # s, p, dp, ds, q, do, dk, dv
+    return 2 * (panels + rows + blocks) + tiles
+
+
+def flash_ok(T: int, D: int = 128, itemsize: int = 4) -> bool:
+    """Shape gate: the kernels keep whole-sequence panels on-chip, so a
+    long T (or a very wide head) must go to the XLA path, not die in
+    Mosaic. Counts :func:`flash_vmem_bytes` against ``VMEM_GATE_BYTES``."""
+    return flash_vmem_bytes(T, D, itemsize) <= VMEM_GATE_BYTES
+
+
+def _params(T: int, D: int, itemsize: int):
+    # no carry between grid steps in any of the three kernels
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel"),
+        vmem_limit_bytes=vmem_limit(flash_vmem_bytes(T, D, itemsize)))
+
+
+def _blk_slice(j):
+    return pl.dslice(pl.multiple_of(j * _BLK, _BLK), _BLK)
 
 
 # ---------------------------------------------------------------------------
@@ -77,30 +105,30 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
     q_pos = qi * Bq + jax.lax.broadcasted_iota(jnp.int32, (Bq, _BLK), 0)
 
     def body(j, carry):
-        acc, m, l = carry
-        kblk = k_ref[0, pl.dslice(j * _BLK, _BLK), :].astype(jnp.float32)
-        vblk = v_ref[0, pl.dslice(j * _BLK, _BLK), :].astype(jnp.float32)
+        acc, m, l = carry                              # m, l: [Bq, 1]
+        kblk = k_ref[0, _blk_slice(j), :].astype(jnp.float32)
+        vblk = v_ref[0, _blk_slice(j), :].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, kblk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)        # [Bq, BLK]
-        s = s + bias_ref[0, pl.dslice(j * _BLK, _BLK)][None, :]
+        s = s + bias_ref[0, :, _blk_slice(j)]           # [1, BLK] over rows
         if causal:
             k_pos = j * _BLK + jax.lax.broadcasted_iota(
                 jnp.int32, (Bq, _BLK), 1)
             s = jnp.where(k_pos <= q_pos, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
-        l = l * alpha + jnp.sum(p, axis=1)
-        acc = acc * alpha[:, None] + jax.lax.dot_general(
+        l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc = acc * alpha + jax.lax.dot_general(
             p, vblk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         return acc, m_new, l
 
     Dp = q_ref.shape[-1]
     acc0 = jnp.zeros((Bq, Dp), jnp.float32)
-    m0 = jnp.full((Bq,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((Bq,), jnp.float32)
+    m0 = jnp.full((Bq, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((Bq, 1), jnp.float32)
     # causal: KV blocks past the q block's diagonal are wholly masked —
     # skip them instead of feeding NEG_INF tiles to the MXU (Bq == BLK,
     # so block j is live iff j <= qi)
@@ -113,14 +141,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
     # and an EXACT NEG_INF lse, which is what the backward kernels gate
     # their recomputed probabilities on (ADVICE r5)
     valid = m > NEG_INF / 2
-    o_ref[0] = jnp.where(valid[:, None], acc / l_safe[:, None],
-                         0.0).astype(o_ref.dtype)
-    lse_ref[0] = jnp.where(valid, m + jnp.log(l_safe), NEG_INF)
+    o_ref[0] = jnp.where(valid, acc / l_safe, 0.0).astype(o_ref.dtype)
+    lse = jnp.where(valid, m + jnp.log(l_safe), NEG_INF)
+    lse_ref[0] = jnp.broadcast_to(lse, (Bq, _BLK))
 
 
 def _run_fwd(q, k, v, bias, causal, interpret):
-    """q,k,v: [G, Tp, Dp]; bias: [G, Tp] additive (0 / NEG_INF).
-    Returns (out [G, Tp, Dp], lse [G, Tp])."""
+    """q,k,v: [G, Tp, Dp]; bias: [G, 1, Tp] additive (0 / NEG_INF).
+    Returns (out [G, Tp, Dp], lse [G, Tp, _BLK] lane-replicated)."""
     G, Tp, Dp = q.shape
     n_q = Tp // _BLK
     return pl.pallas_call(
@@ -131,17 +159,19 @@ def _run_fwd(q, k, v, bias, causal, interpret):
             pl.BlockSpec((1, _BLK, Dp), lambda g, i: (g, i, 0)),
             pl.BlockSpec((1, Tp, Dp), lambda g, i: (g, 0, 0)),
             pl.BlockSpec((1, Tp, Dp), lambda g, i: (g, 0, 0)),
-            pl.BlockSpec((1, Tp), lambda g, i: (g, 0)),
+            pl.BlockSpec((1, 1, Tp), lambda g, i: (g, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, _BLK, Dp), lambda g, i: (g, i, 0)),
-            pl.BlockSpec((1, _BLK), lambda g, i: (g, i)),
+            pl.BlockSpec((1, _BLK, _BLK), lambda g, i: (g, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((G, Tp, Dp), q.dtype),
-            jax.ShapeDtypeStruct((G, Tp), jnp.float32),
+            jax.ShapeDtypeStruct((G, Tp, _BLK), jnp.float32),
         ],
+        compiler_params=_params(Tp, Dp, q.dtype.itemsize),
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q, k, v, bias)
 
 
@@ -153,19 +183,19 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, dvec_ref,
                dq_ref, *, causal: bool, n_kv: int, scale: float):
     q = q_ref[0].astype(jnp.float32)                  # [Bq, Dp]
     do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]                                  # [Bq]
-    dvec = dvec_ref[0]                                # [Bq]
+    lse = lse_ref[0]                                  # [Bq, BLK] replicated
+    dvec = dvec_ref[0]                                # [Bq, BLK] replicated
     Bq = q.shape[0]
     qi = pl.program_id(1)
     q_pos = qi * Bq + jax.lax.broadcasted_iota(jnp.int32, (Bq, _BLK), 0)
 
     def body(j, dq):
-        kblk = k_ref[0, pl.dslice(j * _BLK, _BLK), :].astype(jnp.float32)
-        vblk = v_ref[0, pl.dslice(j * _BLK, _BLK), :].astype(jnp.float32)
+        kblk = k_ref[0, _blk_slice(j), :].astype(jnp.float32)
+        vblk = v_ref[0, _blk_slice(j), :].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, kblk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        s = s + bias_ref[0, pl.dslice(j * _BLK, _BLK)][None, :]
+        s = s + bias_ref[0, :, _blk_slice(j)]
         if causal:
             k_pos = j * _BLK + jax.lax.broadcasted_iota(
                 jnp.int32, (Bq, _BLK), 1)
@@ -174,12 +204,11 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, dvec_ref,
         # from the forward; exp(s - lse) there is garbage (float
         # absorption, not inf) — gate them to zero probability so the
         # row's gradients are exactly zero (ADVICE r5)
-        p = jnp.where(lse[:, None] > NEG_INF / 2,
-                      jnp.exp(s - lse[:, None]), 0.0)  # [Bq, BLK]
+        p = jnp.where(lse > NEG_INF / 2, jnp.exp(s - lse), 0.0)
         dp = jax.lax.dot_general(
             do, vblk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        ds = p * (dp - dvec[:, None])
+        ds = p * (dp - dvec)
         return dq + jax.lax.dot_general(
             ds, kblk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
@@ -193,36 +222,35 @@ def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, dvec_ref,
                 dk_ref, dv_ref, *, causal: bool, n_q: int, scale: float):
     kblk = k_ref[0].astype(jnp.float32)               # [Bk, Dp]
     vblk = v_ref[0].astype(jnp.float32)
-    bias = bias_ref[0]                                # [Bk]
+    bias = bias_ref[0]                                # [1, Bk]
     Bk = kblk.shape[0]
     ki = pl.program_id(1)
     k_pos = ki * Bk + jax.lax.broadcasted_iota(jnp.int32, (_BLK, Bk), 1)
 
     def body(i, carry):
         dk, dv = carry
-        q = q_ref[0, pl.dslice(i * _BLK, _BLK), :].astype(jnp.float32)
-        do = do_ref[0, pl.dslice(i * _BLK, _BLK), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.dslice(i * _BLK, _BLK)]
-        dvec = dvec_ref[0, pl.dslice(i * _BLK, _BLK)]
+        q = q_ref[0, _blk_slice(i), :].astype(jnp.float32)
+        do = do_ref[0, _blk_slice(i), :].astype(jnp.float32)
+        lse = lse_ref[0, _blk_slice(i), :]             # [Bq, Bk] replicated
+        dvec = dvec_ref[0, _blk_slice(i), :]
         s = jax.lax.dot_general(
             q, kblk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        s = s + bias[None, :]
+        s = s + bias
         if causal:
             q_pos = i * _BLK + jax.lax.broadcasted_iota(
                 jnp.int32, (_BLK, Bk), 0)
             s = jnp.where(k_pos <= q_pos, s, NEG_INF)
         # same masked-row gate as _dq_kernel: rows with lse == NEG_INF
         # (no valid key) must contribute zero to dk/dv
-        p = jnp.where(lse[:, None] > NEG_INF / 2,
-                      jnp.exp(s - lse[:, None]), 0.0)  # [Bq, Bk]
+        p = jnp.where(lse > NEG_INF / 2, jnp.exp(s - lse), 0.0)
         dv = dv + jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(
             do, vblk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        ds = p * (dp - dvec[:, None])
+        ds = p * (dp - dvec)
         dk = dk + jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
@@ -240,31 +268,39 @@ def _run_bwd(q, k, v, bias, do, out, lse, causal, interpret):
     G, Tp, Dp = q.shape
     scale = 1.0 / math.sqrt(Dp)
     dvec = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                   axis=-1)                            # [G, Tp]
+                   axis=-1, keepdims=True)             # [G, Tp, 1]
+    dvec = jnp.broadcast_to(dvec, (G, Tp, _BLK))
     qspec = pl.BlockSpec((1, _BLK, Dp), lambda g, i: (g, i, 0))
     fullspec = pl.BlockSpec((1, Tp, Dp), lambda g, i: (g, 0, 0))
-    rowspec = pl.BlockSpec((1, _BLK), lambda g, i: (g, i))
-    fullrow = pl.BlockSpec((1, Tp), lambda g, i: (g, 0))
+    rowspec = pl.BlockSpec((1, _BLK, _BLK), lambda g, i: (g, i, 0))
+    fullrow = pl.BlockSpec((1, Tp, _BLK), lambda g, i: (g, 0, 0))
+    biasfull = pl.BlockSpec((1, 1, Tp), lambda g, i: (g, 0, 0))
+    biasblk = pl.BlockSpec((1, 1, _BLK), lambda g, i: (g, 0, i))
+    params = _params(Tp, Dp, q.dtype.itemsize)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, causal=causal, n_kv=Tp // _BLK,
                           scale=scale),
         grid=(G, Tp // _BLK),
-        in_specs=[qspec, fullspec, fullspec, fullrow, qspec, rowspec,
+        in_specs=[qspec, fullspec, fullspec, biasfull, qspec, rowspec,
                   rowspec],
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((G, Tp, Dp), q.dtype),
+        compiler_params=params,
         interpret=interpret,
+        name="flash_attention_dq",
     )(q, k, v, bias, do, lse, dvec)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, causal=causal, n_q=Tp // _BLK,
                           scale=scale),
         grid=(G, Tp // _BLK),
-        in_specs=[fullspec, qspec, qspec, rowspec, fullspec, fullrow,
+        in_specs=[fullspec, qspec, qspec, biasblk, fullspec, fullrow,
                   fullrow],
         out_specs=[qspec, qspec],
         out_shape=[jax.ShapeDtypeStruct((G, Tp, Dp), k.dtype),
                    jax.ShapeDtypeStruct((G, Tp, Dp), v.dtype)],
+        compiler_params=params,
         interpret=interpret,
+        name="flash_attention_dkv",
     )(q, k, v, bias, do, lse, dvec)
     return dq, dk, dv
 
@@ -316,6 +352,6 @@ def flash_attention(q, k, v, *, causal: bool = False,
         else kv_mask.astype(jnp.float32)
     valid = jnp.pad(valid, ((0, 0), (0, Tp - T)))
     bias = jnp.where(valid > 0, 0.0, NEG_INF).astype(jnp.float32)
-    bias = jnp.repeat(bias, H, axis=0)                 # [B*H, Tp]
+    bias = jnp.repeat(bias, H, axis=0)[:, None, :]     # [B*H, 1, Tp]
     out = _flash_core(qf, kf, vf, bias, causal, interpret)
     return out.reshape(B, H, Tp, Dp)[:, :, :T, :D]

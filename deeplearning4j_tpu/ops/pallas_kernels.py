@@ -34,26 +34,68 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-try:  # pallas ships with jax, but keep the probe-and-fallback seam anyway
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAVE_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# Mosaic's default scoped-VMEM limit is 16 MiB on a v5e (32 MiB on later
+# chips) — a compiler default, not the chip's VMEM, which is 128 MiB per
+# core on v5e/v6e. A kernel that needs more than the default passes its
+# own ``vmem_limit_bytes``; the shape gates refuse anything past
+# VMEM_GATE_BYTES, half the physical VMEM, and send it to the XLA path.
+_DEFAULT_SCOPED_VMEM = 16 * 2 ** 20
+VMEM_GATE_BYTES = 64 * 2 ** 20
+
+
+def vmem_limit(need: int) -> Optional[int]:
+    """``vmem_limit_bytes`` for a kernel whose blocks and temporaries
+    take ``need`` bytes: None (the compiler's default) while three
+    quarters of the smallest default covers it, else ``need`` plus a
+    quarter for Mosaic's own scratch."""
+    if need <= _DEFAULT_SCOPED_VMEM * 3 // 4:
+        return None
+    return need + need // 4
 
 
 def lstm_mode() -> str:
     """'compiled' | 'interpret' | 'off' — the helper-discovery decision."""
     env = os.environ.get("DL4J_TPU_PALLAS", "auto")
-    if not _HAVE_PALLAS or env in ("0", "off", "false"):
+    if env in ("0", "off", "false"):
         return "off"
     if env == "interpret":
         return "interpret"
-    try:
-        platform = jax.devices()[0].platform
-    except Exception:
-        return "off"
-    return "compiled" if platform == "tpu" else "off"
+    return "compiled" if jax.devices()[0].platform == "tpu" else "off"
+
+
+def count_gate_fallback(layer, kernel: str) -> None:
+    """A shape gate sent ``layer`` to the XLA path: count it under the
+    layer's name (once per trace, not per step), so a run can say which
+    path it took."""
+    from deeplearning4j_tpu.profiling.metrics import get_registry
+    get_registry().labeled_counter(
+        "pallas_gate_fallbacks_total",
+        "layers a Pallas shape gate sent to the XLA path (per trace)",
+    ).labels(layer=layer.name or type(layer).__name__, kernel=kernel).inc()
+
+
+def lstm_vmem_bytes(B: int, H: int, itemsize: int = 4) -> int:
+    """VMEM the backward kernel (the largest of the three) asks for, on
+    PADDED sizes and counting what Pallas allocates: every BlockSpec
+    operand is double-buffered (the weights too, though their block
+    never moves), plus the (dh, dc) carry scratch and the gate
+    temporaries."""
+    Hp, Bp = _round_up(H, 128), _round_up(B, 8)
+    slab4, slab1 = Bp * 4 * Hp, Bp * Hp   # one timestep's [B, 4H] / [B, H]
+    # RW^T, peepholes (3 rows pad to 8), 7 [B, H] and 2 [B, 4H] operands
+    blocks = Hp * 4 * Hp + 8 * Hp + 7 * slab1 + 2 * slab4
+    return (2 * blocks + 2 * slab1 + 4 * slab4) * itemsize
+
+
+def _lstm_params(B: int, H: int, itemsize: int):
+    # the (h, c) carry lives in VMEM scratch ACROSS grid steps: the time
+    # grid must run in order on one core
+    return pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",),
+        vmem_limit_bytes=vmem_limit(lstm_vmem_bytes(B, H, itemsize)))
 
 
 # ---------------------------------------------------------------------------
@@ -154,7 +196,9 @@ def _run_lstm_fwd_infer(xz, rw, pw, h0, c0, forget_bias, interpret):
             jax.ShapeDtypeStruct((B, H), dt),
         ],
         scratch_shapes=[pltpu.VMEM((B, H), dt), pltpu.VMEM((B, H), dt)],
+        compiler_params=_lstm_params(B, H, dt.itemsize),
         interpret=interpret,
+        name="fused_lstm_infer",
     )(xz, rw, pw, h0, c0, fb)
 
 
@@ -234,7 +278,9 @@ def _run_lstm_fwd(xz, rw, pw, h0, c0, forget_bias, interpret):
             jax.ShapeDtypeStruct((T, B, H), dt),      # cell cache
         ],
         scratch_shapes=[pltpu.VMEM((B, H), dt), pltpu.VMEM((B, H), dt)],
+        compiler_params=_lstm_params(B, H, dt.itemsize),
         interpret=interpret,
+        name="fused_lstm_fwd",
     )(xz, rw, pw, h0, c0, fb)
 
 
@@ -268,7 +314,9 @@ def _run_lstm_bwd(eps, gates, cs, c_prev, rw, pw, dhT, dcT, interpret):
             jax.ShapeDtypeStruct((B, H), dt),          # dc0
         ],
         scratch_shapes=[pltpu.VMEM((B, H), dt), pltpu.VMEM((B, H), dt)],
+        compiler_params=_lstm_params(B, H, dt.itemsize),
         interpret=interpret,
+        name="fused_lstm_bwd",
     )(eps, gates, cs, c_prev, rw.T, pw, dhT, dcT)
 
 

@@ -42,8 +42,7 @@ _initialized = False
 #: and supplies its own liveness layer (resilience/elastic.py heartbeat
 #: files + step-barrier timeouts), which can tell a slow host from a
 #: dead one and react without killing the fleet.
-_ELASTIC_HEARTBEAT_INTERVAL_S = 3600
-_ELASTIC_MAX_MISSING_HEARTBEATS = 1000
+_ELASTIC_HEARTBEAT_TIMEOUT_S = 3_600_000
 
 #: statuses delivered to the benign missed-heartbeat callback (elastic
 #: mode); resilience/elastic.py reads these as one more failure signal
@@ -74,11 +73,7 @@ def _ensure_cpu_collectives() -> None:
     TPU/GPU (flag only consulted by the CPU client factory)."""
     if os.environ.get("JAX_PLATFORMS", "").startswith("cpu") or \
             str(jax.config.jax_platforms or "").startswith("cpu"):
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # older jaxlib without gloo: keep prior behavior
-            logger.warning("gloo CPU collectives unavailable; multi-process "
-                           "CPU computations will not run")
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
         # NOTE: do NOT disable XLA's thunk runtime here to dodge the
         # gloo slot race (see gloo_collectives_active): the legacy CPU
         # runtime turns a gloo all-reduce failing on a dead peer into a
@@ -187,7 +182,7 @@ def _initialize_elastic(coordinator: str, num_processes: int,
     follows the lease, never the other way around."""
     from jax._src import distributed as jdist
     from jax._src import xla_bridge
-    from jax._src.lib import xla_extension
+    from jax._src.lib import _jax
 
     if xla_bridge.backends_are_initialized():
         raise RuntimeError("multihost.initialize(elastic=True) must be "
@@ -199,14 +194,12 @@ def _initialize_elastic(coordinator: str, num_processes: int,
         host_service = process_id == 0
     if host_service:
         port = coordinator.rsplit(":", 1)[1]
-        gs.service = xla_extension.get_distributed_runtime_service(
+        gs.service = _jax.get_distributed_runtime_service(
             f"[::]:{port}", num_processes,
-            heartbeat_interval=_ELASTIC_HEARTBEAT_INTERVAL_S,
-            max_missing_heartbeats=_ELASTIC_MAX_MISSING_HEARTBEATS)
-    gs.client = xla_extension.get_distributed_runtime_client(
+            heartbeat_timeout=_ELASTIC_HEARTBEAT_TIMEOUT_S)
+    gs.client = _jax.get_distributed_runtime_client(
         coordinator, process_id, init_timeout=300,
-        heartbeat_interval=_ELASTIC_HEARTBEAT_INTERVAL_S,
-        max_missing_heartbeats=_ELASTIC_MAX_MISSING_HEARTBEATS,
+        heartbeat_timeout=_ELASTIC_HEARTBEAT_TIMEOUT_S,
         missed_heartbeat_callback=_on_runtime_fault,
         shutdown_on_destruction=False, use_compression=True)
     gs.client.connect()
@@ -372,11 +365,10 @@ def serve_coordination(port: int, num_processes: int) -> None:
     import sys
     import time as _time
 
-    from jax._src.lib import xla_extension
-    service = xla_extension.get_distributed_runtime_service(
+    from jax._src.lib import _jax
+    service = _jax.get_distributed_runtime_service(
         f"[::]:{int(port)}", int(num_processes),
-        heartbeat_interval=_ELASTIC_HEARTBEAT_INTERVAL_S,
-        max_missing_heartbeats=_ELASTIC_MAX_MISSING_HEARTBEATS)
+        heartbeat_timeout=_ELASTIC_HEARTBEAT_TIMEOUT_S)
     print(f"READY coordination service on port {port} for "
           f"{num_processes} processes", flush=True)
     try:

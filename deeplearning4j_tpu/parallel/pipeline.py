@@ -33,20 +33,7 @@ from typing import Callable, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:
-    from jax import shard_map
-    _COMPAT_SHARD_MAP = False
-except ImportError:  # jax < 0.6 ships it under experimental
-    import functools
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-    # the pre-varying-types replication checker cannot type the ring's
-    # lax.switch branches (newer jax proves the same property via
-    # pvary/pcast); its own error message prescribes check_rep=False
-    shard_map = functools.partial(_exp_shard_map, check_rep=False)
-    # with check_rep off, an out_spec that omits a mesh axis is UNDEFINED
-    # under jit (the eager path happens to pick a valid replica; jit does
-    # not) — _make_ring tiles its outputs over every axis instead
-    _COMPAT_SHARD_MAP = True
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from deeplearning4j_tpu.datasets.dataset import DataSet
@@ -60,13 +47,7 @@ _WARNED_AUX_MICROBATCH = False
 
 
 def _pvary(x, axis):
-    # jax.lax.pvary was deprecated in favor of pcast(..., to='varying')
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axis, to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, (axis,))
-    # pre-varying-type jax (< 0.5): values need no device-varying marking
-    return x
+    return jax.lax.pcast(x, axis, to="varying")
 
 
 def pipeline_apply(stage_fn: Callable, stacked_params, x_microbatches,
@@ -145,21 +126,7 @@ def _make_ring(mesh: Mesh, axis: str, dp_axis: Optional[str], S: int,
 
     def device_fn(bufs, sbufs, cbufs, xs, rng):
         sid = jax.lax.axis_index(axis)
-        if _COMPAT_SHARD_MAP:
-            # bufs/sbufs/cbufs arrive REPLICATED (see the spec selection
-            # below): each device picks its own stage row. jax 0.4.x
-            # miscompiles a P(axis)-sharded operand that is COMPUTED
-            # inside the enclosing jit (pack_bufs/pack_states) — the
-            # manual region reads garbage; replicate-and-index sidesteps
-            # the partitioner entirely at a CPU-test-only memory cost.
-            pflat = jax.lax.dynamic_index_in_dim(bufs, sid, 0,
-                                                 keepdims=False)
-            srow = jax.lax.dynamic_index_in_dim(sbufs, sid, 0,
-                                                keepdims=False)
-            crow = jax.lax.dynamic_index_in_dim(cbufs, sid, 0,
-                                                keepdims=False)
-        else:
-            pflat, srow, crow = bufs[0], sbufs[0], cbufs[0]
+        pflat, srow, crow = bufs[0], sbufs[0], cbufs[0]
         perm = [(j, (j + 1) % S) for j in range(S)]
         key_base = jax.random.fold_in(rng, sid)
         if dp_axis is not None:
@@ -208,36 +175,9 @@ def _make_ring(mesh: Mesh, axis: str, dp_axis: Optional[str], S: int,
             sflat = jax.lax.pmean(sflat, dp_axis)
             cflat = jax.lax.pmean(cflat, dp_axis)  # dummy rows when dp on
         out = jax.lax.psum(outbuf, axis)
-        if _COMPAT_SHARD_MAP:
-            # every output dimension maps a mesh axis (see import shim):
-            # out gains a leading pp axis; state/carry rows gain a dp axis
-            # when dp is on. All tiles are identical (post-psum/pmean), so
-            # the caller strips index 0.
-            if dp_axis is not None:
-                return out[None], sflat[None, None], cflat[None, None]
-            return out[None], sflat[None], cflat[None]
         return out, sflat[None], cflat[None]
 
     batch_spec = P(None, dp_axis, None)
-    if _COMPAT_SHARD_MAP:
-        # replicated param/state/carry operands (see device_fn), and
-        # out_specs that mention EVERY mesh axis (an omitted axis is
-        # undefined under jit with check_rep=False) — all tiles are
-        # identical post-psum/pmean, so the wrapper strips index 0
-        fn = shard_map(
-            device_fn, mesh=mesh,
-            in_specs=(P(), P(), P(), batch_spec, P()),
-            out_specs=(P(axis, None, dp_axis, None),
-                       P(axis, dp_axis) if dp_axis else P(axis),
-                       P(axis, dp_axis) if dp_axis else P(axis)))
-
-        def pipe(*args):
-            outs, sbufs, cbufs = fn(*args)
-            if dp_axis is not None:
-                return outs[0], sbufs[:, 0], cbufs[:, 0]
-            return outs[0], sbufs, cbufs
-
-        return pipe
     return shard_map(device_fn, mesh=mesh,
                      in_specs=(P(axis), P(axis), P(axis), batch_spec, P()),
                      out_specs=(batch_spec, P(axis), P(axis)))

@@ -107,14 +107,7 @@ def ring_self_attention(x, params, mesh: Mesh, *, n_heads: int,
     masked query positions, matching the local layer path. Entry point
     used by SelfAttentionLayer when a mesh context is active, and
     directly by transformer blocks."""
-    try:
-        from jax import shard_map
-    except ImportError:  # jax < 0.6 ships it under experimental
-        import functools
-        from jax.experimental.shard_map import shard_map as _exp
-        # see parallel/pipeline.py: the old replication checker predates
-        # pvary/pcast and rejects valid ring programs
-        shard_map = functools.partial(_exp, check_rep=False)
+    from jax import shard_map
 
     def local_fn(x_l, Wq, Wk, Wv, Wo, *mask_rest):
         mask_l = mask_rest[0] if mask_rest else None

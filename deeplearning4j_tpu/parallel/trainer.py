@@ -430,7 +430,7 @@ class ParallelTrainer:
             feats, labels, fmask, lmask = self._shard_batch_args(batch)
             if stats:
                 # sync the async device_put so transfer time lands in
-                # 'shard', not 'step' — over a remote tunnel that
+                # 'shard', not 'step' — over a slow host link that
                 # distinction is the whole point of the phase
                 jax.block_until_ready((feats, labels))
                 stats.record("shard", time.perf_counter() - t_shard)

@@ -34,11 +34,9 @@ from __future__ import annotations
 import weakref
 from typing import Optional
 
-# Peak dense matmul FLOP/s per chip (bf16 where the chip has bf16 MXUs),
-# by device_kind substring, public cloud specs. First match wins, so
-# longer/more-specific keys come first. The "cpu" entry is a nominal
-# 1 TFLOP/s placeholder so off-chip runs still get a defined ratio —
-# treat CPU "MFU" as a relative number, not a utilization claim.
+# Peak dense bf16 matmul FLOP/s per chip, by device_kind substring, public
+# cloud specs. First match wins, so longer/more-specific keys come first.
+# Accelerators only: a CPU has no MFU.
 PEAK_FLOPS_PER_CHIP = (
     ("v6", 918e12),       # TPU v6e (Trillium)
     ("v5p", 459e12),
@@ -48,18 +46,23 @@ PEAK_FLOPS_PER_CHIP = (
     ("v4", 275e12),
     ("v3", 123e12),
     ("v2", 45e12),
-    ("cpu", 1e12),
 )
 
 
 def peak_flops(device_kind: str) -> Optional[float]:
-    """Peak FLOP/s for a ``device_kind`` string (substring match), or
-    None when the chip is unknown."""
+    """Peak FLOP/s of the accelerator ``device_kind`` names (substring
+    match). None for the CPU backend, which has no utilization to
+    report; an accelerator the table does not know is an error, not a
+    default."""
     kind = (device_kind or "").lower()
+    if kind == "cpu":
+        return None
     for key, peak in PEAK_FLOPS_PER_CHIP:
         if key in kind:
             return peak
-    return None
+    raise ValueError(
+        f"no peak FLOP/s on record for device_kind {device_kind!r}; add "
+        "it to profiling.cost.PEAK_FLOPS_PER_CHIP with its source")
 
 
 def analytic_mfu(flops_per_step: float, step_seconds: float,
@@ -253,15 +256,10 @@ def weight_update_cost(net, dp: int,
 
 
 def _normalize_cost(raw) -> dict:
-    """``cost_analysis()`` returns a dict in newer jax, a 1-list of
-    dicts in 0.4.x, and occasionally None (backend without a cost
-    model). Normalize to {flops, bytes_accessed, ...} floats."""
-    if raw is None:
-        return {}
-    if isinstance(raw, (list, tuple)):
-        raw = raw[0] if raw else {}
+    """``cost_analysis()`` returns a dict, or None on a backend without
+    a cost model. Normalize to {flops, bytes_accessed, ...} floats."""
     out = {}
-    for key, val in dict(raw).items():
+    for key, val in dict(raw or {}).items():
         if key == "flops":
             out["flops"] = float(val)
         elif key in ("bytes accessed", "bytes_accessed"):
@@ -358,11 +356,7 @@ def train_step_cost(net, batch, peak: Optional[float] = None) -> dict:
         comm_bytes_hlo = hlo_comm_bytes(program)
     except Exception:  # noqa: BLE001 — cost numbers stand without the parse
         cost = compiled_cost(net._train_step_fn, *args)
-    try:
-        device_kind = str(getattr(jax.devices()[0], "device_kind",
-                                  jax.devices()[0].platform))
-    except Exception:  # noqa: BLE001 — cost numbers stand without a device
-        device_kind = "unknown"
+    device_kind = str(jax.devices()[0].device_kind)
     peak = peak if peak is not None else peak_flops(device_kind)
     flops = cost.get("flops")
     out = {

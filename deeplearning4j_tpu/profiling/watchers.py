@@ -38,6 +38,12 @@ _COMPILE_EVENTS = {
                                                    "jit:compile"),
 }
 
+# persistent-compilation-cache events -> counter name
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "jax_compile_cache_hits_total",
+    "/jax/compilation_cache/cache_misses": "jax_compile_cache_misses_total",
+}
+
 _COMPILE_TIME_BUCKETS = (0.01, 0.05, 0.2, 1.0, 5.0, 20.0, 60.0, 300.0)
 
 
@@ -48,8 +54,11 @@ class CompileWatcher:
     jax offers no per-listener removal, so ``uninstall()`` deactivates
     this watcher's callbacks instead of deregistering them). Counters:
     ``jax_{trace,lower,compile}_total`` and ``..._seconds_total``, plus
-    a ``jax_compile_seconds`` histogram. Compiles longer than
-    ``warn_compile_s`` log a warning — over a remote-TPU tunnel a
+    a ``jax_compile_seconds`` histogram, and
+    ``jax_compile_cache_{hits,misses}_total`` for the persistent
+    compilation cache (a hit still counts as a compile event — jax
+    times the cache lookup under the same name). Compiles longer than
+    ``warn_compile_s`` log a warning — in a steady-state loop a
     surprise recompile IS the incident.
     """
 
@@ -74,6 +83,7 @@ class CompileWatcher:
                 import jax.monitoring as monitoring
                 monitoring.register_event_duration_secs_listener(
                     self._on_duration)
+                monitoring.register_event_listener(self._on_event)
                 self._installed = True
             except Exception:  # noqa: BLE001 — jax-free runtime: no-op
                 logger.debug("jax.monitoring unavailable; CompileWatcher "
@@ -83,6 +93,12 @@ class CompileWatcher:
     def uninstall(self) -> None:
         with self._lock:
             self._active = False
+
+    def _on_event(self, event: str, **_kw) -> None:
+        name = _CACHE_EVENTS.get(event)
+        if self._active and name is not None:
+            self.registry.counter(
+                name, help="persistent compilation cache lookups").inc()
 
     def _on_duration(self, event: str, duration: float, **_kw) -> None:
         if not self._active:

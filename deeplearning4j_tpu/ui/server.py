@@ -350,7 +350,7 @@ def _system_info() -> dict:
         if "jax" in sys.modules:
             import jax
             from jax._src import xla_bridge
-            if xla_bridge._backends:
+            if xla_bridge.backends_are_initialized():
                 info["devices"] = [
                     f"{getattr(d, 'device_kind', d.platform)} "
                     f"({d.platform})" for d in jax.devices()]

@@ -16,13 +16,7 @@ if "xla_force_host_platform_device_count" not in _flags:
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:
-    # Option first appeared in jax 0.4.34+ builds but is absent from the
-    # installed 0.4.37 wheel; the XLA_FLAGS path above already yields 8
-    # CPU devices, and pytest_configure asserts the count as a backstop.
-    pass
+jax.config.update("jax_num_cpu_devices", 8)
 
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402

@@ -1,6 +1,9 @@
 """Pallas flash-attention kernel parity vs the XLA reference paths
-(interpret mode — how CPU CI exercises the kernel; the compiled-Mosaic
-verdict is captured on hardware by the bench ladder, like the LSTM)."""
+(interpret mode — how CPU CI exercises the kernel — plus a cross-lowering
+for the TPU that needs no chip; the compiled-Mosaic verdict is
+``chip_smoke.py`` phase P3's, on hardware)."""
+
+import functools
 
 import numpy as np
 import pytest
@@ -90,6 +93,22 @@ def test_flash_gradient_parity_masked():
                                    err_msg=f"d{name}")
 
 
+@pytest.mark.parametrize(
+    "shape", [(4, 4, 256, 128), (2, 2, 40, 24), (32, 8, 128, 32)],
+    ids=["aligned", "unaligned", "lm_rung"])
+def test_flash_cross_lowers_for_tpu(shape):
+    """Lowering for ("tpu",) runs the Pallas-to-Mosaic lowering on the
+    CPU: a BlockSpec the (8, 128) tiling rejects raises here, in tier-1,
+    not on the chip. Forward is one kernel, backward adds dq and dk/dv."""
+    q = k = v = jnp.zeros(shape, jnp.float32)
+    fwd = functools.partial(flash_attention, causal=True, interpret=False)
+    bwd = jax.grad(lambda q, k, v: fwd(q, k, v).sum(), argnums=(0, 1, 2))
+    for fn, kernels in ((fwd, 1), (bwd, 3)):
+        text = jax.jit(fn).trace(q, k, v).lower(
+            lowering_platforms=("tpu",)).as_text()
+        assert text.count("tpu_custom_call") == kernels
+
+
 def test_flash_ok_vmem_gate():
     assert flash_ok(2048)
     assert not flash_ok(200_000)
@@ -115,12 +134,26 @@ def test_selfattention_layer_uses_flash_kernel(monkeypatch):
                                   loss="mcxent"))
             .set_input_type(InputType.recurrent(8, 12)).build())
     x = RNG.normal(size=(4, 12, 8)).astype(np.float32)
-    net = MultiLayerNetwork(conf).init()
+
+    def output():
+        # a fresh net per path (same seed, same params): the jitted
+        # infer fn is cached per net, and the mode is read while tracing
+        return np.asarray(MultiLayerNetwork(conf).init().output(x))
+
     monkeypatch.setenv("DL4J_TPU_PALLAS", "0")
-    ref = np.asarray(net.output(x))
+    ref = output()
     monkeypatch.setenv("DL4J_TPU_PALLAS", "interpret")
-    got = np.asarray(net.output(x))
-    np.testing.assert_allclose(got, ref, atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(output(), ref, atol=2e-5, rtol=2e-5)
+    # a shape the gate refuses takes the XLA path and says so
+    from deeplearning4j_tpu.ops import pallas_attention
+    from deeplearning4j_tpu.profiling.metrics import get_registry
+    gated = get_registry().labeled_counter("pallas_gate_fallbacks_total")
+    before = gated.value
+    monkeypatch.setattr(pallas_attention, "VMEM_GATE_BYTES", 0)
+    np.testing.assert_array_equal(output(), ref)
+    assert gated.value == before + 1
+    assert gated.labels(layer="SelfAttentionLayer",
+                        kernel="flash_attention").value >= 1
 
 
 def test_flash_multi_block_causal_masked():

@@ -179,6 +179,25 @@ def test_padding_exact_nonaligned_shape(monkeypatch):
                                    atol=3e-4, err_msg=k)
 
 
+@pytest.mark.parametrize(
+    "shape", [(8, 16, 128, 128), (6, 16, 72, 200), (32, 64, 96, 256)],
+    ids=["aligned", "unaligned", "lstm_rung"])
+def test_fused_lstm_cross_lowers_for_tpu(shape):
+    """Lowering for ("tpu",) runs the Pallas-to-Mosaic lowering on the
+    CPU: a BlockSpec the (8, 128) tiling rejects raises here, in tier-1,
+    not on the chip. (B, T, F, H); one kernel forward, two under grad."""
+    b, t, f, h = shape
+    args = [jnp.zeros(s, jnp.float32) for s in (
+        (b, t, f), (f, 4 * h), (h, 4 * h), (4 * h,), (3 * h,), (b, h),
+        (b, h))]
+    fwd = lambda *a: fused_lstm(*a, forget_bias=1.0, interpret=False)
+    bwd = jax.grad(lambda *a: fwd(*a)[0].sum(), argnums=tuple(range(7)))
+    for fn, kernels in ((fwd, 1), (bwd, 2)):
+        text = jax.jit(fn).trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text()
+        assert text.count("tpu_custom_call") == kernels
+
+
 def test_compiled_gate_accepts_nonaligned(monkeypatch):
     """The H%128/B%8 fallback is gone: compiled mode accepts unaligned
     shapes (padding handles them); only the VMEM bound still declines."""
@@ -188,5 +207,5 @@ def test_compiled_gate_accepts_nonaligned(monkeypatch):
     layer.n_out = 200
     assert layer._fused_kernel_ok(None, batch=6)
     big = _mk_layer(LSTM)
-    big.n_out = 8192  # RW alone = 1GB >> 12MB VMEM bound
+    big.n_out = 8192  # RW alone = 1GB, double-buffered: past the gate
     assert not big._fused_kernel_ok(None, batch=8)

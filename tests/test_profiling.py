@@ -321,8 +321,9 @@ def test_analytic_mfu_arithmetic():
 def test_peak_flops_table():
     assert peak_flops("TPU v5 lite") == 197e12
     assert peak_flops("TPU v4") == 275e12
-    assert peak_flops("cpu") == 1e12
-    assert peak_flops("quantum abacus") is None
+    assert peak_flops("cpu") is None  # a CPU has no MFU
+    with pytest.raises(ValueError, match="quantum abacus"):
+        peak_flops("quantum abacus")  # unknown accelerator: no default
 
 
 def test_lenet_train_step_cost_matches_hand_count():
@@ -356,10 +357,10 @@ def test_lenet_train_step_cost_matches_hand_count():
     assert cost["arithmetic_intensity"] == pytest.approx(
         flops / cost["bytes_accessed"])
     assert cost["batch"] == B
-    # CPU run: the table's CPU fallback peak keeps MFU defined off-chip
-    assert cost["peak_flops_per_chip"] == 1e12
-    mfu = analytic_mfu(flops, 0.01, cost["peak_flops_per_chip"])
-    assert mfu == pytest.approx(flops / 1e10)
+    # CPU run: no peak, so no MFU; against a stated peak it is defined
+    assert cost["peak_flops_per_chip"] is None
+    assert analytic_mfu(flops, 0.01, cost["peak_flops_per_chip"]) is None
+    assert analytic_mfu(flops, 0.01, 1e12) == pytest.approx(flops / 1e10)
 
 
 def test_graph_container_cost_analysis():
@@ -745,35 +746,38 @@ def test_wedged_trainer_step_bundle_names_straggle(tmp_path, fresh_diag):
         trainer.close()
 
 
-def test_hung_backend_probe_emits_bundle_and_record(tmp_path, fresh_diag,
-                                                    monkeypatch, capsys):
-    """ISSUE-17 acceptance, half 2: a simulated dead tunnel (the probe
-    child sleeps forever) yields a structured backend_unreachable
-    failure record AND an on-disk bundle naming bench:probe_backend."""
+def test_bench_ladder_refuses_cpu_and_fails_on_a_raising_rung(
+        tmp_path, fresh_diag, monkeypatch, capsys):
+    """bench.py hides nothing: on a CPU backend it refuses to measure
+    unless BENCH_SMOKE=1 asks for the smoke, and a rung that raises
+    leaves a failure record naming the rung's span AND a non-zero exit."""
     import bench
 
-    monkeypatch.setenv("BENCH_PROBE_HANG_S", "30")
-    wd = StallWatchdog(str(tmp_path), interval_s=0.2)
-    try:
-        ok = bench._probe_backend(1.0, watchdog=wd)
-    finally:
-        wd.close()
-    assert ok is False
-    rec = None
-    for line in capsys.readouterr().out.splitlines():
-        if line.startswith("{"):
-            rec = json.loads(line)
-    assert rec is not None, "no failure record printed"
+    def records():
+        return [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
+                if ln.startswith("{")]
+
+    # the env var alone places the cache: nothing is configured in-process
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    monkeypatch.setenv("BENCH_BUNDLE_DIR", str(tmp_path))
+    monkeypatch.setenv("BENCH_RUNGS", "lenet")
+    monkeypatch.delenv("BENCH_SMOKE", raising=False)
+    monkeypatch.delenv("BENCH_SMALL", raising=False)
+    assert bench.main() == 1
+    assert records() == [], "a CPU run without BENCH_SMOKE=1 printed a record"
+
+    def boom(*_a, **_k):
+        raise RuntimeError("forced rung failure")
+
+    monkeypatch.setenv("BENCH_SMOKE", "1")
+    monkeypatch.setattr(bench, "_run_rung", boom)
+    assert bench.main() == 1
+    (rec,) = records()
     assert rec["failed"] is True
-    assert rec["error"]["kind"] == "backend_unreachable"
-    assert "bench:probe_backend" in rec["error"]["open_spans"]
-    assert rec["error"]["flight_tail"], "flight tail missing"
-    path = rec["error"]["bundle"]
-    assert path and os.path.exists(path)
-    with open(path) as f:
-        bundle = json.load(f)
-    assert bundle["reason"] == "backend_unreachable"
-    assert bundle["culprit"]["span"] == "bench:probe_backend"
+    assert rec["metric"].endswith("_SMOKE")
+    assert rec["error"]["kind"] == "exception"
+    assert "forced rung failure" in rec["error"]["detail"]
+    assert "rung:lenet" in rec["error"]["open_spans"]
 
 
 # ----------------------------------------------------- postmortem reader

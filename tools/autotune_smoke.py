@@ -42,10 +42,7 @@ def main() -> int:
     import numpy as np
 
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", DP)
-    except AttributeError:
-        pass
+    jax.config.update("jax_num_cpu_devices", DP)
     if len(jax.devices()) < DP:
         print(f"autotune_smoke: FAIL need {DP} cpu devices, "
               f"have {jax.devices()}")

@@ -45,10 +45,7 @@ def main() -> int:
     import numpy as np
 
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", DEVICES)
-    except AttributeError:
-        pass
+    jax.config.update("jax_num_cpu_devices", DEVICES)
     if len(jax.devices()) < DEVICES:
         print(f"lm_smoke: FAIL need {DEVICES} cpu devices, "
               f"have {jax.devices()}")

@@ -31,7 +31,7 @@ bench_smoke() {
     rm -f /tmp/_bench_smoke.jsonl
     JAX_PLATFORMS=cpu BENCH_SMOKE=1 \
         BENCH_RUNGS=lenet,input,serve,lm,lm_serve,fleet \
-        BENCH_AUTOTUNE=1 BENCH_CHILD=1 \
+        BENCH_AUTOTUNE=1 \
         python bench.py | tee /tmp/_bench_smoke.jsonl || return 1
     # every successful rung record must carry the ISSUE-10 precision
     # fields, the ISSUE-11 comm_bytes_hlo calibration field, and the
@@ -65,14 +65,16 @@ bad = [r["metric"] for r in tuned
            and math.isfinite(r["measured_vs_predicted_gap"]))]
 assert not bad, f"autotuned records without a finite calibration gap: {bad}"
 # ISSUE 14: the lm rung's record must carry the token-throughput schema
-# with a finite analytic MFU
+# with the compiled step's FLOP count; a CPU run has no MFU
 lm = [r for r in recs if r.get("rung") == "lm"]
 assert lm, "no lm rung record emitted"
 for r in lm:
-    for fld in ("tokens_per_sec_per_chip", "seq_len", "analytic_mfu"):
+    for fld in ("tokens_per_sec_per_chip", "seq_len", "flops_per_step"):
         v = r.get(fld)
         assert v is not None and math.isfinite(float(v)), \
             f"lm record {fld} missing or non-finite: {v!r}"
+    assert r["analytic_mfu"] is None, \
+        f"CPU smoke record reports an MFU: {r['analytic_mfu']!r}"
 # ISSUE 15: the lm_serve rung must carry the token-level serving
 # schema (tokens/sec-at-SLO + TTFT p50/p99), run its timed wave with
 # zero decode recompiles, and BEAT the whole-predict baseline on the

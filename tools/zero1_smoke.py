@@ -31,10 +31,7 @@ def main() -> int:
     import numpy as np
 
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", DP)
-    except AttributeError:
-        pass  # XLA_FLAGS above already forced the device count
+    jax.config.update("jax_num_cpu_devices", DP)
     if len(jax.devices()) < DP:
         print(f"zero1_smoke: FAIL need {DP} cpu devices, "
               f"have {jax.devices()}")
