@@ -10,10 +10,18 @@ MultipleEpochsIterator, ExistingDataSetIterator).
 On TPU the async iterator's job is keeping the host→device feed ahead of the
 step; ``fit()`` wraps any iterator in AsyncDataSetIterator exactly as
 MultiLayerNetwork.fit does (ref: MultiLayerNetwork.java:951).
+
+The async iterators record what both of their threads do, as spans of the
+process's tracer and counters of its registry, under the ``input:*`` names
+``datasets/pipeline.py`` has: the producer's ``input:produce`` (children
+``input:read``, ``input:cast``, ``input:h2d``, ``input:put_wait``) and the
+consumer's ``input:wait``. The k-th item produced and the k-th taken carry
+``batch=k`` (the queue is FIFO); the item that ends the stream is one too.
 """
 
 from __future__ import annotations
 
+import itertools
 import queue
 import threading
 from typing import Iterator, List, Optional
@@ -21,6 +29,8 @@ from typing import Iterator, List, Optional
 import numpy as np
 
 from deeplearning4j_tpu.datasets.dataset import DataSet
+from deeplearning4j_tpu.profiling.metrics import get_registry
+from deeplearning4j_tpu.profiling.tracer import get_tracer
 
 
 class DataSetIterator:
@@ -217,20 +227,42 @@ class AsyncDataSetIterator(DataSetIterator):
         self._thread: Optional[threading.Thread] = None
         self._peek = None  # ("data", ds) | ("error", exc) | ("end", None)
         self._done = False
+        self._taken = 0    # items taken off the queue since the last start
         self._start()
+
+    def _stage(self, ds: DataSet) -> DataSet:
+        """What the producer does to a batch between reading and
+        queueing it: nothing here."""
+        return ds
 
     def _producer(self, q: "queue.Queue"):
         # In-order tagged items: already-produced batches are consumed before
         # an error is raised, and the stream always terminates cleanly.
         try:
-            while self._base.has_next():
-                q.put(("data", self._base.next()))
-            q.put(("end", None))
+            for k in itertools.count():
+                tracer = get_tracer()
+                with tracer.span("input:produce", batch=k):
+                    with tracer.span("input:read"):
+                        more = self._base.has_next()
+                        ds = self._base.next() if more else None
+                    item = ("data", self._stage(ds)) if more else ("end", None)
+                    with tracer.span("input:put_wait") as put:
+                        q.put(item)
+                    get_registry().counter(
+                        "input_backpressure_seconds_total",
+                        help="producer seconds held back by a full "
+                             "prefetch queue").inc(put.dur_ns / 1e9)
+                if not more:
+                    return
         except BaseException as e:  # surfaced, in order, on the consumer side
             q.put(("error", e))
 
+    def async_supported(self) -> bool:
+        return False    # already async — wrapping would double-thread
+
     def _start(self):
         self._done = False
+        self._taken = 0
         self._thread = threading.Thread(target=self._producer,
                                         args=(self._queue,), daemon=True)
         self._thread.start()
@@ -267,8 +299,27 @@ class AsyncDataSetIterator(DataSetIterator):
         self._done = True
 
     def _ensure(self):
-        if self._peek is None and not self._done:
+        if self._peek is not None or self._done:
+            return
+        # the consumer's wait, measured and attributed: ready says whether
+        # the item was there when the consumer came for it
+        depth = self._queue.qsize()
+        with get_tracer().span("input:wait", batch=self._taken,
+                               ready=int(depth > 0), depth=depth) as wait:
             self._peek = self._queue.get()
+        self._taken += 1
+        reg = get_registry()
+        reg.counter("input_stall_seconds_total",
+                    help="consumer seconds blocked waiting on the input "
+                         "pipeline (the chip-starvation measure)"
+                    ).inc(wait.dur_ns / 1e9)
+        if self._peek[0] == "data":
+            reg.counter("input_batches_total",
+                        help="batches emitted by the input pipeline").inc()
+        if not depth:
+            reg.counter("input_empty_takes_total",
+                        help="takes that found the prefetch queue empty"
+                        ).inc()
 
     def has_next(self):
         if self._done:
@@ -327,11 +378,10 @@ class DevicePrefetchIterator(AsyncDataSetIterator):
         self._device = device
         super().__init__(base, queue_size=queue_size)
 
-    def _producer(self, q: "queue.Queue"):
+    def _stage(self, ds: DataSet) -> DataSet:
         import jax
-        import jax.numpy as jnp
 
-        def put(arr, cast: bool):
+        def cast(arr, narrow: bool):
             if arr is None:
                 return None
             # cast on the HOST (numpy + ml_dtypes) so the host→device
@@ -339,19 +389,23 @@ class DevicePrefetchIterator(AsyncDataSetIterator):
             # host-to-device bytes; jnp.asarray first would transfer
             # f32 and cast device-side.
             a = np.asarray(arr)
-            if cast and self._dtype is not None \
+            if narrow and self._dtype is not None \
                     and np.issubdtype(a.dtype, np.floating):
                 a = a.astype(self._dtype)
-            return (jax.device_put(a) if self._device is None
-                    else jax.device_put(a, self._device))
+            return a
 
-        try:
-            while self._base.has_next():
-                ds = self._base.next()
-                q.put(("data", DataSet(
-                    put(ds.features, True), put(ds.labels, True),
-                    put(ds.features_mask, False),
-                    put(ds.labels_mask, False))))
-            q.put(("end", None))
-        except BaseException as e:
-            q.put(("error", e))
+        tracer, reg = get_tracer(), get_registry()
+        with tracer.span("input:cast") as span:
+            host = (cast(ds.features, True), cast(ds.labels, True),
+                    cast(ds.features_mask, False),
+                    cast(ds.labels_mask, False))
+        reg.counter("input_cast_seconds_total",
+                    help="producer seconds casting batches on the host"
+                    ).inc(span.dur_ns / 1e9)
+        with tracer.span("input:h2d") as span:
+            staged = (jax.device_put(host) if self._device is None
+                      else jax.device_put(host, self._device))
+        reg.counter("input_h2d_seconds_total",
+                    help="wall seconds staging batches on device"
+                    ).inc(span.dur_ns / 1e9)
+        return DataSet(*staged)

@@ -22,18 +22,19 @@ import numpy as np
 
 from deeplearning4j_tpu.datasets.dataset import DataSet, MultiDataSet
 from deeplearning4j_tpu.datasets.iterator import (
-    AsyncDataSetIterator, DataSetIterator, ListDataSetIterator,
+    DataSetIterator, ListDataSetIterator,
 )
 from deeplearning4j_tpu.nn.conf.graph import (
     DuplicateToTimeSeriesVertex, LastTimeStepVertex)
 from deeplearning4j_tpu.nn.conf.graph_builder import ComputationGraphConfiguration
 from deeplearning4j_tpu.nn.netcommon import (CostAnalysisMixin, EvalMixin,
-                                              LazyScoreMixin, jit_init,
-                                              ScanFitMixin, SentinelMixin,
-                                              ShardCheckMixin,
+                                              FitLoopMixin, LazyScoreMixin,
+                                              jit_init, ScanFitMixin,
+                                              SentinelMixin, ShardCheckMixin,
 )
 from deeplearning4j_tpu.nn.updater import build_optimizer, compute_updates
 from deeplearning4j_tpu.optimize.listeners import IterationListener, TrainingListener
+from deeplearning4j_tpu.profiling.tracer import get_tracer
 
 Array = jax.Array
 
@@ -59,7 +60,7 @@ def _time_slice(d: Optional[Dict[str, Array]], lo: int, hi: int,
             for k, v in d.items()}
 
 
-class ComputationGraph(LazyScoreMixin, EvalMixin, ScanFitMixin,
+class ComputationGraph(LazyScoreMixin, EvalMixin, FitLoopMixin, ScanFitMixin,
                        CostAnalysisMixin, ShardCheckMixin, SentinelMixin):
     def __init__(self, conf: ComputationGraphConfiguration):
         self.conf = conf
@@ -186,70 +187,76 @@ class ComputationGraph(LazyScoreMixin, EvalMixin, ScanFitMixin,
                 acts[name] = inputs[name]
                 out_masks[name] = (masks or {}).get(name)
                 continue
-            in_acts = [acts[i] for i in node.inputs]
-            in_mask = out_masks.get(node.inputs[0]) if node.inputs else None
-            if node.kind == "vertex":
-                if isinstance(node.vertex, LastTimeStepVertex):
-                    acts[name] = node.vertex.apply_masked(in_acts, in_mask)
-                    out_masks[name] = None
-                elif isinstance(node.vertex, DuplicateToTimeSeriesVertex) \
-                        and isinstance(node.vertex.timesteps, str):
-                    # runtime T from the named reference node's activation
-                    acts[name] = node.vertex.apply(
-                        in_acts, acts[node.vertex.timesteps])
-                    out_masks[name] = in_mask
+            # the scope puts the node's name into the op_name of every
+            # operation, and so of every fusion, it lowers to
+            with jax.named_scope(name):
+                in_acts = [acts[i] for i in node.inputs]
+                in_mask = (out_masks.get(node.inputs[0]) if node.inputs
+                           else None)
+                if node.kind == "vertex":
+                    if isinstance(node.vertex, LastTimeStepVertex):
+                        acts[name] = node.vertex.apply_masked(in_acts, in_mask)
+                        out_masks[name] = None
+                    elif isinstance(node.vertex, DuplicateToTimeSeriesVertex) \
+                            and isinstance(node.vertex.timesteps, str):
+                        # runtime T from the named reference node's activation
+                        acts[name] = node.vertex.apply(
+                            in_acts, acts[node.vertex.timesteps])
+                        out_masks[name] = in_mask
+                    else:
+                        acts[name] = node.vertex.apply(in_acts)
+                        out_masks[name] = in_mask
+                    continue
+                # layer node
+                h = in_acts[0]
+                cur_mask = in_mask
+                if node.preprocessor is not None:
+                    h = node.preprocessor.transform(h, None)
+                    cur_mask = node.preprocessor.transform_mask(cur_mask, None)
+                layer = node.layer
+                if rng is not None:
+                    rng, sub = jax.random.split(rng)
                 else:
-                    acts[name] = node.vertex.apply(in_acts)
-                    out_masks[name] = in_mask
-                continue
-            # layer node
-            h = in_acts[0]
-            cur_mask = in_mask
-            if node.preprocessor is not None:
-                h = node.preprocessor.transform(h, None)
-                cur_mask = node.preprocessor.transform_mask(cur_mask, None)
-            layer = node.layer
-            if rng is not None:
-                rng, sub = jax.random.split(rng)
-            else:
-                sub = None
-            if (stop_before_loss and name in output_set
-                    and hasattr(layer, "compute_loss")):
-                acts[name] = h          # input to the loss head
-                out_masks[name] = cur_mask
-                new_states[name] = states[name]
-                continue
-            # remat (conf.gradient_checkpointing): recompute in backward
-            remat = train and self.conf.training.remat
-            if carries is not None and getattr(layer, "supports_carry", False):
-                c_in = carries.get(name)
-                if c_in is None:
-                    c_in = layer.initial_carry(h.shape[0], h.dtype)
-                # scan() bypasses apply(): input dropout must still fire
-                # so tBPTT training regularizes like standard BPTT
-                h = layer._dropout_input(h, train and not layer.frozen, sub)
-                scan_fn = (jax.checkpoint(layer.scan) if remat
-                           else layer.scan)
-                h, c_out = scan_fn(self._layer_params(params, name), h,
-                                   c_in, cur_mask)
-                new_carries[name] = c_out
-                s = states[name]
-            else:
-                layer_train = train and not layer.frozen
-
-                def apply_fn(p, hh, s_in, r, m, _l=layer, _t=layer_train):
-                    return _l.apply(p, hh, state=s_in, train=_t, rng=r,
-                                    mask=m)
-                if remat:
-                    apply_fn = jax.checkpoint(apply_fn)
-                h, s = apply_fn(self._layer_params(params, name), h,
-                                states[name], sub, cur_mask)
-                if layer.frozen:
+                    sub = None
+                if (stop_before_loss and name in output_set
+                        and hasattr(layer, "compute_loss")):
+                    acts[name] = h          # input to the loss head
+                    out_masks[name] = cur_mask
+                    new_states[name] = states[name]
+                    continue
+                # remat (conf.gradient_checkpointing): recompute in backward
+                remat = train and self.conf.training.remat
+                if carries is not None \
+                        and getattr(layer, "supports_carry", False):
+                    c_in = carries.get(name)
+                    if c_in is None:
+                        c_in = layer.initial_carry(h.shape[0], h.dtype)
+                    # scan() bypasses apply(): input dropout must still fire
+                    # so tBPTT training regularizes like standard BPTT
+                    h = layer._dropout_input(
+                        h, train and not layer.frozen, sub)
+                    scan_fn = (jax.checkpoint(layer.scan) if remat
+                               else layer.scan)
+                    h, c_out = scan_fn(self._layer_params(params, name), h,
+                                       c_in, cur_mask)
+                    new_carries[name] = c_out
                     s = states[name]
-            acts[name] = h
-            # layers that consume or rearrange the time axis drop the mask
-            out_masks[name] = layer.propagate_mask(cur_mask)
-            new_states[name] = s
+                else:
+                    layer_train = train and not layer.frozen
+
+                    def apply_fn(p, hh, s_in, r, m, _l=layer, _t=layer_train):
+                        return _l.apply(p, hh, state=s_in, train=_t, rng=r,
+                                        mask=m)
+                    if remat:
+                        apply_fn = jax.checkpoint(apply_fn)
+                    h, s = apply_fn(self._layer_params(params, name), h,
+                                    states[name], sub, cur_mask)
+                    if layer.frozen:
+                        s = states[name]
+                acts[name] = h
+                # layers that consume or rearrange the time axis drop the mask
+                out_masks[name] = layer.propagate_mask(cur_mask)
+                new_states[name] = s
         if carries is not None:
             return acts, out_masks, new_states, new_carries
         return acts, out_masks, new_states
@@ -413,12 +420,8 @@ class ComputationGraph(LazyScoreMixin, EvalMixin, ScanFitMixin,
         # whole state every step (HBM traffic + footprint)
         return jax.jit(train_step, donate_argnums=(0, 1, 2))
 
-    def fit_batch(self, data: Union[DataSet, MultiDataSet]) -> float:
-        """One optimization step (ref: ComputationGraph.fit).
-
-        NOTE: previous ``params``/``opt_state``/``states`` buffers are
-        DONATED to the jitted step — external aliases held across a step
-        raise "Array has been deleted"; ``np.asarray``-copy first."""
+    def _fit_batch(self, data: Union[DataSet, MultiDataSet]) -> float:
+        """``fit_batch`` under its span (ref: ComputationGraph.fit)."""
         self._check_init()
         algo = self.conf.training.optimization_algo
         if algo not in ("sgd", "stochastic_gradient_descent"):
@@ -458,26 +461,7 @@ class ComputationGraph(LazyScoreMixin, EvalMixin, ScanFitMixin,
                     "for sequence-to-one heads")
         if self._train_step_fn is None:
             self._train_step_fn = self._build_train_step()
-        inputs, labels, masks, lmasks = self._split(data)
-        self._rng, step_rng = jax.random.split(self._rng)
-        from deeplearning4j_tpu.profiling import get_tracer
-        # host-side span: the (async) step dispatch — what hangs when a
-        # compile or transfer wedges (see MultiLayerNetwork.fit_batch)
-        with get_tracer().span("fit_batch", it=self.iteration_count + 1):
-            out = self._train_step_fn(
-                self.params, self.opt_state, self.states, inputs, labels,
-                masks, lmasks, step_rng)
-            (self.params, self.opt_state, self.states, loss,
-             self.last_grads) = out[:5]
-        self.last_batch_size = data.num_examples()
-        # raw device scalar — see MultiLayerNetwork.fit_batch: converting
-        # eagerly would sync the pipeline every step
-        self.score_value = loss
-        self.iteration_count += 1
-        self._observe_sentinel(out[5] if len(out) > 5 else None)
-        for listener in self.listeners:
-            listener.iteration_done(self, self.iteration_count, self.score_value)
-        return self._score_raw
+        return self._standard_step(data, self._split)
 
     def fit(self, data, epochs: int = 1, use_async: bool = True,
             scan_window: int = 1) -> "ComputationGraph":
@@ -489,26 +473,12 @@ class ComputationGraph(LazyScoreMixin, EvalMixin, ScanFitMixin,
             batches = [data]
             data = ListDataSetIterator(batches) if isinstance(data, DataSet) else None
             if data is None:
-                for _ in range(epochs):
-                    self.fit_batch(batches[0])
+                with get_tracer().span("fit"):
+                    for _ in range(epochs):
+                        self.fit_batch(batches[0])
                 return self
         assert isinstance(data, DataSetIterator)
-        it = (AsyncDataSetIterator(data)
-              if use_async and data.async_supported() else data)
-        for _ in range(epochs):
-            for listener in self.listeners:
-                if isinstance(listener, TrainingListener):
-                    listener.on_epoch_start(self)
-            if scan_window > 1:
-                self._fit_epoch_scan(it, scan_window)
-            else:
-                for batch in it:
-                    self.fit_batch(batch)
-            self.epoch_count += 1
-            for listener in self.listeners:
-                if isinstance(listener, TrainingListener):
-                    listener.on_epoch_end(self)
-        return self
+        return self._fit_epochs(data, epochs, use_async, scan_window)
 
     def _tbptt_rnn_inputs(self) -> set:
         """Network inputs whose time axis tBPTT may slice: declared-rnn

@@ -31,14 +31,14 @@ import numpy as np
 
 from deeplearning4j_tpu.datasets.dataset import DataSet
 from deeplearning4j_tpu.datasets.iterator import (
-    AsyncDataSetIterator, DataSetIterator, ListDataSetIterator,
+    DataSetIterator, ListDataSetIterator,
 )
 from deeplearning4j_tpu.nn.conf.builder import MultiLayerConfiguration
 from deeplearning4j_tpu.nn.layers.base import BaseLayerConf
 from deeplearning4j_tpu.nn.netcommon import (CostAnalysisMixin, EvalMixin,
-                                              LazyScoreMixin, jit_init,
-                                              ScanFitMixin, SentinelMixin,
-                                              ShardCheckMixin,
+                                              FitLoopMixin, LazyScoreMixin,
+                                              jit_init, ScanFitMixin,
+                                              SentinelMixin, ShardCheckMixin,
 )
 from deeplearning4j_tpu.nn.updater import (
     build_optimizer, compute_updates, l1_l2_penalty,
@@ -66,8 +66,9 @@ def _sum_aux_losses(states) -> Array:
     return total
 
 
-class MultiLayerNetwork(LazyScoreMixin, EvalMixin, ScanFitMixin,
-                        CostAnalysisMixin, ShardCheckMixin, SentinelMixin):
+class MultiLayerNetwork(LazyScoreMixin, EvalMixin, FitLoopMixin,
+                        ScanFitMixin, CostAnalysisMixin, ShardCheckMixin,
+                        SentinelMixin):
     def __init__(self, conf: MultiLayerConfiguration):
         self.conf = conf
         self.layers: List[BaseLayerConf] = conf.layers
@@ -148,52 +149,58 @@ class MultiLayerNetwork(LazyScoreMixin, EvalMixin, ScanFitMixin,
         h = x
         n = len(self.layers)
         for i, layer in enumerate(self.layers):
-            if i in self.conf.preprocessors:
-                it = in_types[i] if in_types else None
-                h = self.conf.preprocessors[i].transform(h, it)
-                cur_mask = self.conf.preprocessors[i].transform_mask(cur_mask, it)
-            if rng is not None:
-                rng, sub = jax.random.split(rng)
-            else:
-                sub = None
-            is_last = i == n - 1
-            if is_last and hasattr(layer, "compute_loss"):
-                # loss head consumes the pre-layer activation
-                acts.append(h)
-                new_states.append(states[i])
-                break
-            # remat: recompute this layer's activations in backward
-            # instead of storing them (conf.gradient_checkpointing) —
-            # trades FLOPs for HBM on memory-bound models
-            remat = train and self.conf.training.remat
-            if carries is not None and getattr(layer, "supports_carry", False):
-                c_in = carries[i]
-                if c_in is None:
-                    c_in = layer.initial_carry(h.shape[0], h.dtype)
-                # scan() bypasses apply(): input dropout must still fire
-                # so tBPTT training regularizes like standard BPTT
-                h = layer._dropout_input(h, train and not layer.frozen, sub)
-                scan_fn = (jax.checkpoint(layer.scan) if remat
-                           else layer.scan)
-                h, c_out = scan_fn(params[i], h, c_in, cur_mask)
-                new_carries[i] = c_out
-                s = states[i]
-            else:
-                layer_train = train and not layer.frozen
+            # the scope puts the layer's name into the op_name of every
+            # operation, and so of every fusion, it lowers to
+            with jax.named_scope(layer.name or f"layer{i}"):
+                if i in self.conf.preprocessors:
+                    it = in_types[i] if in_types else None
+                    pre = self.conf.preprocessors[i]
+                    h = pre.transform(h, it)
+                    cur_mask = pre.transform_mask(cur_mask, it)
+                if rng is not None:
+                    rng, sub = jax.random.split(rng)
+                else:
+                    sub = None
+                is_last = i == n - 1
+                if is_last and hasattr(layer, "compute_loss"):
+                    # loss head consumes the pre-layer activation
+                    acts.append(h)
+                    new_states.append(states[i])
+                    break
+                # remat: recompute this layer's activations in backward
+                # instead of storing them (conf.gradient_checkpointing) —
+                # trades FLOPs for HBM on memory-bound models
+                remat = train and self.conf.training.remat
+                if carries is not None \
+                        and getattr(layer, "supports_carry", False):
+                    c_in = carries[i]
+                    if c_in is None:
+                        c_in = layer.initial_carry(h.shape[0], h.dtype)
+                    # scan() bypasses apply(): input dropout must still fire
+                    # so tBPTT training regularizes like standard BPTT
+                    h = layer._dropout_input(
+                        h, train and not layer.frozen, sub)
+                    scan_fn = (jax.checkpoint(layer.scan) if remat
+                               else layer.scan)
+                    h, c_out = scan_fn(params[i], h, c_in, cur_mask)
+                    new_carries[i] = c_out
+                    s = states[i]
+                else:
+                    layer_train = train and not layer.frozen
 
-                def apply_fn(p, hh, s_in, r, m, _l=layer, _t=layer_train):
-                    return _l.apply(p, hh, state=s_in, train=_t, rng=r,
-                                    mask=m)
-                if remat:
-                    apply_fn = jax.checkpoint(apply_fn)
-                h, s = apply_fn(params[i], h, states[i], sub, cur_mask)
-                if layer.frozen:
-                    s = states[i]  # frozen: BN running stats don't move
-            # layers that consume or rearrange the time axis drop the mask
-            cur_mask = layer.propagate_mask(cur_mask)
-            new_states.append(s)
-            if collect:
-                acts.append(h)
+                    def apply_fn(p, hh, s_in, r, m, _l=layer, _t=layer_train):
+                        return _l.apply(p, hh, state=s_in, train=_t, rng=r,
+                                        mask=m)
+                    if remat:
+                        apply_fn = jax.checkpoint(apply_fn)
+                    h, s = apply_fn(params[i], h, states[i], sub, cur_mask)
+                    if layer.frozen:
+                        s = states[i]  # frozen: BN running stats don't move
+                # layers that consume or rearrange the time axis drop the mask
+                cur_mask = layer.propagate_mask(cur_mask)
+                new_states.append(s)
+                if collect:
+                    acts.append(h)
         return h, acts, new_states, new_carries, cur_mask
 
     def feed_forward(self, x, train: bool = False) -> List[Array]:
@@ -333,14 +340,8 @@ class MultiLayerNetwork(LazyScoreMixin, EvalMixin, ScanFitMixin,
 
         return jax.jit(train_step, donate_argnums=(0, 1, 2))
 
-    def fit_batch(self, dataset: DataSet) -> float:
-        """One optimization step on one minibatch (ref: fit(DataSet)).
-
-        NOTE: the previous ``net.params`` / ``net.opt_state`` /
-        ``net.states`` device buffers are DONATED to the step (ResNet-scale
-        nets must not copy their whole state every step). External aliases
-        held across a step raise "Array has been deleted" on access — copy
-        with ``np.asarray`` first if you need before/after snapshots."""
+    def _fit_batch(self, dataset: DataSet) -> float:
+        """``fit_batch`` under its span (ref: fit(DataSet))."""
         self._check_init()
         algo = self.conf.training.optimization_algo
         if algo not in ("sgd", "stochastic_gradient_descent"):
@@ -361,33 +362,16 @@ class MultiLayerNetwork(LazyScoreMixin, EvalMixin, ScanFitMixin,
                     f"labels; got rank-{dataset.labels.ndim}. Use "
                     "backprop_type('standard') for sequence-to-one heads.")
             return self._fit_tbptt(dataset)
-        self._rng, step_rng = jax.random.split(self._rng)
-        fmask = None if dataset.features_mask is None else jnp.asarray(dataset.features_mask)
-        lmask = None if dataset.labels_mask is None else jnp.asarray(dataset.labels_mask)
-        from deeplearning4j_tpu.profiling import get_tracer
-        # host-side span: measures the (async) step dispatch, which is
-        # exactly what hangs when a compile or transfer wedges
-        with get_tracer().span("fit_batch", it=self.iteration_count + 1):
-            out = self._train_step_fn(
-                self.params, self.opt_state, self.states,
-                jnp.asarray(dataset.features),
-                jnp.asarray(dataset.labels),
-                fmask, lmask, step_rng)
-            (self.params, self.opt_state, self.states, loss,
-             self.last_grads) = out[:5]
-        self.last_batch_size = dataset.num_examples()
         self.last_input = dataset.features  # for visualization listeners
-        # store the RAW device scalar: converting here would force a
-        # device sync every step (a full round-trip on a remote-TPU link),
-        # serializing the dispatch pipeline. The score_value property
-        # converts on first read (listeners below, score(), callers that
-        # float() the return value).
-        self.score_value = loss
-        self.iteration_count += 1
-        self._observe_sentinel(out[5] if len(out) > 5 else None)
-        for listener in self.listeners:
-            listener.iteration_done(self, self.iteration_count, self.score_value)
-        return self._score_raw
+        return self._standard_step(dataset, self._batch_args)
+
+    @staticmethod
+    def _batch_args(dataset: DataSet):
+        """The jitted step's batch arguments, as device arrays."""
+        as_array = lambda a: None if a is None else jnp.asarray(a)
+        return (jnp.asarray(dataset.features), jnp.asarray(dataset.labels),
+                as_array(dataset.features_mask),
+                as_array(dataset.labels_mask))
 
     # ------------------------------------------------------------------ tBPTT
     def _build_tbptt_step(self):
@@ -546,22 +530,7 @@ class MultiLayerNetwork(LazyScoreMixin, EvalMixin, ScanFitMixin,
         if isinstance(data, DataSet):
             data = ListDataSetIterator([data])
         assert isinstance(data, DataSetIterator)
-        it = (AsyncDataSetIterator(data)
-              if use_async and data.async_supported() else data)
-        for _ in range(epochs):
-            for listener in self.listeners:
-                if isinstance(listener, TrainingListener):
-                    listener.on_epoch_start(self)
-            if scan_window > 1:
-                self._fit_epoch_scan(it, scan_window)
-            else:
-                for batch in it:  # __iter__ resets the (async) iterator
-                    self.fit_batch(batch)
-            self.epoch_count += 1
-            for listener in self.listeners:
-                if isinstance(listener, TrainingListener):
-                    listener.on_epoch_end(self)
-        return self
+        return self._fit_epochs(data, epochs, use_async, scan_window)
 
     # --------------------------------------------------------------- pretrain
     def pretrain(self, iterator: DataSetIterator, epochs: int = 1) -> None:

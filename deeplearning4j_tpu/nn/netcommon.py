@@ -14,11 +14,18 @@ two copies from drifting:
   per-tensor init compiles + dispatches hundreds of tiny device programs
   (one per shape) — minutes over a remote-TPU link; jitted it is a single
   compile and a single execution.
+- ``FitLoopMixin``: the epoch loop of ``fit`` and the standard step of
+  ``fit_batch``, written once so that they are instrumented once.
 """
 
 from __future__ import annotations
 
 import jax
+
+from deeplearning4j_tpu.datasets.iterator import AsyncDataSetIterator
+from deeplearning4j_tpu.optimize.listeners import TrainingListener
+from deeplearning4j_tpu.profiling.metrics import get_registry
+from deeplearning4j_tpu.profiling.tracer import get_tracer
 
 
 class LazyScoreMixin:
@@ -80,6 +87,100 @@ class SentinelMixin:
         raise per policy — see resilience/sentinel.py)."""
         if self._sentinel is not None and flag is not None:
             self._sentinel.observe(flag, self.iteration_count)
+
+
+class FitLoopMixin:
+    """``fit``'s epoch loop and ``fit_batch``'s standard step for both
+    containers, under one set of host spans (what hangs when a compile
+    or a transfer wedges, and where the loop's time goes between two
+    steps) and counters:
+
+        fit                       one per fit() call
+          input:wait              the feed's queue (datasets/iterator.py)
+          fit_batch  it= batch=   the whole of fit_batch; fit_steps_total
+            fit:split             the batch as device arrays
+            fit:rng               the step's key
+            fit:dispatch          the jitted step's (async) dispatch;
+                                  fit_dispatch_seconds_total
+            fit:listeners         the sentinel's drain and the listeners
+
+    ``batch`` is the k-th batch of the epoch, the identifier the feed's
+    spans of both threads carry. Containers provide ``_fit_batch(data)``
+    (which takes the standard path through ``_standard_step``) and
+    ``_fit_epoch_scan``."""
+
+    def _fit_epochs(self, data, epochs: int, use_async: bool,
+                    scan_window: int):
+        it = (AsyncDataSetIterator(data)
+              if use_async and data.async_supported() else data)
+        with get_tracer().span("fit"):
+            for _ in range(epochs):
+                for listener in self.listeners:
+                    if isinstance(listener, TrainingListener):
+                        listener.on_epoch_start(self)
+                if scan_window > 1:
+                    self._fit_epoch_scan(it, scan_window)
+                else:
+                    # __iter__ resets the (async) iterator
+                    for k, batch in enumerate(it):
+                        self._spanned_fit_batch(batch, batch=k)
+                self.epoch_count += 1
+                for listener in self.listeners:
+                    if isinstance(listener, TrainingListener):
+                        listener.on_epoch_end(self)
+        return self
+
+    def fit_batch(self, data) -> float:
+        """One optimization step on one minibatch (ref: fit(DataSet) /
+        ComputationGraph.fit).
+
+        NOTE: the previous ``net.params`` / ``net.opt_state`` /
+        ``net.states`` device buffers are DONATED to the step (ResNet-scale
+        nets must not copy their whole state every step). External aliases
+        held across a step raise "Array has been deleted" on access — copy
+        with ``np.asarray`` first if you need before/after snapshots."""
+        return self._spanned_fit_batch(data)
+
+    def _spanned_fit_batch(self, data, **ids) -> float:
+        with get_tracer().span("fit_batch", it=self.iteration_count + 1,
+                               **ids):
+            loss = self._fit_batch(data)
+        get_registry().counter(
+            "fit_steps_total", help="fit_batch calls completed").inc()
+        return loss
+
+    def _standard_step(self, data, split) -> float:
+        """The standard-backprop step: ``split(data)`` gives the jitted
+        step's batch arguments (features, labels and the two masks, as
+        device arrays)."""
+        tracer = get_tracer()
+        with tracer.span("fit:split"):
+            batch_args = split(data)
+        with tracer.span("fit:rng"):
+            self._rng, step_rng = jax.random.split(self._rng)
+        with tracer.span("fit:dispatch") as dispatch:
+            out = self._train_step_fn(self.params, self.opt_state,
+                                      self.states, *batch_args, step_rng)
+            (self.params, self.opt_state, self.states, loss,
+             self.last_grads) = out[:5]
+        get_registry().counter(
+            "fit_dispatch_seconds_total",
+            help="host seconds dispatching the jitted train step"
+        ).inc(dispatch.dur_ns / 1e9)
+        self.last_batch_size = data.num_examples()
+        # store the RAW device scalar: converting here would force a
+        # device sync every step (a full round-trip on a remote-TPU link),
+        # serializing the dispatch pipeline. The score_value property
+        # converts on first read (listeners below, score(), callers that
+        # float() the return value).
+        self.score_value = loss
+        self.iteration_count += 1
+        with tracer.span("fit:listeners"):
+            self._observe_sentinel(out[5] if len(out) > 5 else None)
+            for listener in self.listeners:
+                listener.iteration_done(self, self.iteration_count,
+                                        self.score_value)
+        return self._score_raw
 
 
 class EvalMixin:

@@ -36,7 +36,7 @@ supervisor and lint tooling.
 """
 
 from deeplearning4j_tpu.profiling.tracer import (  # noqa: F401
-    Tracer, get_tracer, set_tracer, span,
+    Tracer, get_tracer, self_times, set_tracer,
 )
 from deeplearning4j_tpu.profiling.metrics import (  # noqa: F401
     Counter, Gauge, Histogram, MetricsRegistry, get_registry, set_registry,
@@ -55,7 +55,7 @@ from deeplearning4j_tpu.profiling.cost import (  # noqa: F401
 )
 
 __all__ = [
-    "Tracer", "get_tracer", "set_tracer", "span",
+    "Tracer", "get_tracer", "self_times", "set_tracer",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "get_registry",
     "set_registry",
     "FlightRecorder", "get_flightrec", "set_flightrec",

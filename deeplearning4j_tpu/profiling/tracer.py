@@ -17,47 +17,77 @@ A process-global default tracer (``get_tracer()``) is what the
 containers, the parallel trainers, and ``bench.py`` emit into; the
 buffer is bounded (oldest events drop, counted) so a week-long training
 run cannot leak memory into the tracer. Timing is host wall time
-(``perf_counter``): a span around an unsynced jit dispatch measures
-dispatch, not device compute — sync first (as the TrainingStats phases
-do) when the device time is the question.
+(``perf_counter_ns``, whole nanoseconds): a span around an unsynced jit
+dispatch measures dispatch, not device compute — sync first (as the
+TrainingStats phases do) when the device time is the question.
+
+Every span has an ``id`` and a ``parent`` (the innermost span open on
+the same thread when it began), so ``self_times`` can give each span
+its duration less what its children cover. ``with tracer.span(...)``
+also enters a ``jax.profiler.TraceAnnotation`` named ``dl4j:<name>``
+when jax is loaded: under a profiler session the program's spans then
+lie in the profiler's own trace, on the device trace's clock. This
+module never imports jax.
 """
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 from typing import Dict, List, Optional
+
+# prefix of the spans mirrored into jax.profiler's trace
+ANNOTATION_PREFIX = "dl4j:"
 
 
 class _SpanHandle:
     """Token returned by ``Tracer.begin`` — pass it back to ``end``."""
 
-    __slots__ = ("name", "t0_us", "tid", "args", "closed")
+    __slots__ = ("name", "id", "parent", "t0_ns", "dur_ns", "tid", "args",
+                 "closed")
 
-    def __init__(self, name: str, t0_us: float, tid: int, args: dict):
+    def __init__(self, name: str, span_id: int, t0_ns: int, tid: int,
+                 args: dict):
         self.name = name
-        self.t0_us = t0_us
+        self.id = span_id
+        self.parent: Optional[int] = None
+        self.t0_ns = t0_ns
+        self.dur_ns = 0         # set by end(): counters read it
         self.tid = tid
         self.args = args
         self.closed = False
 
 
 class _SpanCtx:
-    """Context manager wrapping one begin/end pair (re-entrant safe:
-    every ``with`` creates a fresh instance)."""
+    """Context manager wrapping one begin/end pair on one thread, and
+    its mirror in jax.profiler's trace (re-entrant safe: every ``with``
+    creates a fresh instance)."""
 
-    __slots__ = ("_tracer", "_handle")
+    __slots__ = ("_tracer", "_name", "_args", "_handle", "_mirror")
 
-    def __init__(self, tracer: "Tracer", handle: _SpanHandle):
+    def __init__(self, tracer: "Tracer", name: str, args: dict):
         self._tracer = tracer
-        self._handle = handle
+        self._name = name
+        self._args = args
 
     def __enter__(self):
+        self._handle = self._tracer.begin(self._name, **self._args)
+        jax = sys.modules.get("jax")
+        if jax is None:
+            self._mirror = None
+        else:   # an atomic load unless a profiler session is running
+            self._mirror = jax.profiler.TraceAnnotation(
+                ANNOTATION_PREFIX + self._name, **self._args)
+            self._mirror.__enter__()
         return self._handle
 
     def __exit__(self, exc_type, exc, tb):
+        if self._mirror is not None:
+            self._mirror.__exit__(exc_type, exc, tb)
         if exc is not None:
             # record the span stack the exception unwound through —
             # `open_span_stack()` is empty by the time an outer handler
@@ -70,44 +100,41 @@ class _SpanCtx:
 class Tracer:
     """Bounded-buffer span recorder with Chrome trace-event export."""
 
-    def __init__(self, max_events: int = 200_000, enabled: bool = True):
-        self.enabled = enabled
+    def __init__(self, max_events: int = 200_000):
         self.max_events = max_events
         self._lock = threading.Lock()
         self._events: List[dict] = []
         self._dropped = 0
+        self._ids = itertools.count(1)
         # tid -> open-span stack (list of _SpanHandle, outermost first);
         # a dict (not threading.local) so open_span_stack() can see every
         # thread's in-flight spans — the hang diagnosis requirement
         self._open: Dict[int, List[_SpanHandle]] = {}
         self._error_key: Optional[int] = None
         self._error_stack: List[str] = []
-        self._epoch = time.perf_counter()
 
     # ------------------------------------------------------------ recording
-    def _now_us(self) -> float:
-        return (time.perf_counter() - self._epoch) * 1e6
-
     def begin(self, name: str, **args) -> _SpanHandle:
         """Open a span explicitly (async work); close with ``end()``.
-        ``end`` may run on a different thread than ``begin``."""
+        ``end`` may run on a different thread than ``begin``, so such a
+        pair is not mirrored into jax.profiler's trace."""
         tid = threading.get_ident()
-        h = _SpanHandle(name, self._now_us(), tid, args)
-        if self.enabled:
-            with self._lock:
-                self._open.setdefault(tid, []).append(h)
+        h = _SpanHandle(name, next(self._ids), time.perf_counter_ns(), tid,
+                        args)
+        with self._lock:
+            stack = self._open.setdefault(tid, [])
+            if stack:
+                h.parent = stack[-1].id
+            stack.append(h)
         return h
 
     def end(self, handle: _SpanHandle) -> None:
-        if handle.closed or not self.enabled:
-            handle.closed = True
+        if handle.closed:
             return
         handle.closed = True
-        dur = max(self._now_us() - handle.t0_us, 0.0)
-        ev = {"name": handle.name, "ph": "X", "ts": handle.t0_us,
-              "dur": dur, "pid": os.getpid(), "tid": handle.tid}
-        if handle.args:
-            ev["args"] = dict(handle.args)
+        handle.dur_ns = max(time.perf_counter_ns() - handle.t0_ns, 0)
+        ev = _event(handle.name, "X", handle.t0_ns, handle.tid, handle.args,
+                    handle.id, handle.parent, handle.dur_ns)
         with self._lock:
             stack = self._open.get(handle.tid)
             if stack and handle in stack:
@@ -133,6 +160,11 @@ class Tracer:
         self._events.append(ev)
         return dropped
 
+    def _record(self, ev: dict) -> None:
+        with self._lock:
+            dropped = self._append_locked(ev)
+        self._count_dropped(dropped)
+
     def _count_dropped(self, dropped: int) -> None:
         """Publish buffer evictions as ``tracer_events_dropped`` so
         bounded-buffer truncation shows up on the same ``/api/metrics``
@@ -147,35 +179,25 @@ class Tracer:
         ).inc(dropped)
 
     def span(self, name: str, **args) -> _SpanCtx:
-        """``with tracer.span("shard"):`` — nested spans stack per
-        thread."""
-        return _SpanCtx(self, self.begin(name, **args))
+        """``with tracer.span("shard") as h:`` — nested spans stack per
+        thread; after the block ``h.dur_ns`` is the span's duration."""
+        return _SpanCtx(self, name, args)
 
     def instant(self, name: str, **args) -> None:
         """Zero-duration marker event (ph "i")."""
-        if not self.enabled:
-            return
-        ev = {"name": name, "ph": "i", "ts": self._now_us(), "s": "t",
-              "pid": os.getpid(), "tid": threading.get_ident()}
-        if args:
-            ev["args"] = dict(args)
-        with self._lock:
-            dropped = self._append_locked(ev)
-        self._count_dropped(dropped)
+        ev = _event(name, "i", time.perf_counter_ns(), threading.get_ident(),
+                    args)
+        ev["s"] = "t"
+        self._record(ev)
 
-    def complete(self, name: str, t0_us: float, dur_us: float,
-                 **args) -> None:
-        """Record an already-measured interval (e.g. a compile duration
-        reported after the fact by jax.monitoring)."""
-        if not self.enabled:
-            return
-        ev = {"name": name, "ph": "X", "ts": t0_us, "dur": max(dur_us, 0.0),
-              "pid": os.getpid(), "tid": threading.get_ident()}
-        if args:
-            ev["args"] = dict(args)
-        with self._lock:
-            dropped = self._append_locked(ev)
-        self._count_dropped(dropped)
+    def complete(self, name: str, dur_ns: int, **args) -> None:
+        """Record an interval of ``dur_ns`` that ended now, measured by
+        someone else (e.g. a compile duration reported after the fact by
+        jax.monitoring)."""
+        dur_ns = max(int(dur_ns), 0)
+        self._record(_event(
+            name, "X", time.perf_counter_ns() - dur_ns,
+            threading.get_ident(), args, next(self._ids), None, dur_ns))
 
     def _note_error(self, handle: _SpanHandle, exc: BaseException) -> None:
         """Called by span contexts as an exception unwinds through them
@@ -199,15 +221,15 @@ class Tracer:
         start time (outermost/oldest first) — the hang diagnosis."""
         with self._lock:
             live = [h for stack in self._open.values() for h in stack]
-        return [h.name for h in sorted(live, key=lambda h: h.t0_us)]
+        return [h.name for h in sorted(live, key=lambda h: h.t0_ns)]
 
     def open_spans_by_thread(self) -> Dict[int, List[dict]]:
         """Per-thread in-flight spans, outermost first: tid -> list of
-        ``{name, t0_us, args}``. The diagnostic-bundle form — the stall
+        ``{name, t0_ns, args}``. The diagnostic-bundle form — the stall
         culprit is the DEEPEST open span of the stale subsystem's
         thread, which the flat ``open_span_stack`` cannot attribute."""
         with self._lock:
-            return {tid: [{"name": h.name, "t0_us": h.t0_us,
+            return {tid: [{"name": h.name, "t0_ns": h.t0_ns,
                            "args": dict(h.args)} for h in stack]
                     for tid, stack in self._open.items() if stack}
 
@@ -222,7 +244,10 @@ class Tracer:
     # --------------------------------------------------------------- export
     def export(self) -> dict:
         """Chrome trace-event JSON object (the ``traceEvents`` wrapper
-        form both Perfetto and chrome://tracing accept)."""
+        form both Perfetto and chrome://tracing accept). Beside Chrome's
+        microsecond ``ts``/``dur`` every event carries ``ts_ns`` (and a
+        span ``dur_ns``, ``id``, ``parent``): whole nanoseconds of
+        ``time.perf_counter_ns()``."""
         with self._lock:
             events = [dict(e) for e in self._events]
         return {"traceEvents": events,
@@ -248,6 +273,43 @@ class Tracer:
             self._error_stack = []
 
 
+def _event(name: str, ph: str, ts_ns: int, tid: int, args: dict,
+           span_id: Optional[int] = None, parent: Optional[int] = None,
+           dur_ns: Optional[int] = None) -> dict:
+    """One exported event; a span (``span_id`` given) carries its id, its
+    parent and its duration, an instant does not."""
+    ev = {"name": name, "ph": ph, "ts": ts_ns / 1e3, "ts_ns": ts_ns,
+          "pid": os.getpid(), "tid": tid}
+    if span_id is not None:
+        ev.update(id=span_id, parent=parent, dur_ns=dur_ns, dur=dur_ns / 1e3)
+    if args:
+        ev["args"] = dict(args)
+    return ev
+
+
+def self_times(events: List[dict]) -> Dict[int, int]:
+    """``{span id: nanoseconds}``: each span's duration less the part of
+    its interval that its children (by ``parent``) cover. ``events`` are
+    exported events; those without an ``id`` (instants) are passed over."""
+    spans = [e for e in events if "id" in e]
+    children: Dict[int, List[dict]] = {}
+    for e in spans:
+        if e["parent"] is not None:
+            children.setdefault(e["parent"], []).append(e)
+    out = {}
+    for e in spans:
+        lo, hi = e["ts_ns"], e["ts_ns"] + e["dur_ns"]
+        covered, edge = 0, lo
+        for c in sorted(children.get(e["id"], ()), key=lambda c: c["ts_ns"]):
+            c_lo = max(c["ts_ns"], edge)
+            c_hi = min(c["ts_ns"] + c["dur_ns"], hi)
+            if c_hi > c_lo:
+                covered += c_hi - c_lo
+                edge = c_hi
+        out[e["id"]] = e["dur_ns"] - covered
+    return out
+
+
 # ---------------------------------------------------------------------------
 # process-global default tracer
 # ---------------------------------------------------------------------------
@@ -268,8 +330,3 @@ def set_tracer(tracer: Tracer) -> Tracer:
     with _default_lock:
         prev, _default = _default, tracer
     return prev
-
-
-def span(name: str, **args) -> _SpanCtx:
-    """``with profiling.span("epoch"):`` on the global tracer."""
-    return _default.span(name, **args)

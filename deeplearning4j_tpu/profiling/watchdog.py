@@ -117,7 +117,7 @@ def _find_culprit(stale: Optional[Dict[str, Any]],
     deepest, deepest_tid = None, None
     for t, stack in open_spans.items():
         if stack and (deepest is None
-                      or stack[-1]["t0_us"] > deepest["t0_us"]):
+                      or stack[-1]["t0_ns"] > deepest["t0_ns"]):
             deepest, deepest_tid = stack[-1], t
     if deepest is not None:
         return {"subsystem": subsystem, "tid": int(deepest_tid),

@@ -117,9 +117,7 @@ class CompileWatcher:
                 "jax_compile_seconds", help="per-program XLA compile time",
                 buckets=_COMPILE_TIME_BUCKETS).observe(duration)
             # mirror into the trace timeline, backdated by the duration
-            self.tracer.complete(span_name,
-                                 self.tracer._now_us() - duration * 1e6,
-                                 duration * 1e6)
+            self.tracer.complete(span_name, int(duration * 1e9))
             if duration >= self.warn_compile_s:
                 logger.warning("XLA compile took %.1fs — if this step "
                                "already ran, something changed its "

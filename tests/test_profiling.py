@@ -22,6 +22,7 @@ from deeplearning4j_tpu.profiling import (
     set_flightrec, set_tracer, train_step_cost,
 )
 from deeplearning4j_tpu.profiling import watchdog as watchdog_mod
+from deeplearning4j_tpu.profiling.tracer import ANNOTATION_PREFIX, self_times
 from deeplearning4j_tpu.profiling.metrics import set_registry
 from deeplearning4j_tpu.profiling.watchdog import (
     BUNDLE_FORMAT, beat, clear_beats, heartbeat_ages,
@@ -103,6 +104,181 @@ def test_begin_end_across_threads():
     assert [e["name"] for e in tr.export()["traceEvents"]] == ["prefetch"]
 
 
+def _by_name(tr):
+    return {e["name"]: e for e in tr.export()["traceEvents"]}
+
+
+def test_span_ids_and_parents_nested():
+    tr = Tracer()
+    with tr.span("outer") as outer:
+        with tr.span("first") as first:
+            with tr.span("leaf"):
+                pass
+        with tr.span("second"):
+            pass
+    with tr.span("alone"):
+        pass
+    ev = _by_name(tr)
+    ids = [e["id"] for e in ev.values()]
+    assert all(isinstance(i, int) for i in ids) and len(set(ids)) == 5
+    assert ev["outer"]["parent"] is None and ev["alone"]["parent"] is None
+    assert ev["first"]["parent"] == ev["second"]["parent"] == outer.id
+    assert ev["leaf"]["parent"] == first.id == ev["first"]["id"]
+    json.dumps(tr.export())     # id and parent survive the exporter
+
+
+def test_span_parent_is_per_thread():
+    """A span's parent is the innermost span open on ITS thread: a span
+    open on another thread at the same time is no parent."""
+    tr = Tracer()
+    started, release = threading.Event(), threading.Event()
+
+    def feed():
+        with tr.span("feed:outer"):
+            started.set()
+            assert release.wait(5)
+            with tr.span("feed:inner"):
+                pass
+
+    t = threading.Thread(target=feed)
+    with tr.span("main:outer"):
+        t.start()
+        assert started.wait(5)
+        with tr.span("main:inner"):
+            release.set()
+            t.join(5)
+    assert not t.is_alive()
+    ev = _by_name(tr)
+    assert ev["main:inner"]["parent"] == ev["main:outer"]["id"]
+    assert ev["feed:inner"]["parent"] == ev["feed:outer"]["id"]
+    assert ev["feed:outer"]["parent"] is None
+    assert ev["feed:outer"]["tid"] != ev["main:outer"]["tid"]
+
+
+def test_begin_end_across_threads_keeps_id_parent_and_thread():
+    tr = Tracer()
+    with tr.span("outer") as outer:
+        h = tr.begin("prefetch")        # parent: open here, on this thread
+        t = threading.Thread(target=tr.end, args=(h,))
+        t.start()
+        t.join(5)
+        with tr.span("after") as after:     # the ended span is off the stack
+            pass
+    ev = _by_name(tr)
+    assert ev["prefetch"]["parent"] == outer.id
+    assert ev["prefetch"]["tid"] == threading.get_ident()
+    assert ev["after"]["parent"] == outer.id and after.id > h.id
+
+
+def test_tracer_clock_is_integer_nanoseconds():
+    tr = Tracer()
+    before = time.perf_counter_ns()
+    with tr.span("s") as h:
+        time.sleep(0.002)
+    tr.instant("mark")
+    tr.complete("compile", 5_000_000)
+    after = time.perf_counter_ns()
+    s, mark, done = tr.export()["traceEvents"]
+    for e in (s, mark, done):
+        assert type(e["ts_ns"]) is int
+        assert e["ts"] == e["ts_ns"] / 1e3      # Chrome's microseconds
+    assert before <= s["ts_ns"] <= mark["ts_ns"] <= after
+    assert type(s["dur_ns"]) is int and s["dur_ns"] >= 2_000_000
+    assert s["dur"] == s["dur_ns"] / 1e3 and h.dur_ns == s["dur_ns"]
+    assert done["dur_ns"] == 5_000_000 and done["parent"] is None
+    # backdated by its duration from the instant it was reported
+    assert mark["ts_ns"] <= done["ts_ns"] + 5_000_000 <= after
+    assert "dur_ns" not in mark and "id" not in mark
+
+
+def _ev(i, parent, t0, dur):
+    return {"name": f"s{i}", "id": i, "parent": parent, "ts_ns": t0,
+            "dur_ns": dur}
+
+
+@pytest.mark.parametrize("events,want", [
+    # a leaf's self time is its duration; a parent's is less its children
+    ([_ev(1, None, 0, 100), _ev(2, 1, 10, 30), _ev(3, 1, 50, 20)],
+     {1: 50, 2: 30, 3: 20}),
+    # grandchildren count against their parent only
+    ([_ev(1, None, 0, 100), _ev(2, 1, 10, 60), _ev(3, 2, 20, 40)],
+     {1: 40, 2: 20, 3: 40}),
+    # children that overlap (ended on another thread) cover once, and a
+    # child that outlives its parent covers only the parent's interval
+    ([_ev(1, None, 0, 100), _ev(2, 1, 10, 50), _ev(3, 1, 40, 30),
+      _ev(4, 1, 90, 40)], {1: 30, 2: 50, 3: 30, 4: 40}),
+    # an instant has no id and is passed over
+    ([_ev(1, None, 0, 10), {"name": "mark", "ph": "i", "ts_ns": 5}],
+     {1: 10}),
+])
+def test_self_times(events, want):
+    assert self_times(events) == want
+
+
+def test_self_times_of_recorded_spans_sum_to_the_root():
+    tr = Tracer()
+    with tr.span("root"):
+        for _ in range(3):
+            with tr.span("step"):
+                with tr.span("leaf"):
+                    time.sleep(0.001)
+    events = tr.export()["traceEvents"]
+    self_ns = self_times(events)
+    root = next(e for e in events if e["name"] == "root")
+    assert sum(self_ns.values()) == root["dur_ns"]
+    assert all(v >= 0 for v in self_ns.values())
+
+
+def test_spans_are_mirrored_into_the_profilers_trace(tmp_path):
+    """Under ``jax.profiler.trace`` a ``with tracer.span`` lands in the
+    host plane as ``dl4j:<name>``; a begin/end pair does not."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    tr = Tracer()
+    with jax.profiler.trace(str(tmp_path)):
+        with tr.span("mirrored", batch=3):
+            jax.block_until_ready(jax.numpy.ones(8) + 1)
+        tr.end(tr.begin("not_mirrored"))
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    names = {e.name for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events}
+    assert ANNOTATION_PREFIX + "mirrored" in names
+    assert not any("not_mirrored" in n for n in names)
+    assert {e["name"] for e in tr.export()["traceEvents"]} == {
+        "mirrored", "not_mirrored"}
+
+
+def test_tracer_imports_and_records_without_jax():
+    """``profiling/tracer.py`` never imports jax, and records with jax
+    absent from ``sys.modules``: a fresh interpreter proves both."""
+    import subprocess
+    import sys
+    from deeplearning4j_tpu.profiling import tracer as tracer_mod
+    code = (
+        "import importlib.util, sys\n"
+        f"spec = importlib.util.spec_from_file_location('tracer', "
+        f"{tracer_mod.__file__!r})\n"
+        "m = importlib.util.module_from_spec(spec)\n"
+        "sys.modules['tracer'] = m\n"
+        "spec.loader.exec_module(m)\n"
+        "t = m.Tracer()\n"
+        "with t.span('outer'):\n"
+        "    with t.span('inner', k=1):\n"
+        "        pass\n"
+        "ev = t.export()['traceEvents']\n"
+        "assert [e['name'] for e in ev] == ['inner', 'outer'], ev\n"
+        "assert ev[0]['parent'] == ev[1]['id']\n"
+        "assert 'jax' not in sys.modules, 'tracer imported jax'\n"
+        "print('ok')\n")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=60)
+    assert out.returncode == 0 and out.stdout.strip() == "ok", out.stderr
+
+
 def test_tracer_bounded_buffer_drops_and_counts():
     tr = Tracer(max_events=10)
     for i in range(25):
@@ -114,7 +290,7 @@ def test_tracer_bounded_buffer_drops_and_counts():
     # every event source is bounded, not just end(): a compile-watcher
     # recompile storm (complete) or marker flood (instant) can't leak
     for i in range(30):
-        tr.complete(f"c{i}", 0.0, 1.0)
+        tr.complete(f"c{i}", 1000)
         tr.instant(f"i{i}")
     assert tr.event_count() <= 10
 
