@@ -541,11 +541,10 @@ def _run_rung(jax, rung: str, smoke: bool, on_accel: bool, device_kind: str,
                 else net.fit_batches_scan)
 
     # Stage a small rotation of distinct batches in DEVICE memory once
-    # (bf16 on TPU via the DevicePrefetchIterator host-cast path — halves
-    # the host-to-device bytes and is the native MXU dtype), then time
-    # the training step cycling through them: MLPerf-style synthetic-
-    # input measurement of samples/sec/chip, independent of the host
-    # link.
+    # (bf16 on TPU, narrowed on the device by DevicePrefetchIterator: the
+    # native MXU dtype), then time the training step cycling through
+    # them: MLPerf-style synthetic-input measurement of samples/sec/chip,
+    # independent of the host link.
     t = time.perf_counter()
     n_stage = 2 if smoke else 4
     with tracer.span("stage_batches", n=n_stage):

@@ -14,18 +14,21 @@ MultiLayerNetwork.fit does (ref: MultiLayerNetwork.java:951).
 The async iterators record what both of their threads do, as spans of the
 process's tracer and counters of its registry, under the ``input:*`` names
 ``datasets/pipeline.py`` has: the producer's ``input:produce`` (children
-``input:read``, ``input:cast``, ``input:h2d``, ``input:put_wait``) and the
+``input:read``, ``input:h2d``, ``input:cast``, ``input:put_wait``) and the
 consumer's ``input:wait``. The k-th item produced and the k-th taken carry
 ``batch=k`` (the queue is FIFO); the item that ends the stream is one too.
 """
 
 from __future__ import annotations
 
+import functools
 import itertools
 import queue
 import threading
 from typing import Iterator, List, Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from deeplearning4j_tpu.datasets.dataset import DataSet
@@ -349,6 +352,17 @@ class AsyncDataSetIterator(DataSetIterator):
         return self._base.batch_size()
 
 
+@functools.partial(jax.jit, static_argnames="dtype")
+def _narrow_floats(arrays, dtype):
+    """``arrays`` with every floating leaf converted to ``dtype``, other
+    leaves as they are. One jit for the module: every
+    ``DevicePrefetchIterator`` shares the compiled convert of a shape, so a
+    new iterator over shapes already seen compiles nothing."""
+    return jax.tree_util.tree_map(
+        lambda a: a.astype(dtype)
+        if jnp.issubdtype(a.dtype, jnp.floating) else a, arrays)
+
+
 class DevicePrefetchIterator(AsyncDataSetIterator):
     """Async prefetch that also stages each batch in DEVICE memory (with
     optional dtype cast) from the producer thread — double-buffered
@@ -358,14 +372,29 @@ class DevicePrefetchIterator(AsyncDataSetIterator):
 
     ``jax.device_put`` is asynchronous: the transfer overlaps the previous
     training step, so fit() sees device-resident arrays and the step time
-    excludes the host-to-device transfer. Over a slow host link this is
-    the difference between transfer-bound and compute-bound training.
+    excludes the host-to-device transfer.
+
+    ``dtype`` narrows the floating features and labels ON THE DEVICE: the
+    batch goes up as the base iterator gave it and one jitted convert,
+    dispatched from the producer thread behind the upload, rounds it to
+    nearest even, as numpy's ``astype`` does: the host cast's bits, for
+    float64 input too (``device_put`` makes it float32 first, x64 being
+    off, and so did ``ml_dtypes`` on the host). Masks and integer leaves
+    keep their dtype; ``dtype=None`` uploads untouched.
+
+    Measured in one cell on one host (ResNet-50, 77 MB of float32 a batch,
+    one v5e; PERF.md §6): the host's ``astype`` held the producer thread
+    49.2 ms a batch and paced a 52.6 ms step; uploading the wide batch and
+    converting there holds it 1.7 ms. The price: twice the bytes cross the
+    host link, and each batch waits on the device in its WIDE dtype until
+    the convert's turn comes behind the steps already queued, up to
+    ``queue_size + 2`` of them (+0.7 to 1.1 GB of peak memory in that
+    cell). Where the link is slower than the host's cast (a remote device)
+    that is the wrong trade; no such link was measured.
     """
 
     def __init__(self, base: DataSetIterator, queue_size: int = 2,
                  dtype: Optional[str] = None, device=None):
-        import jax.numpy as jnp
-
         self._dtype = None if dtype is None else jnp.dtype(dtype)
         # device=None stages on the DEFAULT device UNCOMMITTED
         # (device_put with no target). An explicit device would commit the
@@ -379,33 +408,29 @@ class DevicePrefetchIterator(AsyncDataSetIterator):
         super().__init__(base, queue_size=queue_size)
 
     def _stage(self, ds: DataSet) -> DataSet:
-        import jax
-
-        def cast(arr, narrow: bool):
-            if arr is None:
-                return None
-            # cast on the HOST (numpy + ml_dtypes) so the host→device
-            # transfer ships the narrow dtype — with bf16 that halves the
-            # host-to-device bytes; jnp.asarray first would transfer
-            # f32 and cast device-side.
-            a = np.asarray(arr)
-            if narrow and self._dtype is not None \
-                    and np.issubdtype(a.dtype, np.floating):
-                a = a.astype(self._dtype)
-            return a
-
         tracer, reg = get_tracer(), get_registry()
-        with tracer.span("input:cast") as span:
-            host = (cast(ds.features, True), cast(ds.labels, True),
-                    cast(ds.features_mask, False),
-                    cast(ds.labels_mask, False))
-        reg.counter("input_cast_seconds_total",
-                    help="producer seconds casting batches on the host"
-                    ).inc(span.dur_ns / 1e9)
+        # a leaf that is no array (a list of rows) goes up as one array,
+        # not as a pytree of scalars
+        batch = tuple(a if a is None or isinstance(a, (np.ndarray, jax.Array))
+                      else np.asarray(a) for a in (
+                          ds.features, ds.labels, ds.features_mask,
+                          ds.labels_mask))
+        # device_put returns before the bytes have crossed: the span reads
+        # the upload's dispatch (1 ms for 77 MB), not the transfer
         with tracer.span("input:h2d") as span:
-            staged = (jax.device_put(host) if self._device is None
-                      else jax.device_put(host, self._device))
+            staged = (jax.device_put(batch) if self._device is None
+                      else jax.device_put(batch, self._device))
         reg.counter("input_h2d_seconds_total",
                     help="wall seconds staging batches on device"
                     ).inc(span.dur_ns / 1e9)
+        # the convert runs on the device behind the upload; this thread
+        # only dispatches it, so the span reads next to nothing once the
+        # shape has compiled
+        with tracer.span("input:cast") as span:
+            if self._dtype is not None:
+                staged = _narrow_floats(staged[:2],
+                                        dtype=self._dtype) + staged[2:]
+        reg.counter("input_cast_seconds_total",
+                    help="producer seconds narrowing batches (the dispatch "
+                         "of the device's convert)").inc(span.dur_ns / 1e9)
         return DataSet(*staged)
