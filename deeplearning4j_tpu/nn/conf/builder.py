@@ -48,14 +48,16 @@ _RNN_LAYERS = {"LSTM", "GravesLSTM", "GravesBidirectionalLSTM", "SimpleRnn",
                "Subsampling1DLayer", "SelfAttentionLayer",
                "LastTimeStepLayer", "TimeDistributedLayer",
                "ZeroPadding1DLayer", "PositionalEmbeddingLayer",
-               "TiedRnnOutputLayer"}
+               "TiedRnnOutputLayer", "GatedDeltaNetLayer",
+               "QKNormAttentionLayer"}
+_IDS_LAYERS = {"TokenEmbeddingLayer"}
 _ANY_LAYERS = {"BatchNormalization", "GlobalPoolingLayer", "ActivationLayer",
                "DropoutLayer", "LossLayer", "ReshapeLayer", "PermuteLayer",
                # feature-axis normalization is rank-agnostic: a LayerNorm
                # between attention blocks must keep its rnn-typed input
                # (an auto Rnn->FF preprocessor here would strip the time
                # axis the transformer's residual stream carries)
-               "LayerNormalization"}
+               "LayerNormalization", "RMSNorm", "GatedFeedForwardLayer"}
 
 
 def expected_input_kind(layer: BaseLayerConf) -> str:
@@ -66,6 +68,8 @@ def expected_input_kind(layer: BaseLayerConf) -> str:
         return "rnn"
     if tag in _ANY_LAYERS:
         return "any"
+    if tag in _IDS_LAYERS:
+        return "ids"
     return "ff"
 
 

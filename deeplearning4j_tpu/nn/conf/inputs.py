@@ -18,8 +18,8 @@ from typing import Optional, Tuple
 
 @dataclass(frozen=True)
 class InputType:
-    kind: str  # "ff" | "rnn" | "cnn" | "cnnflat"
-    size: Optional[int] = None            # ff / rnn feature size
+    kind: str  # "ff" | "rnn" | "cnn" | "cnnflat" | "ids"
+    size: Optional[int] = None            # ff / rnn feature size; ids: vocabulary
     timesteps: Optional[int] = None       # rnn (None = variable)
     height: Optional[int] = None          # cnn
     width: Optional[int] = None
@@ -35,6 +35,13 @@ class InputType:
         return InputType(kind="rnn", size=size, timesteps=timesteps)
 
     @staticmethod
+    def token_ids(vocab_size: int, timesteps: Optional[int] = None
+                  ) -> "InputType":
+        """Integer token ids ``[B, T]`` below ``vocab_size``: the one input
+        that is no float array. Only ``TokenEmbeddingLayer`` takes it."""
+        return InputType(kind="ids", size=vocab_size, timesteps=timesteps)
+
+    @staticmethod
     def convolutional(height: int, width: int, channels: int) -> "InputType":
         return InputType(kind="cnn", height=height, width=width, channels=channels)
 
@@ -45,9 +52,7 @@ class InputType:
 
     # ---- derived ----
     def flat_size(self) -> int:
-        if self.kind in ("ff", "cnnflat"):
-            return int(self.size)
-        if self.kind == "rnn":
+        if self.kind in ("ff", "cnnflat", "rnn", "ids"):
             return int(self.size)
         if self.kind == "cnn":
             return int(self.height * self.width * self.channels)
@@ -60,6 +65,8 @@ class InputType:
         if self.kind == "rnn":
             ts = self.timesteps or 1
             return (ts, self.size)  # [T, F] per example (batch-major [B,T,F])
+        if self.kind == "ids":
+            return (self.timesteps or 1,)
         if self.kind == "cnn":
             return (self.height, self.width, self.channels)  # NHWC
         raise ValueError(self.kind)

@@ -32,6 +32,7 @@ from deeplearning4j_tpu.nn.netcommon import (CostAnalysisMixin, EvalMixin,
                                               jit_init, ScanFitMixin,
                                               SentinelMixin, ShardCheckMixin,
 )
+from deeplearning4j_tpu.nn.remat import checkpoint_after_cotangent
 from deeplearning4j_tpu.nn.updater import build_optimizer, compute_updates
 from deeplearning4j_tpu.optimize.listeners import IterationListener, TrainingListener
 from deeplearning4j_tpu.profiling.tracer import get_tracer
@@ -248,7 +249,7 @@ class ComputationGraph(LazyScoreMixin, EvalMixin, FitLoopMixin, ScanFitMixin,
                         return _l.apply(p, hh, state=s_in, train=_t, rng=r,
                                         mask=m)
                     if remat:
-                        apply_fn = jax.checkpoint(apply_fn)
+                        apply_fn = checkpoint_after_cotangent(apply_fn)
                     h, s = apply_fn(self._layer_params(params, name), h,
                                     states[name], sub, cur_mask)
                     if layer.frozen:
@@ -323,9 +324,12 @@ class ComputationGraph(LazyScoreMixin, EvalMixin, FitLoopMixin, ScanFitMixin,
             if lm is None:
                 lbl = labels[out_name]
                 lm = out_masks.get(out_name) if lbl.ndim > 2 else None
-            total = total + layer.compute_loss(
-                self._layer_params(params, out_name), acts[out_name],
-                labels[out_name], mask=lm)
+            # the head's own operations under the node's name, as every
+            # other node's are (it was left out of _forward's scope)
+            with jax.named_scope(out_name):
+                total = total + layer.compute_loss(
+                    self._layer_params(params, out_name), acts[out_name],
+                    labels[out_name], mask=lm)
         return total
 
     def _loss_fn(self, params, states, inputs, labels: Dict[str, Array],

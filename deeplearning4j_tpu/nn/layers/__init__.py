@@ -35,6 +35,7 @@ from deeplearning4j_tpu.nn.layers.convolution import (  # noqa: F401
 )
 from deeplearning4j_tpu.nn.layers.normalization import (  # noqa: F401
     LayerNormalization,
+    RMSNorm,
     BatchNormalization,
     LocalResponseNormalization,
 )
@@ -56,8 +57,14 @@ from deeplearning4j_tpu.nn.layers.shape import (  # noqa: F401
     ZeroPadding1DLayer,
 )
 from deeplearning4j_tpu.nn.layers.variational import VariationalAutoencoder  # noqa: F401
-from deeplearning4j_tpu.nn.layers.attention import SelfAttentionLayer  # noqa: F401
+from deeplearning4j_tpu.nn.layers.attention import (  # noqa: F401
+    QKNormAttentionLayer,
+    SelfAttentionLayer,
+)
 from deeplearning4j_tpu.nn.layers.embedding import (  # noqa: F401
     PositionalEmbeddingLayer,
     TiedRnnOutputLayer,
+    TokenEmbeddingLayer,
 )
+from deeplearning4j_tpu.nn.layers.feedforward import GatedFeedForwardLayer  # noqa: F401
+from deeplearning4j_tpu.nn.layers.linear_attention import GatedDeltaNetLayer  # noqa: F401

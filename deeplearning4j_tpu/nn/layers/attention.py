@@ -21,6 +21,7 @@ from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.layers.base import (
     Array, BaseLayerConf, Params, register_layer,
 )
+from deeplearning4j_tpu.nn.layers.normalization import rms_normalize
 
 NEG_INF = -1e30
 
@@ -230,9 +231,17 @@ class SelfAttentionLayer(BaseLayerConf):
                 batch_axis=ring.data_axis, causal=self.causal,
                 block_size=self.block_size, mask=mask)
             return out, state
-        q = self._split_heads(x @ params["Wq"])
-        k = self._split_heads(x @ params["Wk"])
-        v = self._split_heads(x @ params["Wv"])
+        out = self._attend(x @ params["Wq"], x @ params["Wk"],
+                           x @ params["Wv"], mask) @ params["Wo"]
+        if mask is not None:
+            out = out * mask[..., None]
+        return out, state
+
+    def _attend(self, q, k, v, mask):
+        """Softmax attention of projected ``q, k, v [B, T, H*D]`` by head,
+        heads merged again: the Pallas flash kernel where its shape gate
+        allows, else the blockwise or the plain XLA path."""
+        q, k, v = map(self._split_heads, (q, k, v))
         # helper seam (the cuDNN-discovery analog, like the fused LSTM):
         # MXU-native flash attention when the Pallas kernel applies
         from deeplearning4j_tpu.ops.pallas_attention import (
@@ -240,7 +249,7 @@ class SelfAttentionLayer(BaseLayerConf):
         from deeplearning4j_tpu.ops.pallas_kernels import count_gate_fallback
         amode = attention_mode()
         use_flash = amode != "off" and flash_ok(
-            x.shape[1], self.head_dim, q.dtype.itemsize)
+            q.shape[2], self.head_dim, q.dtype.itemsize)
         if amode != "off" and not use_flash:
             count_gate_fallback(self, "flash_attention")
         if use_flash:
@@ -254,11 +263,7 @@ class SelfAttentionLayer(BaseLayerConf):
         else:
             out = attention_reference(q, k, v, causal=self.causal, mask=mask)
         B, H, T, D = out.shape
-        out = out.transpose(0, 2, 1, 3).reshape(B, T, H * D)
-        out = out @ params["Wo"]
-        if mask is not None:
-            out = out * mask[..., None]
-        return out, state
+        return out.transpose(0, 2, 1, 3).reshape(B, T, H * D)
 
     # ------------------------------------------------- incremental decode
     def cache_shape(self, rows: int, max_len: int) -> Tuple[int, ...]:
@@ -317,3 +322,41 @@ class SelfAttentionLayer(BaseLayerConf):
         H, D = self.n_heads, self.head_dim
         out = out.transpose(0, 2, 1, 3).reshape(B, 1, H * D)
         return out @ params["Wo"], k_cache, v_cache
+
+
+@register_layer
+@dataclass
+class QKNormAttentionLayer(SelfAttentionLayer):
+    """Causal multi-head self attention with QK-norm and no positional
+    term: ``q = RMSNorm(Wq x)``, ``k = RMSNorm(Wk x)`` over the whole
+    projection (all heads together, one gain a channel), then softmax
+    attention by head through ``SelfAttentionLayer``'s kernel seam. The
+    full-attention layer of a hybrid decoder whose other layers carry the
+    order of the tokens in their recurrent state. Trains; it has no
+    incremental decode and does not ride the sequence-parallel ring."""
+    causal: bool = True
+    norm_eps: float = 1e-6
+    sequence_parallel: bool = False
+
+    supports_kv_cache = False
+
+    def param_order(self) -> List[str]:
+        return ["Wq", "Wk", "Wv", "q_gamma", "k_gamma", "Wo"]
+
+    def init_params(self, rng, dtype=jnp.float32) -> Params:
+        p = super().init_params(rng, dtype)
+        hd = self.n_heads * self.head_dim
+        p["q_gamma"] = jnp.ones((hd,), dtype)
+        p["k_gamma"] = jnp.ones((hd,), dtype)
+        return p
+
+    def apply(self, params, x, *, state, train, rng, mask=None):
+        x = self._dropout_input(x, train, rng)
+        norm = lambda a, g: (rms_normalize(a, self.norm_eps)
+                             * params[g]).astype(x.dtype)
+        out = self._attend(norm(x @ params["Wq"], "q_gamma"),
+                           norm(x @ params["Wk"], "k_gamma"),
+                           x @ params["Wv"], mask) @ params["Wo"]
+        if mask is not None:
+            out = out * mask[..., None]
+        return out, state

@@ -35,7 +35,9 @@ from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.layers.base import (
     BaseLayerConf, Params, register_layer,
 )
-from deeplearning4j_tpu.nn.layers.recurrent import RnnOutputLayer
+from deeplearning4j_tpu.nn.layers.recurrent import (
+    RnnOutputLayer, flat_time_loss,
+)
 from deeplearning4j_tpu.ops.activations import get_activation
 
 #: GPT-2's positional-embedding init scale
@@ -137,12 +139,6 @@ class TiedRnnOutputLayer(RnnOutputLayer):
                 "support tied heads)")
         return x @ params["W_tok"].T
 
-    def apply(self, params, x, *, state, train, rng, mask=None):
-        out = get_activation(self.activation)(self._logits(params, x))
-        if mask is not None:
-            out = out * mask[..., None]
-        return out, state
-
     def compute_loss(self, params, x, labels, *, mask=None,
                      average: bool = True):
         """Same loss semantics as RnnOutputLayer (per-timestep loss summed
@@ -155,16 +151,51 @@ class TiedRnnOutputLayer(RnnOutputLayer):
         natively, so the rank-3 path needs no reshape at all — which is
         also one less all-gather of the logits. ``average=False`` (the
         eval path, never sharded) keeps the per-timestep matrix via the
-        flat route."""
+        flat route. Class ids ``[B, T]`` go either way as one-hot rows do."""
         from deeplearning4j_tpu.ops.losses import get_loss, promote_loss_dtype
         preout = self._logits(params, x)
         preout, labels = promote_loss_dtype(preout, labels)
         if not average:
-            B, T, F = preout.shape
-            flat_mask = mask.reshape(B * T) if mask is not None else None
-            per = get_loss(self.loss)(labels.reshape(B * T, F),
-                                      preout.reshape(B * T, F),
-                                      self.activation, flat_mask)
-            return per.reshape(B, T)
+            return flat_time_loss(self.loss, labels, preout, self.activation,
+                                  mask)
         per_ex = get_loss(self.loss)(labels, preout, self.activation, mask)
         return jnp.mean(per_ex)
+
+
+@register_layer
+@dataclass
+class TokenEmbeddingLayer(BaseLayerConf):
+    """``[B, T]`` int32 token ids -> ``[B, T, D]``: the rows of ``W [V, D]``
+    gathered by id, no positions and no bias. 64 KB a sequence of 8,192
+    cross the host link where one-hot rows would be 411 MB at a vocabulary
+    of 12,544, and the lookup is a gather, not a ``[T, V] x [V, D]``
+    product; the gradient scatters into the rows that were read. Declared
+    by ``InputType.token_ids(vocab_size, timesteps)``."""
+    n_out: int = 0
+
+    def set_n_in(self, in_type: InputType) -> None:
+        if in_type.kind != "ids":
+            raise ValueError(
+                f"TokenEmbeddingLayer expects token ids "
+                f"(InputType.token_ids), got {in_type}")
+        self.n_in = in_type.size
+
+    def infer_output_type(self, in_type: InputType) -> InputType:
+        return InputType.recurrent(self.n_out, in_type.timesteps)
+
+    def param_order(self) -> List[str]:
+        return ["W"]
+
+    def init_params(self, rng, dtype=jnp.float32) -> Params:
+        return {"W": self._init_w(rng, (self.n_in, self.n_out), self.n_in,
+                                  self.n_out, dtype)}
+
+    def apply(self, params, x, *, state, train, rng, mask=None):
+        if not jnp.issubdtype(x.dtype, jnp.integer):
+            raise ValueError(
+                f"TokenEmbeddingLayer({self.name!r}) takes integer ids "
+                f"[B, T], got {x.dtype} {x.shape}")
+        out = jnp.take(params["W"], x, axis=0)
+        if mask is not None:
+            out = out * mask[..., None].astype(out.dtype)
+        return out, state

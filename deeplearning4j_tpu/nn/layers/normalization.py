@@ -23,6 +23,13 @@ from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.layers.base import BaseLayerConf, Params, State, register_layer
 
 
+def rms_normalize(x, eps: float):
+    """``x / sqrt(mean(x^2) + eps)`` over the last axis, the statistics and
+    the result in float32 or wider."""
+    x = x.astype(jnp.promote_types(x.dtype, jnp.float32))
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+
+
 def _batch_mean(a, axes):
     """``mean(a, axes)`` as the mean over the examples of each example's
     own mean. A backend that adds a reduction's terms one after another
@@ -221,3 +228,34 @@ class LayerNormalization(BaseLayerConf):
         xhat = (xs - mean) * jax.lax.rsqrt(var + self.eps)
         out = params["gamma"] * xhat + params["beta"]
         return out.astype(in_dtype), state
+
+
+@register_layer
+@dataclass
+class RMSNorm(BaseLayerConf):
+    """``gamma * x / sqrt(mean(x^2) + eps)`` over the feature axis, with no
+    mean taken out and no shift (Zhang and Sennrich, arXiv:1910.07467):
+    the norm of today's decoder blocks. Statistics in float32 or wider,
+    the output in the input's dtype; rank-agnostic, as
+    ``LayerNormalization`` is."""
+    eps: float = 1e-6
+    n_features: int = 0
+
+    def set_n_in(self, in_type: InputType) -> None:
+        self.n_in = in_type.flat_size()
+        self.n_features = (in_type.channels if in_type.kind == "cnn"
+                           else in_type.flat_size())
+
+    def infer_output_type(self, in_type: InputType) -> InputType:
+        return in_type
+
+    def param_order(self) -> List[str]:
+        return ["gamma"]
+
+    def init_params(self, rng, dtype=jnp.float32) -> Params:
+        return {"gamma": jnp.ones((self.n_features,), dtype)}
+
+    def apply(self, params, x, *, state, train, rng, mask=None):
+        y = rms_normalize(x, self.eps) * params["gamma"].astype(
+            jnp.promote_types(x.dtype, jnp.float32))
+        return y.astype(x.dtype), state

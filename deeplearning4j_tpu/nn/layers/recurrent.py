@@ -36,7 +36,9 @@ from deeplearning4j_tpu.nn.layers.base import (
 )
 from deeplearning4j_tpu.nn.layers.core import OutputLayer
 from deeplearning4j_tpu.ops.activations import get_activation
-from deeplearning4j_tpu.ops.losses import get_loss, promote_loss_dtype
+from deeplearning4j_tpu.ops.losses import (
+    get_loss, is_class_ids, promote_loss_dtype,
+)
 
 
 def _lstm_cell(params: Params, x_t: Array, h: Array, c: Array,
@@ -395,6 +397,7 @@ class RnnOutputLayer(BaseLayerConf):
     here just a batched matmul over the time axis)."""
     n_out: int = 0
     loss: str = "mcxent"
+    has_bias: bool = True       # False: an untied language-model head
 
     def set_n_in(self, in_type: InputType) -> None:
         if in_type.kind != "rnn":
@@ -404,15 +407,23 @@ class RnnOutputLayer(BaseLayerConf):
     def infer_output_type(self, in_type: InputType) -> InputType:
         return InputType.recurrent(self.n_out, in_type.timesteps)
 
+    def param_order(self) -> List[str]:
+        return ["W", "b"] if self.has_bias else ["W"]
+
     def init_params(self, rng, dtype=jnp.float32) -> Params:
         k_w, _ = jax.random.split(rng)
-        return {
-            "W": self._init_w(k_w, (self.n_in, self.n_out), self.n_in, self.n_out, dtype),
-            "b": self._init_b((self.n_out,), dtype),
-        }
+        p = {"W": self._init_w(k_w, (self.n_in, self.n_out), self.n_in,
+                               self.n_out, dtype)}
+        if self.has_bias:
+            p["b"] = self._init_b((self.n_out,), dtype)
+        return p
+
+    def _logits(self, params, x):
+        out = x @ params["W"]
+        return out + params["b"] if self.has_bias else out
 
     def apply(self, params, x, *, state, train, rng, mask=None):
-        out = get_activation(self.activation)(x @ params["W"] + params["b"])
+        out = get_activation(self.activation)(self._logits(params, x))
         if mask is not None:
             out = out * mask[..., None]
         return out, state
@@ -420,16 +431,23 @@ class RnnOutputLayer(BaseLayerConf):
     def compute_loss(self, params, x, labels, *, mask=None, average: bool = True):
         """Loss summed over timesteps; score = total / minibatch size, with
         masked timesteps excluded from the total (matches the reference's
-        score semantics for time series)."""
-        preout = x @ params["W"] + params["b"]
-        preout, labels = promote_loss_dtype(preout, labels)
-        B, T, F = preout.shape
-        flat_pre = preout.reshape(B * T, F)
-        flat_lab = labels.reshape(B * T, F)
-        flat_mask = mask.reshape(B * T) if mask is not None else None
-        per = get_loss(self.loss)(flat_lab, flat_pre, self.activation, flat_mask)
-        per_ex = per.reshape(B, T).sum(axis=1)
-        return jnp.mean(per_ex) if average else per.reshape(B, T)
+        score semantics for time series). ``labels``: one-hot rows
+        ``[B, T, F]`` or class ids ``[B, T]`` (``ops.losses.is_class_ids``)."""
+        preout, labels = promote_loss_dtype(self._logits(params, x), labels)
+        per = flat_time_loss(self.loss, labels, preout, self.activation, mask)
+        return jnp.mean(per.sum(axis=1)) if average else per
+
+
+def flat_time_loss(loss: str, labels, preout, activation, mask):
+    """The per-timestep loss ``[B, T]`` of logits ``[B, T, F]`` by the flat
+    ``[B*T, F]`` route, for one-hot rows and class ids alike."""
+    B, T, F = preout.shape
+    flat_lab = (labels.reshape(B * T) if is_class_ids(labels)
+                else labels.reshape(B * T, F))
+    flat_mask = mask.reshape(B * T) if mask is not None else None
+    per = get_loss(loss)(flat_lab, preout.reshape(B * T, F), activation,
+                         flat_mask)
+    return per.reshape(B, T)
 
 
 @register_layer

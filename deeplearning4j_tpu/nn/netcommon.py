@@ -21,6 +21,7 @@ two copies from drifting:
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 
 from deeplearning4j_tpu.datasets.iterator import AsyncDataSetIterator
 from deeplearning4j_tpu.optimize.listeners import TrainingListener
@@ -145,8 +146,15 @@ class FitLoopMixin:
         with get_tracer().span("fit_batch", it=self.iteration_count + 1,
                                **ids):
             loss = self._fit_batch(data)
-        get_registry().counter(
+        registry = get_registry()
+        registry.counter(
             "fit_steps_total", help="fit_batch calls completed").inc()
+        fed = getattr(data, "features", None)
+        if hasattr(fed, "dtype") and jnp.issubdtype(fed.dtype, jnp.integer):
+            registry.counter(
+                "train_tokens_total",
+                help="token ids of the integer-fed batches fit_batch took"
+            ).inc(fed.size)
         return loss
 
     def _standard_step(self, data, split) -> float:

@@ -116,13 +116,14 @@ ACTIVATIONS: Dict[str, Callable[[Array], Array]] = {
     "softsign": softsign,
     "cube": cube,
     "swish": swish,
+    "silu": swish,
 }
 
 # Activations smooth enough for finite-difference gradient checking
 # (ref: gradientcheck/GradientCheckUtil.java:47-58 whitelist).
 SMOOTH_ACTIVATIONS = frozenset(
     {"identity", "linear", "sigmoid", "tanh", "softmax", "logsoftmax",
-     "softplus", "softsign", "cube", "elu", "selu", "gelu", "swish",
+     "softplus", "softsign", "cube", "elu", "selu", "gelu", "swish", "silu",
      "rationaltanh"}
 )
 

@@ -31,10 +31,20 @@ def _apply_act(preout: Array, activation: str) -> Array:
     return get_activation(activation)(preout)
 
 
+def is_class_ids(labels: Array) -> bool:
+    """Integer targets: one class id where a one-hot row would stand, so
+    ``labels`` has one axis fewer than the logits (``[B, T]`` against
+    ``[B, T, V]``: 64 KB a sequence of 8,192 where the rows take 411 MB at
+    a vocabulary of 12,544)."""
+    return jnp.issubdtype(labels.dtype, jnp.integer)
+
+
 def promote_loss_dtype(preout: Array, labels: Array):
     """Mixed precision: losses compute in >= f32 (promote, don't hard-cast,
-    so f64 gradient checks stay f64)."""
+    so f64 gradient checks stay f64). Class ids stay whole numbers."""
     dt = jnp.promote_types(preout.dtype, jnp.float32)
+    if is_class_ids(labels):
+        return preout.astype(dt), labels
     return preout.astype(dt), labels.astype(dt)
 
 
@@ -73,12 +83,19 @@ def l1(labels: Array, preout: Array, activation: str, mask=None) -> Array:
 
 
 def mcxent(labels: Array, preout: Array, activation: str, mask=None) -> Array:
-    """Multi-class cross entropy. Fused when activation == softmax."""
+    """Multi-class cross entropy. Fused when activation == softmax.
+    ``labels`` are one-hot (or soft) rows, or class ids (``is_class_ids``):
+    the id picks the one term a one-hot row would keep, so the two give the
+    same float32 bits."""
     if activation == "softmax":
         logp = jax.nn.log_softmax(preout, axis=-1)
-        return _reduce(-labels * logp, mask)
-    out = jnp.clip(_apply_act(preout, activation), _EPS, 1.0 - _EPS)
-    return _reduce(-labels * jnp.log(out), mask)
+    else:
+        logp = jnp.log(jnp.clip(_apply_act(preout, activation), _EPS,
+                                1.0 - _EPS))
+    if is_class_ids(labels):
+        picked = jnp.take_along_axis(logp, labels[..., None], axis=-1)
+        return _reduce(-picked[..., 0], mask)
+    return _reduce(-labels * logp, mask)
 
 
 def negativeloglikelihood(labels, preout, activation, mask=None):
