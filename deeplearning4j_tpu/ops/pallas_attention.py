@@ -6,7 +6,7 @@ impl). This module is the MXU-native version of the same math: one
 kernel invocation per (batch*head, q-block) computes online-softmax
 attention with the score tile, running max and normalizer all resident
 in VMEM — no [T, T] score matrix ever reaches HBM, and the K/V panels
-stream through the MXU at 128-wide tiles. Backward is the standard
+stream through the MXU in blocks of up to 512 rows. Backward is the standard
 FlashAttention-2 recomputation: per-row ``D = rowsum(dO * O)`` plus the
 saved logsumexp lets dq and dk/dv kernels rebuild the probability tiles
 block-by-block instead of storing them.
@@ -17,9 +17,32 @@ reads ``DL4J_TPU_PALLAS`` — compiled on TPU by default, interpret for
 CPU CI, off to force the XLA paths. Parity between the kernel and
 ``attention_reference`` is enforced by tests/test_pallas_attention.py.
 
+Precision: the MXU's operands have the dtype of the kernel's inputs and
+every product accumulates in float32 (``preferred_element_type``).
+bfloat16 q, k, v and dO go to the MXU as they are; the probabilities
+``P`` and ``dS = P * (dP - D)`` are formed in float32 and cast to the
+operand dtype where they enter ``P V``, ``P^T dO``, ``dS K`` and
+``dS^T Q``; with float32 inputs each cast is the identity and every
+product has float32 operands (how many MXU passes those take is the
+ambient matmul precision's affair: at the default Mosaic makes one
+bfloat16 pass of them on a v5e, PERF.md PR 30). Float32 whatever the
+inputs are: the scores (the softmax scale multiplies them, never a
+bfloat16 q), the additive bias and the causal select, the running max
+and normaliser, ``exp``, the logsumexp, ``D = rowsum(dO * O)``, the
+accumulators and the masked-row gates on ``NEG_INF``; the outputs are
+cast to the inputs' dtype at the end.
+``pallas_flash_traces_total{operands=...}`` counts the traces by that
+dtype.
+
 Shapes: q, k, v are [B, H, T, D] (self-attention: same T). The kernel
-pads T to the 128-lane block and D to 128 internally; padded KV columns
-are masked with the same additive bias that carries ``kv_mask``.
+pads D to 128 and T to its block internally (``_padded_len``: 512, 256 or
+128 rows, the largest that costs no more than an eighth of padding);
+padded KV columns are masked with the same additive bias that carries
+``kv_mask``. One turn of a kernel's inner loop makes a square [B, B]
+score tile. The forward and dq hold B queries a grid step and walk the
+keys; dk/dv holds B keys and walks the queries on TRANSPOSED tiles
+(``S^T = K Q^T``), so that neither ``P^T dO`` nor ``dS^T Q`` transposes a
+tile on its way to the MXU.
 
 Future work: the ring-attention path (parallel/sequence.py) still uses
 the lax.scan blockwise kernel for its per-shard step — composing ring
@@ -45,11 +68,12 @@ from deeplearning4j_tpu.ops.pallas_kernels import (
 )
 
 NEG_INF = -1e30
-# q/k block = MXU tile width. Per-row vectors (lse, rowsum(dO*O)) travel
-# as [G, Tp, _BLK] arrays too, the value repeated along the lane axis: a
-# [_BLK, _BLK] block satisfies the (8, 128) tiling and has the shape of
-# the score tile, so the backward subtracts it from the scores
-# elementwise with no column-to-lane relayout inside the kernel.
+# MXU tile width, and the least block. The forward writes its per-row lse
+# as a [G, Tp, _BLK] array, the value repeated along the lane axis: a
+# [B, _BLK] block satisfies the (8, 128) tiling, and dq repeats it to the
+# width of its score tile and subtracts it elementwise, with no
+# column-to-lane relayout inside the kernel (dk/dv, whose tiles are
+# transposed, reads the same vectors as [1, Tp] rows).
 _BLK = 128
 
 
@@ -59,20 +83,51 @@ def attention_mode() -> str:
     return lstm_mode()
 
 
+def _padded_len(T: int) -> int:
+    """The length the kernels run at: ``T`` padded to the largest block of
+    512, 256, 128 that costs no more than an eighth over the 128-padding
+    (T = 8,320 runs at 8,704 in blocks of 512, not at 8,320 in blocks of
+    128; T = 300 runs at 384)."""
+    base = _round_up(T, _BLK)
+    return next(Tp for Tp in (_round_up(T, b) for b in (512, 256, _BLK))
+                if 8 * Tp <= 9 * base)
+
+
+def _block(Tp: int) -> int:
+    """Side of the square score tile one turn of a kernel's inner loop
+    makes, and the rows a grid step holds (queries in the forward and dq,
+    keys in dk/dv): the most of 512, 256, 128 that divides the padded
+    length. A turn costs some 260 ns whatever it holds and the per-row
+    vectors (running max, normaliser, the rescaled accumulator) a pass
+    over the rows whatever the tile's width, so the kernels are at 1.3 to
+    1.8 times the products' own time on a v5e at 512 and at 5 to 9 times
+    at 128; past 512 nothing more is gained (PERF.md, PR 30)."""
+    return next(b for b in (512, 256, _BLK) if Tp % b == 0)
+
+
+def _vmem_bytes(Tp: int, Dp: int, itemsize: int) -> int:
+    B = _block(Tp)
+    panels = 2 * Tp * Dp * itemsize
+    blocks = 4 * B * Dp * itemsize
+    vectors = (2 * B * _BLK + 2 * 8 * Tp) * 4
+    tiles = (8 * B * B + 4 * B * Dp) * 4
+    return 2 * (panels + blocks + vectors) + tiles
+
+
 def flash_vmem_bytes(T: int, D: int = 128, itemsize: int = 4) -> int:
-    """VMEM the largest of the three kernels (dk/dv) asks for, counting
-    what Pallas allocates: every BlockSpec operand is double-buffered,
-    the whole-sequence operands are [Tp, Dp] panels (K and V in the
-    forward and dq kernels, Q and dO in dk/dv) and [Tp, _BLK] f32 row
-    vectors (lse, rowsum(dO*O)), and the loop body holds a handful of
-    [_BLK, _BLK] / [_BLK, Dp] f32 tiles."""
-    Tp = _round_up(T, _BLK)
-    Dp = _round_up(D, _BLK)
-    panels = 2 * Tp * Dp * itemsize            # two whole-sequence panels
-    rows = 2 * Tp * _BLK * 4                   # lse + dvec, whole sequence
-    blocks = 4 * _BLK * Dp * itemsize + 8 * _BLK  # k, v in; dk, dv out; bias
-    tiles = 8 * _BLK * max(_BLK, Dp) * 4       # s, p, dp, ds, q, do, dk, dv
-    return 2 * (panels + rows + blocks) + tiles
+    """VMEM the largest of the three kernels asks for, counting what
+    Pallas allocates, with B the block of the padded length. Every
+    BlockSpec operand is double-buffered and has the inputs' ``itemsize``:
+    two whole-sequence [Tp, Dp] panels (K and V in the forward and dq
+    kernels, Q and dO in dk/dv) and four [B, Dp] blocks (k, v in and dk,
+    dv out). The per-row vectors are float32 whatever the inputs are: two
+    lane-replicated [B, _BLK] blocks (lse and rowsum(dO*O) in dq; the
+    keys' bias column in dk/dv pads to one) and two [1, Tp] rows padded
+    to 8 sublanes (dk/dv's lse and rowsum(dO*O); the bias row elsewhere).
+    The loop body's tiles are float32 too, and single: eight of [B, B]
+    (s, p, dp, ds, the positions, the broadcast bias and vectors) and
+    four of [B, Dp] (the accumulators and their updates)."""
+    return _vmem_bytes(_padded_len(T), _round_up(D, _BLK), itemsize)
 
 
 def flash_ok(T: int, D: int = 128, itemsize: int = 4) -> bool:
@@ -82,15 +137,37 @@ def flash_ok(T: int, D: int = 128, itemsize: int = 4) -> bool:
     return flash_vmem_bytes(T, D, itemsize) <= VMEM_GATE_BYTES
 
 
-def _params(T: int, D: int, itemsize: int):
+def _params(Tp: int, Dp: int, itemsize: int):
     # no carry between grid steps in any of the three kernels
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel"),
-        vmem_limit_bytes=vmem_limit(flash_vmem_bytes(T, D, itemsize)))
+        vmem_limit_bytes=vmem_limit(_vmem_bytes(Tp, Dp, itemsize)))
 
 
-def _blk_slice(j):
-    return pl.dslice(pl.multiple_of(j * _BLK, _BLK), _BLK)
+def _dot(a, b, contract_b: int = 0):
+    """``a @ b`` on the MXU with a float32 accumulator (``a @ b.T`` for
+    ``contract_b=1``). Float32 operands follow the ambient matmul
+    precision, as every product of the program does; narrower ones are
+    exact products whatever it says, and Mosaic refuses them under
+    "highest", so they name the default."""
+    precision = None if a.dtype == jnp.float32 else jax.lax.Precision.DEFAULT
+    return jax.lax.dot_general(
+        a, b, (((1,), (contract_b,)), ((), ())), precision=precision,
+        preferred_element_type=jnp.float32)
+
+
+def _dot_nt(a, b):
+    return _dot(a, b, 1)
+
+
+def _blk_slice(j, B: int):
+    return pl.dslice(pl.multiple_of(j * B, B), B)
+
+
+def _lanes(x, B: int):
+    """A lane-replicated [B, _BLK] per-row vector at the width of a [B, B]
+    score tile."""
+    return jnp.concatenate([x] * (B // _BLK), axis=1)
 
 
 # ---------------------------------------------------------------------------
@@ -98,41 +175,37 @@ def _blk_slice(j):
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
-                causal: bool, n_kv: int, scale: float):
-    q = q_ref[0].astype(jnp.float32) * scale          # [Bq, Dp]
-    Bq = q.shape[0]
+                causal: bool, scale: float):
+    # MXU operands (q, k, v, and p below) keep the input dtype; the
+    # scale multiplies the float32 scores, never a bfloat16 q
+    q = q_ref[0]                                      # [B, Dp]
+    B, Dp = q.shape
     qi = pl.program_id(1)
-    q_pos = qi * Bq + jax.lax.broadcasted_iota(jnp.int32, (Bq, _BLK), 0)
+    q_pos = qi * B + jax.lax.broadcasted_iota(jnp.int32, (B, B), 0)
 
     def body(j, carry):
-        acc, m, l = carry                              # m, l: [Bq, 1]
-        kblk = k_ref[0, _blk_slice(j), :].astype(jnp.float32)
-        vblk = v_ref[0, _blk_slice(j), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, kblk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)        # [Bq, BLK]
-        s = s + bias_ref[0, :, _blk_slice(j)]           # [1, BLK] over rows
+        acc, m, l = carry                              # m, l: [B, 1]
+        kblk = k_ref[0, _blk_slice(j, B), :]
+        vblk = v_ref[0, _blk_slice(j, B), :]
+        s = _dot_nt(q, kblk) * scale                   # [B, B]
+        s = s + bias_ref[0, :, _blk_slice(j, B)]        # [1, B] over rows
         if causal:
-            k_pos = j * _BLK + jax.lax.broadcasted_iota(
-                jnp.int32, (Bq, _BLK), 1)
+            k_pos = j * B + jax.lax.broadcasted_iota(jnp.int32, (B, B), 1)
             s = jnp.where(k_pos <= q_pos, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
         l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc = acc * alpha + jax.lax.dot_general(
-            p, vblk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc = acc * alpha + _dot(p.astype(vblk.dtype), vblk)
         return acc, m_new, l
 
-    Dp = q_ref.shape[-1]
-    acc0 = jnp.zeros((Bq, Dp), jnp.float32)
-    m0 = jnp.full((Bq, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((Bq, 1), jnp.float32)
+    acc0 = jnp.zeros((B, Dp), jnp.float32)
+    m0 = jnp.full((B, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((B, 1), jnp.float32)
     # causal: KV blocks past the q block's diagonal are wholly masked —
-    # skip them instead of feeding NEG_INF tiles to the MXU (Bq == BLK,
-    # so block j is live iff j <= qi)
-    hi = jnp.minimum(qi + 1, n_kv) if causal else n_kv
+    # skip them instead of feeding NEG_INF tiles to the MXU (square
+    # blocks, so block j is live iff j <= qi)
+    hi = (qi + 1) if causal else k_ref.shape[1] // B
     acc, m, l = jax.lax.fori_loop(0, hi, body, (acc0, m0, l0))
     l_safe = jnp.maximum(l, 1e-30)
     # a fully-masked row (zero valid keys) never raises m off NEG_INF —
@@ -143,27 +216,26 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
     valid = m > NEG_INF / 2
     o_ref[0] = jnp.where(valid, acc / l_safe, 0.0).astype(o_ref.dtype)
     lse = jnp.where(valid, m + jnp.log(l_safe), NEG_INF)
-    lse_ref[0] = jnp.broadcast_to(lse, (Bq, _BLK))
+    lse_ref[0] = jnp.broadcast_to(lse, (B, _BLK))
 
 
-def _run_fwd(q, k, v, bias, causal, interpret):
+def _run_fwd(q, k, v, bias, causal, interpret, scale):
     """q,k,v: [G, Tp, Dp]; bias: [G, 1, Tp] additive (0 / NEG_INF).
     Returns (out [G, Tp, Dp], lse [G, Tp, _BLK] lane-replicated)."""
     G, Tp, Dp = q.shape
-    n_q = Tp // _BLK
+    B = _block(Tp)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, causal=causal, n_kv=Tp // _BLK,
-                          scale=1.0 / math.sqrt(Dp)),
-        grid=(G, n_q),
+        functools.partial(_fwd_kernel, causal=causal, scale=scale),
+        grid=(G, Tp // B),
         in_specs=[
-            pl.BlockSpec((1, _BLK, Dp), lambda g, i: (g, i, 0)),
+            pl.BlockSpec((1, B, Dp), lambda g, i: (g, i, 0)),
             pl.BlockSpec((1, Tp, Dp), lambda g, i: (g, 0, 0)),
             pl.BlockSpec((1, Tp, Dp), lambda g, i: (g, 0, 0)),
             pl.BlockSpec((1, 1, Tp), lambda g, i: (g, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, _BLK, Dp), lambda g, i: (g, i, 0)),
-            pl.BlockSpec((1, _BLK, _BLK), lambda g, i: (g, i, 0)),
+            pl.BlockSpec((1, B, Dp), lambda g, i: (g, i, 0)),
+            pl.BlockSpec((1, B, _BLK), lambda g, i: (g, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((G, Tp, Dp), q.dtype),
@@ -180,128 +252,117 @@ def _run_fwd(q, k, v, bias, causal, interpret):
 # ---------------------------------------------------------------------------
 
 def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, dvec_ref,
-               dq_ref, *, causal: bool, n_kv: int, scale: float):
-    q = q_ref[0].astype(jnp.float32)                  # [Bq, Dp]
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]                                  # [Bq, BLK] replicated
-    dvec = dvec_ref[0]                                # [Bq, BLK] replicated
-    Bq = q.shape[0]
+               dq_ref, *, causal: bool, scale: float):
+    q = q_ref[0]                                      # [B, Dp]
+    do = do_ref[0]
+    B = q.shape[0]
+    lse = _lanes(lse_ref[0], B)                       # [B, B] replicated
+    dvec = _lanes(dvec_ref[0], B)
     qi = pl.program_id(1)
-    q_pos = qi * Bq + jax.lax.broadcasted_iota(jnp.int32, (Bq, _BLK), 0)
+    q_pos = qi * B + jax.lax.broadcasted_iota(jnp.int32, (B, B), 0)
 
     def body(j, dq):
-        kblk = k_ref[0, _blk_slice(j), :].astype(jnp.float32)
-        vblk = v_ref[0, _blk_slice(j), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, kblk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        s = s + bias_ref[0, :, _blk_slice(j)]
+        kblk = k_ref[0, _blk_slice(j, B), :]
+        vblk = v_ref[0, _blk_slice(j, B), :]
+        s = _dot_nt(q, kblk) * scale
+        s = s + bias_ref[0, :, _blk_slice(j, B)]
         if causal:
-            k_pos = j * _BLK + jax.lax.broadcasted_iota(
-                jnp.int32, (Bq, _BLK), 1)
+            k_pos = j * B + jax.lax.broadcasted_iota(jnp.int32, (B, B), 1)
             s = jnp.where(k_pos <= q_pos, s, NEG_INF)
         # fully-masked query rows (zero valid keys) carry lse == NEG_INF
         # from the forward; exp(s - lse) there is garbage (float
         # absorption, not inf) — gate them to zero probability so the
         # row's gradients are exactly zero (ADVICE r5)
         p = jnp.where(lse > NEG_INF / 2, jnp.exp(s - lse), 0.0)
-        dp = jax.lax.dot_general(
-            do, vblk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - dvec)
-        return dq + jax.lax.dot_general(
-            ds, kblk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+        dp = _dot_nt(do, vblk)
+        ds = (p * (dp - dvec)).astype(kblk.dtype)
+        return dq + _dot(ds, kblk) * scale
 
     dq0 = jnp.zeros(q.shape, jnp.float32)
-    hi = jnp.minimum(qi + 1, n_kv) if causal else n_kv
+    hi = (qi + 1) if causal else k_ref.shape[1] // B
     dq_ref[0] = jax.lax.fori_loop(0, hi, body, dq0).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, dvec_ref,
-                dk_ref, dv_ref, *, causal: bool, n_q: int, scale: float):
-    kblk = k_ref[0].astype(jnp.float32)               # [Bk, Dp]
-    vblk = v_ref[0].astype(jnp.float32)
-    bias = bias_ref[0]                                # [1, Bk]
-    Bk = kblk.shape[0]
+                dk_ref, dv_ref, *, causal: bool, scale: float):
+    """One grid step holds B keys and walks the queries in blocks of B, on
+    TRANSPOSED score tiles ``S^T = K Q^T``: keys along the sublanes,
+    queries along the lanes. ``P^T dO`` and ``dS^T Q`` are then plain
+    products (no tile is transposed on its way to the MXU), and the
+    per-query lse and rowsum(dO*O) are [1, B] rows that broadcast over
+    the sublanes."""
+    kblk = k_ref[0]                                   # [B, Dp]
+    vblk = v_ref[0]
+    B = kblk.shape[0]
     ki = pl.program_id(1)
-    k_pos = ki * Bk + jax.lax.broadcasted_iota(jnp.int32, (_BLK, Bk), 1)
+    bias = jnp.broadcast_to(bias_ref[0], (B, B))      # [B, 1] over lanes
+    k_pos = ki * B + jax.lax.broadcasted_iota(jnp.int32, (B, B), 0)
 
     def body(i, carry):
         dk, dv = carry
-        q = q_ref[0, _blk_slice(i), :].astype(jnp.float32)
-        do = do_ref[0, _blk_slice(i), :].astype(jnp.float32)
-        lse = lse_ref[0, _blk_slice(i), :]             # [Bq, Bk] replicated
-        dvec = dvec_ref[0, _blk_slice(i), :]
-        s = jax.lax.dot_general(
-            q, kblk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        s = s + bias
+        q = q_ref[0, _blk_slice(i, B), :]
+        do = do_ref[0, _blk_slice(i, B), :]
+        lse = lse_ref[0, :, _blk_slice(i, B)]          # [1, B]
+        dvec = dvec_ref[0, :, _blk_slice(i, B)]
+        st = _dot_nt(kblk, q) * scale                  # [keys, queries]
+        st = st + bias
         if causal:
-            q_pos = i * _BLK + jax.lax.broadcasted_iota(
-                jnp.int32, (_BLK, Bk), 0)
-            s = jnp.where(k_pos <= q_pos, s, NEG_INF)
-        # same masked-row gate as _dq_kernel: rows with lse == NEG_INF
+            q_pos = i * B + jax.lax.broadcasted_iota(jnp.int32, (B, B), 1)
+            st = jnp.where(k_pos <= q_pos, st, NEG_INF)
+        # same masked-row gate as _dq_kernel: queries with lse == NEG_INF
         # (no valid key) must contribute zero to dk/dv
-        p = jnp.where(lse > NEG_INF / 2, jnp.exp(s - lse), 0.0)
-        dv = dv + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do, vblk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - dvec)
-        dk = dk + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+        pt = jnp.where(lse > NEG_INF / 2, jnp.exp(st - lse), 0.0)
+        dv = dv + _dot(pt.astype(do.dtype), do)
+        dpt = _dot_nt(vblk, do)
+        dst = (pt * (dpt - dvec)).astype(q.dtype)
+        dk = dk + _dot(dst, q) * scale
         return dk, dv
 
     z = jnp.zeros(kblk.shape, jnp.float32)
     # causal: q blocks above the diagonal never attend to this KV block
     lo = ki if causal else 0
-    dk, dv = jax.lax.fori_loop(lo, n_q, body, (z, z))
+    dk, dv = jax.lax.fori_loop(lo, q_ref.shape[1] // B, body, (z, z))
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
-def _run_bwd(q, k, v, bias, do, out, lse, causal, interpret):
+def _run_bwd(q, k, v, bias, do, out, lse, causal, interpret, scale):
     G, Tp, Dp = q.shape
-    scale = 1.0 / math.sqrt(Dp)
+    B = _block(Tp)
     dvec = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                    axis=-1, keepdims=True)             # [G, Tp, 1]
-    dvec = jnp.broadcast_to(dvec, (G, Tp, _BLK))
-    qspec = pl.BlockSpec((1, _BLK, Dp), lambda g, i: (g, i, 0))
+    blkspec = pl.BlockSpec((1, B, Dp), lambda g, i: (g, i, 0))
     fullspec = pl.BlockSpec((1, Tp, Dp), lambda g, i: (g, 0, 0))
-    rowspec = pl.BlockSpec((1, _BLK, _BLK), lambda g, i: (g, i, 0))
-    fullrow = pl.BlockSpec((1, Tp, _BLK), lambda g, i: (g, 0, 0))
-    biasfull = pl.BlockSpec((1, 1, Tp), lambda g, i: (g, 0, 0))
-    biasblk = pl.BlockSpec((1, 1, _BLK), lambda g, i: (g, 0, i))
+    vecspec = pl.BlockSpec((1, B, _BLK), lambda g, i: (g, i, 0))
+    colspec = pl.BlockSpec((1, B, 1), lambda g, i: (g, i, 0))
+    fullrow = pl.BlockSpec((1, 1, Tp), lambda g, i: (g, 0, 0))
     params = _params(Tp, Dp, q.dtype.itemsize)
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, causal=causal, n_kv=Tp // _BLK,
-                          scale=scale),
-        grid=(G, Tp // _BLK),
-        in_specs=[qspec, fullspec, fullspec, biasfull, qspec, rowspec,
-                  rowspec],
-        out_specs=qspec,
+        functools.partial(_dq_kernel, causal=causal, scale=scale),
+        grid=(G, Tp // B),
+        in_specs=[blkspec, fullspec, fullspec, fullrow, blkspec, vecspec,
+                  vecspec],
+        out_specs=blkspec,
         out_shape=jax.ShapeDtypeStruct((G, Tp, Dp), q.dtype),
         compiler_params=params,
         interpret=interpret,
         name="flash_attention_dq",
-    )(q, k, v, bias, do, lse, dvec)
+    )(q, k, v, bias, do, lse, jnp.broadcast_to(dvec, (G, Tp, _BLK)))
+    # dk/dv read the per-query vectors as [1, Tp] rows and the keys' bias
+    # as a [B, 1] column
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, causal=causal, n_q=Tp // _BLK,
-                          scale=scale),
-        grid=(G, Tp // _BLK),
-        in_specs=[fullspec, qspec, qspec, biasblk, fullspec, fullrow,
+        functools.partial(_dkv_kernel, causal=causal, scale=scale),
+        grid=(G, Tp // B),
+        in_specs=[fullspec, blkspec, blkspec, colspec, fullspec, fullrow,
                   fullrow],
-        out_specs=[qspec, qspec],
+        out_specs=[blkspec, blkspec],
         out_shape=[jax.ShapeDtypeStruct((G, Tp, Dp), k.dtype),
                    jax.ShapeDtypeStruct((G, Tp, Dp), v.dtype)],
         compiler_params=params,
         interpret=interpret,
         name="flash_attention_dkv",
-    )(q, k, v, bias, do, lse, dvec)
+    )(q, k, v, bias.reshape(G, Tp, 1), do, lse[:, :, 0].reshape(G, 1, Tp),
+      dvec.reshape(G, 1, Tp))
     return dq, dk, dv
 
 
@@ -309,24 +370,35 @@ def _run_bwd(q, k, v, bias, do, out, lse, causal, interpret):
 # differentiable core + public entry
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _flash_core(q, k, v, bias, causal, interpret):
-    out, _ = _run_fwd(q, k, v, bias, causal, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _flash_core(q, k, v, bias, causal, interpret, scale):
+    out, _ = _run_fwd(q, k, v, bias, causal, interpret, scale)
     return out
 
 
-def _flash_core_fwd(q, k, v, bias, causal, interpret):
-    out, lse = _run_fwd(q, k, v, bias, causal, interpret)
+def _flash_core_fwd(q, k, v, bias, causal, interpret, scale):
+    out, lse = _run_fwd(q, k, v, bias, causal, interpret, scale)
     return out, (q, k, v, bias, out, lse)
 
 
-def _flash_core_bwd(causal, interpret, res, g):
+def _flash_core_bwd(causal, interpret, scale, res, g):
     q, k, v, bias, out, lse = res
-    dq, dk, dv = _run_bwd(q, k, v, bias, g, out, lse, causal, interpret)
+    dq, dk, dv = _run_bwd(q, k, v, bias, g, out, lse, causal, interpret,
+                          scale)
     return dq, dk, dv, jnp.zeros_like(bias)
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
+
+
+def _count_trace(dtype) -> None:
+    """Which products a run's kernels make, counted once per trace (not
+    per step) under the operands' dtype."""
+    from deeplearning4j_tpu.profiling.metrics import get_registry
+    get_registry().labeled_counter(
+        "pallas_flash_traces_total",
+        "flash-attention traces by the dtype of the MXU operands (per trace)",
+    ).labels(operands=jnp.dtype(dtype).name).inc()
 
 
 def flash_attention(q, k, v, *, causal: bool = False,
@@ -335,16 +407,17 @@ def flash_attention(q, k, v, *, causal: bool = False,
     """softmax(QK^T/sqrt(D))V via the Pallas kernels. q,k,v: [B,H,T,D]
     (self-attention: shared T). ``kv_mask``: [B, T] key validity.
 
-    NOTE the softmax scale uses the PADDED head dim when D is not a
-    multiple of 128 — callers pre-scale q so the math matches the
-    unpadded reference exactly (this function does that internally)."""
+    The products' operands have ``q.dtype`` (k and v are brought to it)
+    and their accumulators are float32: bfloat16 inputs reach the MXU as
+    they are, float32 inputs are not narrowed. The softmax scale is that
+    of the UNPADDED head dim and multiplies the float32 scores."""
     B, H, T, D = q.shape
-    Tp, Dp = _round_up(T, _BLK), _round_up(D, _BLK)
-    # the kernel divides by sqrt(Dp); fold the correction into q
-    q = q * (math.sqrt(Dp) / math.sqrt(D))
+    Tp, Dp = _padded_len(T), _round_up(D, _BLK)
+    _count_trace(q.dtype)
 
     def prep(x):
-        x = jnp.pad(x, ((0, 0), (0, 0), (0, Tp - T), (0, Dp - D)))
+        x = jnp.pad(x.astype(q.dtype),
+                    ((0, 0), (0, 0), (0, Tp - T), (0, Dp - D)))
         return x.reshape(B * H, Tp, Dp)
 
     qf, kf, vf = prep(q), prep(k), prep(v)
@@ -353,5 +426,6 @@ def flash_attention(q, k, v, *, causal: bool = False,
     valid = jnp.pad(valid, ((0, 0), (0, Tp - T)))
     bias = jnp.where(valid > 0, 0.0, NEG_INF).astype(jnp.float32)
     bias = jnp.repeat(bias, H, axis=0)[:, None, :]     # [B*H, 1, Tp]
-    out = _flash_core(qf, kf, vf, bias, causal, interpret)
+    out = _flash_core(qf, kf, vf, bias, causal, interpret,
+                      1.0 / math.sqrt(D))
     return out.reshape(B, H, Tp, Dp)[:, :, :T, :D]

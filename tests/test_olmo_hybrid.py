@@ -181,6 +181,58 @@ def test_flash_in_interpret_mode_is_the_plain_attention_with_qk_norm(
     assert np.array_equal(np.asarray(moved[:, :100]), np.asarray(got[:, :100]))
 
 
+def test_bf16_full_attention_through_the_kernel_follows_the_xla_path(
+        monkeypatch):
+    """The tiny model under ``PrecisionPolicy("bf16")``, where the flash
+    kernels take bfloat16 operands: the full-attention node's output and
+    the first gradient (the optimizer's first moment after one step) with
+    the kernel (interpreted) against ``DL4J_TPU_PALLAS=off``. Both sides
+    are bfloat16 programs, and the XLA path is the coarser one (it rounds
+    its scores to bfloat16 before the softmax, the kernel keeps them
+    float32), so the bounds are bfloat16's own: a few of its 2^-8 for the
+    node's output (read 5.1e-3 by the gap of norms) and for the gradients
+    of the node's own parameters (read 0.8e-2 to 1.6e-2 on three batches).
+    Below the node the gradients pass three recurrent layers: the median
+    leaf reads 1.5e-2 to 1.7e-2, the worst 0.03 to 0.17 (an ``A_log`` or
+    ``dt_bias`` of two numbers, each a sum of thousands of terms that
+    cancel), which is held to a half: a wrong kernel reads 1 and more."""
+    from deeplearning4j_tpu.nn.updater import cast_floats
+    t = 160
+    x, y = id_batches(1, seed=5, t=t)[0]
+    seen = {}
+    for mode in ("off", "interpret"):
+        monkeypatch.setenv("DL4J_TPU_PALLAS", mode)
+        registry = MetricsRegistry()
+        previous = set_registry(registry)
+        try:
+            net = ComputationGraph(
+                olmo_hybrid_tiny(V, t, precision="bf16", seed=5)).init()
+            acts, _, _ = net._forward(
+                cast_floats(net.params, jnp.bfloat16), net.states,
+                {"tokens": jnp.asarray(x)}, train=True, rng=None)
+            net.fit(DataSet(x, y))
+        finally:
+            set_registry(previous)
+        assert acts["b3_mix"].dtype == jnp.bfloat16
+        traces = registry.labeled_counter("pallas_flash_traces_total")
+        assert traces.labels(operands="float32").value == 0
+        assert (traces.labels(operands="bfloat16").value > 0) == (
+            mode == "interpret")
+        seen[mode] = (np.asarray(acts["b3_mix"], np.float32), jax.device_get(
+            program.first_moment(net.opt_state)))
+
+    def gap(a, b):
+        return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+    (out, grads), (out_xla, grads_xla) = seen["interpret"], seen["off"]
+    assert gap(out, out_xla) < 1.5e-2
+    gaps = {leaf: gap(g, grads_xla[leaf]) for leaf, g in grads.items()}
+    own = {leaf: g for leaf, g in gaps.items() if leaf.startswith("b3_mix/")}
+    assert len(own) == 6 and max(own.values()) < 4e-2, own
+    assert np.median(list(gaps.values())) < 4e-2, sorted(gaps.values())
+    assert max(gaps.values()) < 0.5, max(gaps, key=gaps.get)
+
+
 NEW_LAYERS = [
     GatedDeltaNetLayer(n_heads=2, key_dim=8, value_dim=16, conv_kernel=4,
                        allow_neg_eigval=False),

@@ -16,31 +16,63 @@ from deeplearning4j_tpu.ops.pallas_attention import flash_attention, flash_ok
 
 RNG = np.random.default_rng(3)
 
+# the kernels multiply in the dtype they are given: every parity case runs
+# in float32 (elementwise, at the tolerance it always had) and in bfloat16
+# (the error's norm over the answer's, against the float32 reference on the
+# same bfloat16 inputs)
+DTYPES = pytest.mark.parametrize(
+    "dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+BF16_OUT, BF16_GRAD = 4e-3, 6e-3
 
-def _qkv(B=2, H=2, T=24, D=8):
-    q = jnp.asarray(RNG.normal(size=(B, H, T, D)).astype(np.float32))
-    k = jnp.asarray(RNG.normal(size=(B, H, T, D)).astype(np.float32))
-    v = jnp.asarray(RNG.normal(size=(B, H, T, D)).astype(np.float32))
-    return q, k, v
+
+def _qkv(B=2, H=2, T=24, D=8, dtype=jnp.float32, n=3):
+    return tuple(jnp.asarray(RNG.normal(size=(B, H, T, D)), jnp.float32)
+                 .astype(dtype) for _ in range(n))
 
 
+def _f32(fn):
+    """``fn`` on float32 copies of its array arguments: the reference the
+    bfloat16 cases are held to."""
+    return lambda *a: fn(*(x.astype(jnp.float32) for x in a))
+
+
+def _assert_close(got, ref, tol, bf16_tol, err_msg=""):
+    """float32: elementwise with atol = rtol = ``tol``. bfloat16: the norm
+    of the error over the norm of the answer under ``bf16_tol``."""
+    if got.dtype == jnp.float32:
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   atol=tol, rtol=tol, err_msg=err_msg)
+        return
+    assert got.dtype == jnp.bfloat16, got.dtype
+    got, ref = (np.asarray(a.astype(jnp.float32), np.float64)
+                for a in (got, ref))
+    gap = np.linalg.norm(got - ref) / np.linalg.norm(ref)
+    assert gap < bf16_tol, f"{err_msg} gap of norms {gap:.2e}"
+
+
+def _grads(fn, cot, *args):
+    return jax.grad(lambda *a: jnp.sum(
+        fn(*a).astype(jnp.float32) * cot.astype(jnp.float32)),
+        argnums=(0, 1, 2))(*args)
+
+
+@DTYPES
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_forward_parity(causal):
-    q, k, v = _qkv()
-    ref = attention_reference(q, k, v, causal=causal)
+def test_flash_forward_parity(causal, dtype):
+    q, k, v = _qkv(dtype=dtype)
+    ref = _f32(functools.partial(attention_reference, causal=causal))(q, k, v)
     got = flash_attention(q, k, v, causal=causal, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               atol=2e-5, rtol=2e-5)
+    _assert_close(got, ref, 2e-5, BF16_OUT)
 
 
-def test_flash_forward_parity_masked():
-    q, k, v = _qkv(T=20)
+@DTYPES
+def test_flash_forward_parity_masked(dtype):
+    q, k, v = _qkv(T=20, dtype=dtype)
     mask = jnp.asarray((RNG.random((2, 20)) > 0.3).astype(np.float32))
     mask = mask.at[:, 0].set(1.0)  # at least one valid key per row
-    ref = attention_reference(q, k, v, mask=mask)
+    ref = _f32(functools.partial(attention_reference, mask=mask))(q, k, v)
     got = flash_attention(q, k, v, kv_mask=mask, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               atol=2e-5, rtol=2e-5)
+    _assert_close(got, ref, 2e-5, BF16_OUT)
 
 
 def test_flash_forward_aligned_shape():
@@ -51,58 +83,47 @@ def test_flash_forward_aligned_shape():
                                atol=2e-5, rtol=2e-5)
 
 
+@DTYPES
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_gradient_parity(causal):
+def test_flash_gradient_parity(causal, dtype):
     """FA2 backward (recompute + saved lse) == autodiff of the
     reference, for q, k AND v."""
-    q, k, v = _qkv(B=1, H=2, T=12, D=8)
-    cot = jnp.asarray(RNG.normal(size=q.shape).astype(np.float32))
-
-    def loss_ref(q, k, v):
-        return jnp.sum(attention_reference(q, k, v, causal=causal) * cot)
-
-    def loss_flash(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal=causal,
-                                       interpret=True) * cot)
-
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    g_fl = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    q, k, v, cot = _qkv(B=1, H=2, T=12, D=8, dtype=dtype, n=4)
+    g_ref = _grads(_f32(functools.partial(attention_reference,
+                                          causal=causal)), cot, q, k, v)
+    g_fl = _grads(functools.partial(flash_attention, causal=causal,
+                                    interpret=True), cot, q, k, v)
     for a, b, name in zip(g_fl, g_ref, "qkv"):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=5e-5, rtol=5e-5,
-                                   err_msg=f"d{name}")
+        _assert_close(a, b, 5e-5, BF16_GRAD, f"d{name}")
 
 
-def test_flash_gradient_parity_masked():
-    q, k, v = _qkv(B=2, H=1, T=10, D=4)
+@DTYPES
+def test_flash_gradient_parity_masked(dtype):
+    q, k, v, cot = _qkv(B=2, H=1, T=10, D=4, dtype=dtype, n=4)
     mask = jnp.ones((2, 10)).at[0, 7:].set(0.0)
-    cot = jnp.asarray(RNG.normal(size=q.shape).astype(np.float32))
-
-    def loss_ref(q, k, v):
-        return jnp.sum(attention_reference(q, k, v, mask=mask) * cot)
-
-    def loss_flash(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, kv_mask=mask,
-                                       interpret=True) * cot)
-
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    g_fl = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    g_ref = _grads(_f32(functools.partial(attention_reference, mask=mask)),
+                   cot, q, k, v)
+    g_fl = _grads(functools.partial(flash_attention, kv_mask=mask,
+                                    interpret=True), cot, q, k, v)
     for a, b, name in zip(g_fl, g_ref, "qkv"):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=5e-5, rtol=5e-5,
-                                   err_msg=f"d{name}")
+        _assert_close(a, b, 5e-5, BF16_GRAD, f"d{name}")
 
 
 @pytest.mark.parametrize(
-    "shape", [(4, 4, 256, 128), (2, 2, 40, 24), (32, 8, 128, 32)],
-    ids=["aligned", "unaligned", "lm_rung"])
-def test_flash_cross_lowers_for_tpu(shape):
+    "shape,dtype",
+    [((4, 4, 256, 128), jnp.float32), ((2, 2, 40, 24), jnp.float32),
+     ((32, 8, 128, 32), jnp.float32), ((1, 2, 512, 128), jnp.bfloat16)],
+    ids=["aligned", "unaligned", "lm_rung", "bfloat16"])
+def test_flash_cross_lowers_for_tpu(shape, dtype):
     """Lowering for ("tpu",) runs the Pallas-to-Mosaic lowering on the
     CPU: a BlockSpec the (8, 128) tiling rejects raises here, in tier-1,
-    not on the chip. Forward is one kernel, backward adds dq and dk/dv."""
-    q = k = v = jnp.zeros(shape, jnp.float32)
+    not on the chip. Forward is one kernel, backward adds dq and dk/dv.
+    The bfloat16 case has products contracted over dimension 0 of both
+    operands (``P^T dO``, ``dS^T Q``) on 16-bit tiles."""
+    q = k = v = jnp.zeros(shape, dtype)
     fwd = functools.partial(flash_attention, causal=True, interpret=False)
-    bwd = jax.grad(lambda q, k, v: fwd(q, k, v).sum(), argnums=(0, 1, 2))
+    bwd = jax.grad(lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
+                   argnums=(0, 1, 2))
     for fn, kernels in ((fwd, 1), (bwd, 3)):
         text = jax.jit(fn).trace(q, k, v).lower(
             lowering_platforms=("tpu",)).as_text()
@@ -115,6 +136,21 @@ def test_flash_ok_vmem_gate():
     # wide heads count too: [Tp, Dp] panels, not a hardcoded 128
     assert not flash_ok(4096, 1024)
     assert flash_ok(4096, 128)
+    # the cell's shape in both widths, and the length the gate still takes
+    assert flash_ok(8192, 128, 2) and flash_ok(8192, 128, 4)
+    assert flash_ok(32768, 128, 2) and not flash_ok(32768, 128, 4)
+
+
+@pytest.mark.parametrize("T,padded,block", [
+    (12, 128, 128), (300, 384, 128), (512, 512, 512), (520, 640, 128),
+    (768, 768, 256), (1500, 1536, 512), (8192, 8192, 512),
+    (8320, 8704, 512)])
+def test_flash_block_follows_the_length(T, padded, block):
+    """The largest block of 512, 256, 128 that pads T by no more than an
+    eighth over the 128-padding: a length just off a multiple of 512 does
+    not fall back to blocks of 128."""
+    from deeplearning4j_tpu.ops.pallas_attention import _block, _padded_len
+    assert _padded_len(T) == padded and _block(padded) == block
 
 
 def test_selfattention_layer_uses_flash_kernel(monkeypatch):
@@ -156,35 +192,31 @@ def test_selfattention_layer_uses_flash_kernel(monkeypatch):
                         kernel="flash_attention").value >= 1
 
 
-def test_flash_multi_block_causal_masked():
-    """T=300 spans three KV blocks: the cross-block online-softmax
-    carry, causal block skipping (hi=qi+1 / lo=ki) and masked-block
-    rescale all genuinely fire — fwd AND grads."""
-    q, k, v = _qkv(B=1, H=1, T=300, D=8)
-    mask = jnp.ones((1, 300)).at[0, 130:170].set(0.0)  # hole in block 2
-    cot = jnp.asarray(RNG.normal(size=q.shape).astype(np.float32))
-
-    ref = attention_reference(q, k, v, causal=True, mask=mask)
-    got = flash_attention(q, k, v, causal=True, kv_mask=mask,
-                          interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               atol=3e-5, rtol=3e-5)
-
-    def loss(fn):
-        return lambda q, k, v: jnp.sum(fn(q, k, v) * cot)
-
-    g_ref = jax.grad(loss(lambda q, k, v: attention_reference(
-        q, k, v, causal=True, mask=mask)), argnums=(0, 1, 2))(q, k, v)
-    g_fl = jax.grad(loss(lambda q, k, v: flash_attention(
-        q, k, v, causal=True, kv_mask=mask, interpret=True)),
-        argnums=(0, 1, 2))(q, k, v)
-    for a, b, name in zip(g_fl, g_ref, "qkv"):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=1e-4, rtol=1e-4,
-                                   err_msg=f"d{name}")
+@pytest.mark.parametrize(
+    "T,dtype", [(300, jnp.float32), (300, jnp.bfloat16),
+                (1500, jnp.float32), (1500, jnp.bfloat16)],
+    ids=["T300-float32", "T300-bfloat16", "T1500-float32", "T1500-bfloat16"])
+def test_flash_multi_block_causal_masked(T, dtype):
+    """T=300 spans three blocks of 128, T=1500 three of 512 (the block
+    follows the length): the cross-block online-softmax carry, causal
+    block skipping (hi=qi+1 / lo=ki) and masked-block rescale all
+    genuinely fire — fwd AND grads."""
+    from deeplearning4j_tpu.ops.pallas_attention import _block, _padded_len
+    assert _padded_len(T) // _block(_padded_len(T)) == 3
+    q, k, v, cot = _qkv(B=1, H=1, T=T, D=8, dtype=dtype, n=4)
+    mask = jnp.ones((1, T)).at[0, 130:170].set(0.0)  # hole in block 2
+    ref_fn = _f32(functools.partial(attention_reference, causal=True,
+                                    mask=mask))
+    fl_fn = functools.partial(flash_attention, causal=True, kv_mask=mask,
+                              interpret=True)
+    _assert_close(fl_fn(q, k, v), ref_fn(q, k, v), 3e-5, BF16_OUT)
+    for a, b, name in zip(_grads(fl_fn, cot, q, k, v),
+                          _grads(ref_fn, cot, q, k, v), "qkv"):
+        _assert_close(a, b, 1e-4, BF16_GRAD, f"d{name}")
 
 
-def test_flash_zero_valid_key_row_fwd_bwd():
+@DTYPES
+def test_flash_zero_valid_key_row_fwd_bwd(dtype):
     """A batch row whose kv_mask has ZERO valid keys (all-padding
     sequence): forward emits exactly zero for that row, backward emits
     exactly zero (and finite) gradients — the lse == NEG_INF gate in
@@ -192,32 +224,75 @@ def test_flash_zero_valid_key_row_fwd_bwd():
     fully-masked rows were float-absorption garbage, not inf, so the
     old l > 0 test never fired). The valid batch row keeps full fwd/bwd
     parity with the reference."""
-    q, k, v = _qkv(B=2, H=2, T=12, D=8)
+    q, k, v, cot = _qkv(B=2, H=2, T=12, D=8, dtype=dtype, n=4)
     mask = jnp.ones((2, 12)).at[0].set(0.0)  # batch 0: no valid key
-    cot = jnp.asarray(RNG.normal(size=q.shape).astype(np.float32))
+    fl_fn = functools.partial(flash_attention, kv_mask=mask, interpret=True)
 
-    out = flash_attention(q, k, v, kv_mask=mask, interpret=True)
+    out = fl_fn(q, k, v)
     assert float(jnp.max(jnp.abs(out[0]))) == 0.0  # masked row: zeros
-
-    def loss(fn):
-        return lambda q, k, v: jnp.sum(fn(q, k, v) * cot)
-
-    g_fl = jax.grad(loss(lambda q, k, v: flash_attention(
-        q, k, v, kv_mask=mask, interpret=True)), argnums=(0, 1, 2))(q, k, v)
+    g_fl = _grads(fl_fn, cot, q, k, v)
     for g, name in zip(g_fl, "qkv"):
         assert bool(jnp.all(jnp.isfinite(g))), f"d{name} not finite"
         assert float(jnp.max(jnp.abs(g[0]))) == 0.0, \
             f"d{name}: masked row must have zero gradients"
 
     # the valid batch row is untouched by the gate: parity holds
-    ref1 = attention_reference(q[1:], k[1:], v[1:], mask=mask[1:])
-    np.testing.assert_allclose(np.asarray(out[1:]), np.asarray(ref1),
-                               atol=2e-5, rtol=2e-5)
-    g_ref = jax.grad(
-        lambda q, k, v: jnp.sum(
-            attention_reference(q, k, v, mask=mask[1:]) * cot[1:]),
-        argnums=(0, 1, 2))(q[1:], k[1:], v[1:])
+    ref_fn = _f32(functools.partial(attention_reference, mask=mask[1:]))
+    _assert_close(out[1:], ref_fn(q[1:], k[1:], v[1:]), 2e-5, BF16_OUT)
+    g_ref = _grads(ref_fn, cot[1:], q[1:], k[1:], v[1:])
     for a, b, name in zip(g_fl, g_ref, "qkv"):
-        np.testing.assert_allclose(np.asarray(a[1:]), np.asarray(b),
-                                   atol=5e-5, rtol=5e-5,
-                                   err_msg=f"d{name} (valid row)")
+        _assert_close(a[1:], b, 5e-5, BF16_GRAD, f"d{name} (valid row)")
+
+
+def _kernel_dots(jaxpr, inside=False):
+    """Every ``dot_general`` inside a ``pallas_call`` of ``jaxpr``, at any
+    depth (custom-vjp calls, the kernels' loops): ``[(lhs dtype, rhs dtype,
+    result dtype), ...]``."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if inside and eqn.primitive.name == "dot_general":
+            found.append((*(v.aval.dtype for v in eqn.invars),
+                          eqn.outvars[0].aval.dtype))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _kernel_dots(
+                sub, inside or eqn.primitive.name == "pallas_call")
+    return found
+
+
+@DTYPES
+def test_flash_products_take_the_inputs_dtype(dtype):
+    """The nine products of a forward and backward (two in the forward
+    kernel, three in dq, four in dk/dv): operands in the inputs' dtype,
+    results in float32. bfloat16 inputs are not widened on their way to
+    the MXU, float32 inputs are not narrowed."""
+    q, k, v, cot = _qkv(B=1, H=1, T=16, D=8, dtype=dtype, n=4)
+    jaxpr = jax.make_jaxpr(lambda q, k, v: _grads(functools.partial(
+        flash_attention, causal=True, interpret=True), cot, q, k, v))(
+            q, k, v)
+    dots = _kernel_dots(jaxpr.jaxpr)
+    assert len(dots) == 9, dots
+    want = (jnp.dtype(dtype), jnp.dtype(dtype), jnp.dtype(jnp.float32))
+    assert set(dots) == {want}, dots
+
+
+def test_flash_traces_are_counted_by_operand_dtype():
+    """``pallas_flash_traces_total{operands=...}``: once a trace, not a
+    call, under the dtype of q."""
+    from deeplearning4j_tpu.profiling.metrics import (
+        MetricsRegistry, set_registry)
+    registry = MetricsRegistry()
+    previous = set_registry(registry)
+    try:
+        fn = jax.jit(functools.partial(flash_attention, causal=True,
+                                       interpret=True))
+        args = _qkv(B=1, H=1, T=16, D=8, dtype=jnp.bfloat16)
+        fn(*args)
+        fn(*args)
+        counted = registry.labeled_counter("pallas_flash_traces_total")
+        assert counted.labels(operands="bfloat16").value == 1
+        assert counted.labels(operands="float32").value == 0
+        fn(*_qkv(B=1, H=1, T=16, D=8))
+        assert counted.labels(operands="float32").value == 1
+        assert counted.value == 2
+    finally:
+        set_registry(previous)
