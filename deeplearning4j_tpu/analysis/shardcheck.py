@@ -482,8 +482,8 @@ def lower_step_program(jitted, *args, capture_jaxpr: bool = False,
 def hlo_comm_bytes(program: StepProgram, dp: Optional[int] = None) -> int:
     """Per-chip collective bytes of the compiled program on the ring
     model (loop-body collectives counted once — static trip counts are
-    not recovered from the HLO). The number bench records persist as
-    ``comm_bytes_hlo`` and SC007 gates against the cost model."""
+    not recovered from the HLO). The number SC007 gates against the
+    cost model."""
     _classify_reduce_scatter_form(program.module, dp)
     return sum(c.ring_bytes() for c in program.module.collectives)
 

@@ -13,7 +13,7 @@ seconds-per-step estimate per candidate:
   when the model has an attention layer to ring over), at the chip's
   matmul rate for the candidate's compute dtype.
 - ``comm_s``: the dp gradient exchange (exact ring model, shared with
-  BENCH records), plus first-order activation-exchange terms for tp/sp
+  shardcheck's SC007), plus first-order activation-exchange terms for tp/sp
   and boundary transfers for pp.
 - ``pipeline_bubble``: the GPipe factor ``(pp - 1 + m) / m`` with
   ``m = gradient_accumulation`` microbatches.

@@ -6,8 +6,7 @@ init), wraps it in a ``ParallelTrainer`` constructed from the
 candidate's ``trainer_kwargs()`` (the exact recipe ``TunedConfig`` uses,
 so what is measured is what ships), pays the compile in warmup steps,
 then times ``steps`` asynchronously-dispatched steps closed by one
-``block_until_ready`` — the same discipline as bench.py's timed loop,
-so a probe number and a bench number mean the same thing. Compile time
+``block_until_ready``. Compile time
 is reported separately (``compile_s``), never inside the measurement.
 
 Probes never touch the caller's net: parameter state, optimizer state

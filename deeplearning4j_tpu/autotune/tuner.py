@@ -26,8 +26,7 @@ HBM budget, the system picks its own configuration —
 5. Emit a :class:`~deeplearning4j_tpu.autotune.config.TunedConfig`
    carrying the choice AND the per-config
    ``measured_vs_predicted_gap`` — the calibration surface, exported as
-   ``autotune_*`` metrics on ``/api/metrics`` and persisted in bench
-   records (``BENCH_AUTOTUNE=1``).
+   ``autotune_*`` metrics on ``/api/metrics``.
 """
 
 from __future__ import annotations

@@ -44,8 +44,8 @@ open-span stack names the input stage, and the ``input_*`` counters and
 gauges land on ``/api/metrics``. The time a consumer blocks in
 ``next()`` is the pipeline's **input stall** — accumulated here
 (``stall_s``, ``input_stall_seconds_total``) and surfaced as
-``input_stall_s`` by ``TrainingStats.export()`` and every bench rung
-record, so input-bound vs compute-bound time is attributable per run.
+``input_stall_s`` by ``TrainingStats.export()``, so input-bound vs
+compute-bound time is attributable per run.
 
 Chaos seams (``resilience/faultinject``): ``slow_input`` stalls the Nth
 ``next()`` (the stall lands in ``input_stall_s`` and the open-span

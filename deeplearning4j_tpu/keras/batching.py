@@ -271,8 +271,7 @@ def _pow2_floor(n: int) -> int:
 
 def quantile(ordered, q: float) -> float:
     """Nearest-rank quantile of an already-sorted sequence — the ONE
-    convention the p50/p99 gauges, ``stats()``, and the bench serve
-    rung all share."""
+    convention the p50/p99 gauges and ``stats()`` share."""
     return ordered[min(len(ordered) - 1, int(len(ordered) * q))]
 
 
@@ -388,8 +387,7 @@ class BatchScheduler:
         # executed — the speculative-prewarm signal
         self._bucket_mix: collections.Counter = collections.Counter()
         self._stopping = False
-        # serve-rung stats (also on /api/metrics, but the bench child
-        # wants per-scheduler numbers, not process-global ones)
+        # per-scheduler stats (/api/metrics has the process-global ones)
         self._stats_lock = threading.Lock()
         self.compile_s = 0.0
         self._batch_sizes: collections.Counter = collections.Counter()
@@ -782,7 +780,7 @@ class BatchScheduler:
         self._compiled.evict_owner(self._cache_owner)
 
     def stats(self) -> dict:
-        """Per-scheduler serve stats (the bench serve rung's record)."""
+        """Per-scheduler serve stats."""
         p50, p99 = self.latency.quantiles()
         with self._stats_lock:
             return {

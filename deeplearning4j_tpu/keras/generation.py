@@ -86,8 +86,7 @@ histogram, ``serving_ttft_seconds`` + ``serving_ttft_p50/p99_ms``
 ``serving_prefix_cache_hits_total``,
 ``serving_page_table_corruptions_total``, and ``serve:prefill`` /
 ``serve:decode`` tracer spans. ``stats()`` surfaces
-``prefix_cache_hit_rate`` and the ``kv_pages_*`` pool occupancy the
-bench's ``lm_serve`` record carries.
+``prefix_cache_hit_rate`` and the ``kv_pages_*`` pool occupancy.
 """
 
 from __future__ import annotations

@@ -18,8 +18,7 @@ bf16 ``PrecisionPolicy``, and dp x pp under ``GraphPipelineTrainer``
 (the residual stream between blocks is the single-tensor cut point GPipe
 needs; inside a block the residual skip makes a cut illegal, which is
 exactly what graphcheck's GC017 verifies). ``tools/lm_smoke.py`` gates
-the composed configs bitwise against their replicated twins; the ``lm``
-bench rung reports tokens/sec/chip + analytic MFU.
+the composed configs bitwise against their replicated twins.
 
 The character data path is ``models/char_rnn``'s: one-hot char windows,
 next-char targets — here shaped for the streaming pipeline
@@ -44,7 +43,7 @@ from deeplearning4j_tpu.nn.layers import (
     TimeDistributedLayer,
 )
 
-#: default charset of the synthetic char-LM workloads (bench/smoke) —
+#: default charset of the synthetic char-LM workloads (the smokes) —
 #: small enough that tiny models learn it, matching char_rnn's usage
 DEFAULT_CHARSET = "abcdefghijklmnopqrstuvwxyz .,;\n"
 
@@ -264,8 +263,8 @@ def char_lm_batches(text: str, seq_len: int, batch_size: int,
 def synthetic_char_text(n_chars: int, seed: int = 0,
                         charset: str = DEFAULT_CHARSET) -> str:
     """Deterministic synthetic 'prose' with local structure (repeated
-    trigram draws) so a tiny LM has something learnable — the bench
-    rung's corpus when no file is given."""
+    trigram draws) so a tiny LM has something learnable — the smokes'
+    corpus when no file is given."""
     rng = np.random.default_rng(seed)
     grams = ["the ", "and ", "ing ", "ion ", "ent ", "was ", "are ",
              "of ", "to ", "in ", "he ", "she ", "it ", ". "]

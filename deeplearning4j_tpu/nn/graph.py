@@ -29,11 +29,11 @@ from deeplearning4j_tpu.nn.conf.graph import (
 from deeplearning4j_tpu.nn.conf.graph_builder import ComputationGraphConfiguration
 from deeplearning4j_tpu.nn.netcommon import (CostAnalysisMixin, EvalMixin,
                                               FitLoopMixin, LazyScoreMixin,
+                                              apply_layer, build_train_step,
                                               jit_init, ScanFitMixin,
                                               SentinelMixin, ShardCheckMixin,
 )
-from deeplearning4j_tpu.nn.remat import checkpoint_after_cotangent
-from deeplearning4j_tpu.nn.updater import build_optimizer, compute_updates
+from deeplearning4j_tpu.nn.updater import build_optimizer, l1_l2_penalty
 from deeplearning4j_tpu.optimize.listeners import IterationListener, TrainingListener
 from deeplearning4j_tpu.profiling.tracer import get_tracer
 
@@ -80,7 +80,6 @@ class ComputationGraph(LazyScoreMixin, EvalMixin, FitLoopMixin, ScanFitMixin,
         self._infer_traces = 0          # trace counter (tests)
         self._rng = jax.random.PRNGKey(conf.training.seed)
         self._rnn_carries: Optional[Dict[str, Any]] = None  # rnnTimeStep
-        self._tbptt_step_fn = None
         self._decode_fns = None         # (prefill, decode) pure fns
         self._paged_decode_fns: Dict[int, Any] = {}  # page_len -> step fn
         # layer nodes in topological order (the trainable walk)
@@ -225,39 +224,15 @@ class ComputationGraph(LazyScoreMixin, EvalMixin, FitLoopMixin, ScanFitMixin,
                     out_masks[name] = cur_mask
                     new_states[name] = states[name]
                     continue
-                # remat (conf.gradient_checkpointing): recompute in backward
-                remat = train and self.conf.training.remat
-                if carries is not None \
-                        and getattr(layer, "supports_carry", False):
-                    c_in = carries.get(name)
-                    if c_in is None:
-                        c_in = layer.initial_carry(h.shape[0], h.dtype)
-                    # scan() bypasses apply(): input dropout must still fire
-                    # so tBPTT training regularizes like standard BPTT
-                    h = layer._dropout_input(
-                        h, train and not layer.frozen, sub)
-                    scan_fn = (jax.checkpoint(layer.scan) if remat
-                               else layer.scan)
-                    h, c_out = scan_fn(self._layer_params(params, name), h,
-                                       c_in, cur_mask)
-                    new_carries[name] = c_out
-                    s = states[name]
-                else:
-                    layer_train = train and not layer.frozen
-
-                    def apply_fn(p, hh, s_in, r, m, _l=layer, _t=layer_train):
-                        return _l.apply(p, hh, state=s_in, train=_t, rng=r,
-                                        mask=m)
-                    if remat:
-                        apply_fn = checkpoint_after_cotangent(apply_fn)
-                    h, s = apply_fn(self._layer_params(params, name), h,
-                                    states[name], sub, cur_mask)
-                    if layer.frozen:
-                        s = states[name]
-                acts[name] = h
-                # layers that consume or rearrange the time axis drop the mask
-                out_masks[name] = layer.propagate_mask(cur_mask)
-                new_states[name] = s
+                carried = carries is not None
+                (acts[name], new_states[name], carry,
+                 out_masks[name]) = apply_layer(
+                    layer, self._layer_params(params, name), h, states[name],
+                    sub, cur_mask, train=train,
+                    remat=train and self.conf.training.remat, carried=carried,
+                    carry=carries.get(name) if carried else None)
+                if carry is not None:
+                    new_carries[name] = carry
         if carries is not None:
             return acts, out_masks, new_states, new_carries
         return acts, out_masks, new_states
@@ -332,20 +307,24 @@ class ComputationGraph(LazyScoreMixin, EvalMixin, FitLoopMixin, ScanFitMixin,
                     labels[out_name], mask=lm)
         return total
 
+    def _layer_list(self):
+        return [self.conf.nodes[n].layer for n in self._layer_nodes]
+
+    def _with_penalties(self, data_loss, params, new_states) -> Array:
+        """The score: Σ output losses + L1/L2 over all layer params (ref:
+        CG.computeGradientAndScore:1016-1028) + the layers' auxiliary
+        losses."""
+        from deeplearning4j_tpu.nn.multilayer import _sum_aux_losses
+        total = data_loss + l1_l2_penalty(
+            [params[n] for n in self._layer_nodes], self._layer_list())
+        return total + _sum_aux_losses(new_states)
+
     def _loss_fn(self, params, states, inputs, labels: Dict[str, Array],
                  masks, label_masks, rng, train=True):
         acts, out_masks, new_states = self._forward(
             params, states, inputs, train=train, rng=rng, masks=masks)
         total = self._data_loss(params, acts, out_masks, labels, label_masks)
-        # L1/L2 over all layer params (score = Σ output losses + reg;
-        # ref: CG.computeGradientAndScore:1016-1028)
-        from deeplearning4j_tpu.nn.updater import l1_l2_penalty
-        layer_list = [self.conf.nodes[n].layer for n in self._layer_nodes]
-        param_list = [params[n] for n in self._layer_nodes]
-        total = total + l1_l2_penalty(param_list, layer_list)
-        from deeplearning4j_tpu.nn.multilayer import _sum_aux_losses
-        total = total + _sum_aux_losses(new_states)
-        return total, new_states
+        return self._with_penalties(total, params, new_states), new_states
 
     def score(self, data: Union[DataSet, MultiDataSet], train: bool = False) -> float:
         self._check_init()
@@ -379,50 +358,12 @@ class ComputationGraph(LazyScoreMixin, EvalMixin, FitLoopMixin, ScanFitMixin,
 
     # ------------------------------------------------------------- train step
     def _build_train_step(self):
-        tx = self._tx
-        training = self.conf.training
-        collect_grads = getattr(self, "_collect_grads", False)
-        sentinel = self._sentinel
-        if sentinel is not None:
-            from deeplearning4j_tpu.resilience.sentinel import guard_update
-        from deeplearning4j_tpu.nn.updater import (
-            PrecisionPolicy, cast_floats, precision_value_and_grad,
-        )
-        policy = PrecisionPolicy.parse(
-            getattr(training, "precision", None),
-            loss_scale=getattr(training, "loss_scale", None))
-        mixed = policy.mixed
+        def loss_of(p, states, inputs, labels, masks, lmasks, _, rng):
+            loss, new_states = self._loss_fn(p, states, inputs, labels,
+                                             masks, lmasks, rng)
+            return loss, (new_states, None)
 
-        def train_step(params, opt_state, states, inputs, labels, masks,
-                       lmasks, rng):
-            if mixed:
-                # step-boundary cast seams: forward/backward in the
-                # compute dtype, fp32 master params stay the update's
-                inputs = cast_floats(inputs, policy.compute_dtype)
-                masks = cast_floats(masks, policy.compute_dtype)
-
-            def loss_for_grad(p):
-                return self._loss_fn(p, states, inputs, labels, masks,
-                                     lmasks, rng)
-
-            (loss, new_states), grads = precision_value_and_grad(
-                loss_for_grad, policy)(params)
-            layer_list = [self.conf.nodes[n].layer for n in self._layer_nodes]
-            new_params, new_opt = compute_updates(
-                tx, grads, opt_state, params, layer_list, training)
-            out_grads = grads if collect_grads else None
-            if sentinel is None:
-                return new_params, new_opt, new_states, loss, out_grads
-            # non-finite guard: a diverged update never lands (old state
-            # selected in-program — no host sync; see resilience/sentinel)
-            sel, bad = guard_update(
-                loss, grads, (params, opt_state, states),
-                (new_params, new_opt, new_states))
-            return sel[0], sel[1], sel[2], loss, out_grads, bad
-
-        # donate params/opt/states: ResNet-scale nets must not copy their
-        # whole state every step (HBM traffic + footprint)
-        return jax.jit(train_step, donate_argnums=(0, 1, 2))
+        return build_train_step(self, self._layer_list(), loss_of)
 
     def _fit_batch(self, data: Union[DataSet, MultiDataSet]) -> float:
         """``fit_batch`` under its span (ref: ComputationGraph.fit)."""
@@ -494,102 +435,55 @@ class ComputationGraph(LazyScoreMixin, EvalMixin, FitLoopMixin, ScanFitMixin,
 
     # ------------------------------------------------------------------ tBPTT
     def _build_tbptt_step(self):
-        tx = self._tx
         training = self.conf.training
         fwd = training.tbptt_fwd_length
         bwd = training.tbptt_bwd_length or fwd
         data_loss_of = self._data_loss
         rnn_inputs = self._tbptt_rnn_inputs()
-        sentinel = self._sentinel
-        if sentinel is not None:
-            from deeplearning4j_tpu.resilience.sentinel import guard_update
-        from deeplearning4j_tpu.nn.updater import (
-            PrecisionPolicy, cast_floats, precision_value_and_grad,
-        )
-        policy = PrecisionPolicy.parse(
-            getattr(training, "precision", None),
-            loss_scale=getattr(training, "loss_scale", None))
-        mixed = policy.mixed
 
-        def step(params, opt_state, states, inputs, labels, masks, lmasks,
-                 carries, rng):
-            if mixed:
-                inputs = cast_floats(inputs, policy.compute_dtype)
-                masks = cast_floats(masks, policy.compute_dtype)
+        def loss_of(p, states, inputs, labels, masks, lmasks, carries, rng):
             # bwd < fwd: run the slice head forward-only (stop-gradded
             # activations + carries), backprop through the last bwd steps
             # only — same semantics as MultiLayerNetwork._build_tbptt_step
             # (ref: ComputationGraph.doTruncatedBPTT:2042 shares the MLN
             # backward time-loop truncation via LSTMHelpers.java:333)
-            T = next(v.shape[1] for n, v in inputs.items()
-                     if n in rnn_inputs)
+            T = next(v.shape[1] for n, v in inputs.items() if n in rnn_inputs)
             split = max(T - bwd, 0) if bwd < fwd else 0
+            if split == 0:
+                acts, om, new_states, new_carries = self._forward(
+                    p, states, inputs, train=True, rng=rng, masks=masks,
+                    carries=carries)
+                data_loss = data_loss_of(p, acts, om, labels, lmasks)
+            else:
+                rng1, rng2 = (jax.random.split(rng) if rng is not None
+                              else (None, None))
+                head = lambda d, m=3, o=None: _time_slice(d, 0, split, m, only=o)
+                tail = lambda d, m=3, o=None: _time_slice(d, split, T, m, only=o)
+                acts1, om1, states1, carries1 = self._forward(
+                    p, states, head(inputs, o=rnn_inputs), train=True,
+                    rng=rng1, masks=head(masks, 2, rnn_inputs),
+                    carries=carries)
+                acts1 = jax.tree.map(jax.lax.stop_gradient, acts1)
+                carries1 = jax.tree.map(jax.lax.stop_gradient, carries1)
+                acts2, om2, new_states, new_carries = self._forward(
+                    p, states1, tail(inputs, o=rnn_inputs), train=True,
+                    rng=rng2, masks=tail(masks, 2, rnn_inputs),
+                    carries=carries1)
+                # per-timestep losses SUM over time: head + tail ==
+                # the single-call slice loss
+                data_loss = (
+                    data_loss_of(p, acts1, om1, head(labels), head(lmasks, 2))
+                    + data_loss_of(p, acts2, om2, tail(labels),
+                                   tail(lmasks, 2)))
+            return (self._with_penalties(data_loss, p, new_states),
+                    (new_states, new_carries))
 
-            def loss_for_grad(p):
-                if split == 0:
-                    acts, om, new_states, new_carries = self._forward(
-                        p, states, inputs, train=True, rng=rng, masks=masks,
-                        carries=carries)
-                    data_loss = data_loss_of(p, acts, om, labels, lmasks)
-                else:
-                    rng1, rng2 = (jax.random.split(rng) if rng is not None
-                                  else (None, None))
-                    head = lambda d, m=3, o=None: _time_slice(
-                        d, 0, split, m, only=o)
-                    tail = lambda d, m=3, o=None: _time_slice(
-                        d, split, T, m, only=o)
-                    acts1, om1, states1, carries1 = self._forward(
-                        p, states, head(inputs, o=rnn_inputs), train=True,
-                        rng=rng1, masks=head(masks, 2, rnn_inputs),
-                        carries=carries)
-                    acts1 = jax.tree.map(jax.lax.stop_gradient, acts1)
-                    carries1 = jax.tree.map(jax.lax.stop_gradient, carries1)
-                    acts2, om2, new_states, new_carries = self._forward(
-                        p, states1, tail(inputs, o=rnn_inputs),
-                        train=True, rng=rng2,
-                        masks=tail(masks, 2, rnn_inputs),
-                        carries=carries1)
-                    # per-timestep losses SUM over time: head + tail ==
-                    # the single-call slice loss
-                    data_loss = (
-                        data_loss_of(p, acts1, om1, head(labels),
-                                     head(lmasks, 2))
-                        + data_loss_of(p, acts2, om2, tail(labels),
-                                       tail(lmasks, 2)))
-                from deeplearning4j_tpu.nn.updater import l1_l2_penalty
-                layer_list = [self.conf.nodes[n].layer
-                              for n in self._layer_nodes]
-                param_list = [p[n] for n in self._layer_nodes]
-                from deeplearning4j_tpu.nn.multilayer import _sum_aux_losses
-                return (data_loss + l1_l2_penalty(param_list, layer_list)
-                        + _sum_aux_losses(new_states),
-                        (new_states, new_carries))
-
-            (loss, (new_states, new_carries)), grads = \
-                precision_value_and_grad(loss_for_grad, policy)(params)
-            layer_list = [self.conf.nodes[n].layer for n in self._layer_nodes]
-            new_params, new_opt = compute_updates(
-                tx, grads, opt_state, params, layer_list, training)
-            # stop gradients across tBPTT boundaries
-            new_carries = jax.tree.map(jax.lax.stop_gradient, new_carries)
-            if sentinel is None:
-                return new_params, new_opt, new_states, new_carries, loss
-            # non-finite guard incl. carries: a NaN window must not
-            # poison the next window's recurrent state
-            sel, bad = guard_update(
-                loss, grads, (params, opt_state, states, carries),
-                (new_params, new_opt, new_states, new_carries))
-            return sel[0], sel[1], sel[2], sel[3], loss, bad
-
-        return jax.jit(step, donate_argnums=(0, 1, 2))
+        return build_train_step(self, self._layer_list(), loss_of,
+                                carried=True)
 
     def _fit_tbptt(self, data: Union[DataSet, MultiDataSet]) -> float:
         """Truncated BPTT over time slices, carrying per-node RNN state
         (ref: ComputationGraph.doTruncatedBPTT:2042-2103)."""
-        if self._tbptt_step_fn is None:
-            self._tbptt_step_fn = self._build_tbptt_step()
-        self.last_grads = None  # tBPTT step doesn't collect gradients
-        fwd = self.conf.training.tbptt_fwd_length
         inputs, labels, masks, lmasks = self._split(data)
         rnn_inputs = self._tbptt_rnn_inputs()
         T = next(v.shape[1] for n, v in inputs.items() if n in rnn_inputs)
@@ -602,29 +496,14 @@ class ComputationGraph(LazyScoreMixin, EvalMixin, FitLoopMixin, ScanFitMixin,
                    for name in self._layer_nodes
                    if getattr(self.conf.nodes[name].layer,
                               "supports_carry", False)}
-        total, slices = 0.0, 0
-        for start in range(0, T, fwd):
-            end = min(start + fwd, T)
-            self._rng, step_rng = jax.random.split(self._rng)
-            out = self._tbptt_step_fn(
-                self.params, self.opt_state, self.states,
-                _time_slice(inputs, start, end, only=rnn_inputs),
-                _time_slice(labels, start, end),
-                _time_slice(masks, start, end, 2, rnn_inputs),
-                _time_slice(lmasks, start, end, 2),
-                carries, step_rng)
-            (self.params, self.opt_state, self.states, carries, loss) = \
-                out[:5]
-            total = total + loss  # device accumulate — no per-slice sync
-            slices += 1
-            self.iteration_count += 1
-            self.score_value = loss
-            self._observe_sentinel(out[5] if len(out) > 5 else None)
-            for listener in self.listeners:
-                listener.iteration_done(self, self.iteration_count,
-                                        self.score_value)
-        self.last_batch_size = data.num_examples()
-        return total / max(slices, 1)
+
+        def window(start, end):
+            return (_time_slice(inputs, start, end, only=rnn_inputs),
+                    _time_slice(labels, start, end),
+                    _time_slice(masks, start, end, 2, rnn_inputs),
+                    _time_slice(lmasks, start, end, 2))
+
+        return self._tbptt_steps(data, T, carries, window)
 
     # ------------------------------------------------------- rnn statefulness
     def rnn_clear_previous_state(self) -> None:

@@ -37,12 +37,11 @@ from deeplearning4j_tpu.nn.conf.builder import MultiLayerConfiguration
 from deeplearning4j_tpu.nn.layers.base import BaseLayerConf
 from deeplearning4j_tpu.nn.netcommon import (CostAnalysisMixin, EvalMixin,
                                               FitLoopMixin, LazyScoreMixin,
+                                              apply_layer, build_train_step,
                                               jit_init, ScanFitMixin,
                                               SentinelMixin, ShardCheckMixin,
 )
-from deeplearning4j_tpu.nn.updater import (
-    build_optimizer, compute_updates, l1_l2_penalty,
-)
+from deeplearning4j_tpu.nn.updater import build_optimizer, l1_l2_penalty
 from deeplearning4j_tpu.optimize.listeners import IterationListener, TrainingListener
 
 Array = jax.Array
@@ -161,43 +160,16 @@ class MultiLayerNetwork(LazyScoreMixin, EvalMixin, FitLoopMixin,
                     rng, sub = jax.random.split(rng)
                 else:
                     sub = None
-                is_last = i == n - 1
-                if is_last and hasattr(layer, "compute_loss"):
+                if i == n - 1 and hasattr(layer, "compute_loss"):
                     # loss head consumes the pre-layer activation
                     acts.append(h)
                     new_states.append(states[i])
                     break
-                # remat: recompute this layer's activations in backward
-                # instead of storing them (conf.gradient_checkpointing) —
-                # trades FLOPs for HBM on memory-bound models
-                remat = train and self.conf.training.remat
-                if carries is not None \
-                        and getattr(layer, "supports_carry", False):
-                    c_in = carries[i]
-                    if c_in is None:
-                        c_in = layer.initial_carry(h.shape[0], h.dtype)
-                    # scan() bypasses apply(): input dropout must still fire
-                    # so tBPTT training regularizes like standard BPTT
-                    h = layer._dropout_input(
-                        h, train and not layer.frozen, sub)
-                    scan_fn = (jax.checkpoint(layer.scan) if remat
-                               else layer.scan)
-                    h, c_out = scan_fn(params[i], h, c_in, cur_mask)
-                    new_carries[i] = c_out
-                    s = states[i]
-                else:
-                    layer_train = train and not layer.frozen
-
-                    def apply_fn(p, hh, s_in, r, m, _l=layer, _t=layer_train):
-                        return _l.apply(p, hh, state=s_in, train=_t, rng=r,
-                                        mask=m)
-                    if remat:
-                        apply_fn = jax.checkpoint(apply_fn)
-                    h, s = apply_fn(params[i], h, states[i], sub, cur_mask)
-                    if layer.frozen:
-                        s = states[i]  # frozen: BN running stats don't move
-                # layers that consume or rearrange the time axis drop the mask
-                cur_mask = layer.propagate_mask(cur_mask)
+                carried = carries is not None
+                h, s, new_carries[i], cur_mask = apply_layer(
+                    layer, params[i], h, states[i], sub, cur_mask,
+                    train=train, remat=train and self.conf.training.remat,
+                    carried=carried, carry=carries[i] if carried else None)
                 new_states.append(s)
                 if collect:
                     acts.append(h)
@@ -255,8 +227,10 @@ class MultiLayerNetwork(LazyScoreMixin, EvalMixin, FitLoopMixin,
     def _aux_losses(states) -> "jnp.ndarray":
         return _sum_aux_losses(states)
 
-    def _loss_fn(self, params, states, features, labels, fmask, lmask, rng,
-                 train: bool = True):
+    def _loss_and_head_input(self, params, states, features, labels, fmask,
+                             lmask, rng, train: bool = True):
+        """``(loss, (new_states, h))``: the data loss with the penalty and
+        the layers' auxiliary losses, and ``h``, what the loss head took."""
         h, _, new_states, _, cur_mask = self._forward(
             params, states, features, train=train, rng=rng, mask=fmask)
         out_layer = self.layers[-1]
@@ -266,7 +240,13 @@ class MultiLayerNetwork(LazyScoreMixin, EvalMixin, FitLoopMixin,
             cur_mask if labels.ndim > 2 else None)
         data_loss = out_layer.compute_loss(params[-1], h, labels, mask=mask)
         reg = l1_l2_penalty(params, self.layers)
-        return data_loss + reg + _sum_aux_losses(new_states), new_states
+        return data_loss + reg + _sum_aux_losses(new_states), (new_states, h)
+
+    def _loss_fn(self, params, states, features, labels, fmask, lmask, rng,
+                 train: bool = True):
+        loss, (new_states, _) = self._loss_and_head_input(
+            params, states, features, labels, fmask, lmask, rng, train)
+        return loss, new_states
 
     def score(self, dataset: Optional[DataSet] = None, train: bool = False) -> float:
         """Mean per-example loss + regularization
@@ -284,61 +264,23 @@ class MultiLayerNetwork(LazyScoreMixin, EvalMixin, FitLoopMixin,
 
     # ------------------------------------------------------------- train step
     def _build_train_step(self):
-        tx = self._tx
-        training = self.conf.training
-        collect_grads = getattr(self, "_collect_grads", False)
-        sentinel = self._sentinel
-        if sentinel is not None:
-            from deeplearning4j_tpu.resilience.sentinel import guard_update
         from deeplearning4j_tpu.nn.layers.core import CenterLossOutputLayer
-        from deeplearning4j_tpu.nn.updater import (
-            PrecisionPolicy, cast_floats, precision_value_and_grad,
-        )
+
+        def loss_of(p, states, features, labels, fmask, lmask, _, rng):
+            return self._loss_and_head_input(p, states, features, labels,
+                                             fmask, lmask, rng)
+
+        def move_centers(params, new_params, h_last, labels):
+            # EMA center update outside the gradient step
+            # (ref: CenterLossOutputLayer alpha semantics)
+            new_params[-1]["cL"] = self.layers[-1].updated_centers(
+                {"cL": params[-1]["cL"]}, h_last, labels)
+            return new_params
+
         center_loss_head = isinstance(self.layers[-1], CenterLossOutputLayer)
-        policy = PrecisionPolicy.parse(
-            getattr(training, "precision", None),
-            loss_scale=getattr(training, "loss_scale", None))
-        mixed = policy.mixed
-
-        def train_step(params, opt_state, states, features, labels, fmask,
-                       lmask, rng):
-            if mixed:
-                # step-boundary cast seams: forward/backward in the
-                # compute dtype, fp32 master params stay the update's
-                features = cast_floats(features, policy.compute_dtype)
-                fmask = cast_floats(fmask, policy.compute_dtype)
-
-            def loss_for_grad(p):
-                h, _, new_states, _, cur_mask = self._forward(
-                    p, states, features, train=True, rng=rng, mask=fmask)
-                out_layer = self.layers[-1]
-                mask = lmask if lmask is not None else (
-                    cur_mask if labels.ndim > 2 else None)
-                data_loss = out_layer.compute_loss(p[-1], h, labels, mask=mask)
-                reg = l1_l2_penalty(p, self.layers)
-                return (data_loss + reg + _sum_aux_losses(new_states),
-                        (new_states, h))
-
-            (loss, (new_states, h_last)), grads = precision_value_and_grad(
-                loss_for_grad, policy)(params)
-            new_params, new_opt = compute_updates(
-                tx, grads, opt_state, params, self.layers, training)
-            if center_loss_head:
-                # EMA center update outside the gradient step
-                # (ref: CenterLossOutputLayer alpha semantics)
-                new_params[-1]["cL"] = self.layers[-1].updated_centers(
-                    {"cL": params[-1]["cL"]}, h_last, labels)
-            out_grads = grads if collect_grads else None
-            if sentinel is None:
-                return new_params, new_opt, new_states, loss, out_grads
-            # non-finite guard: a diverged update never lands (the old
-            # state is selected in-program — no host sync)
-            sel, bad = guard_update(
-                loss, grads, (params, opt_state, states),
-                (new_params, new_opt, new_states))
-            return sel[0], sel[1], sel[2], loss, out_grads, bad
-
-        return jax.jit(train_step, donate_argnums=(0, 1, 2))
+        return build_train_step(
+            self, self.layers, loss_of,
+            after_update=move_centers if center_loss_head else None)
 
     def _fit_batch(self, dataset: DataSet) -> float:
         """``fit_batch`` under its span (ref: fit(DataSet))."""
@@ -375,26 +317,11 @@ class MultiLayerNetwork(LazyScoreMixin, EvalMixin, FitLoopMixin,
 
     # ------------------------------------------------------------------ tBPTT
     def _build_tbptt_step(self):
-        tx = self._tx
         training = self.conf.training
         fwd = training.tbptt_fwd_length
         bwd = training.tbptt_bwd_length or fwd
-        sentinel = self._sentinel
-        if sentinel is not None:
-            from deeplearning4j_tpu.resilience.sentinel import guard_update
-        from deeplearning4j_tpu.nn.updater import (
-            PrecisionPolicy, cast_floats, precision_value_and_grad,
-        )
-        policy = PrecisionPolicy.parse(
-            getattr(training, "precision", None),
-            loss_scale=getattr(training, "loss_scale", None))
-        mixed = policy.mixed
 
-        def step(params, opt_state, states, features, labels, fmask, lmask,
-                 carries, rng):
-            if mixed:
-                features = cast_floats(features, policy.compute_dtype)
-                fmask = cast_floats(fmask, policy.compute_dtype)
+        def loss_of(p, states, features, labels, fmask, lmask, carries, rng):
             # When bwd < fwd the reference's backward time-loop only visits
             # the LAST bwd steps of each fwd slice
             # (MultiLayerNetwork.java:1119 + LSTMHelpers.java:333
@@ -410,68 +337,43 @@ class MultiLayerNetwork(LazyScoreMixin, EvalMixin, FitLoopMixin,
             def seg(x, lo, hi):
                 return None if x is None else x[:, lo:hi]
 
-            def loss_for_grad(p):
-                out_layer = self.layers[-1]
-                if split == 0:
-                    h, _, new_states, new_carries, cur_mask = self._forward(
-                        p, states, features, train=True, rng=rng, mask=fmask,
-                        carries=carries)
-                    mask = lmask if lmask is not None else cur_mask
-                    data_loss = out_layer.compute_loss(p[-1], h, labels,
-                                                       mask=mask)
-                else:
-                    rng1, rng2 = jax.random.split(rng)
-                    h1, _, states1, carries1, m1 = self._forward(
-                        p, states, seg(features, 0, split), train=True,
-                        rng=rng1, mask=seg(fmask, 0, split), carries=carries)
-                    h1 = jax.lax.stop_gradient(h1)
-                    carries1 = jax.tree.map(jax.lax.stop_gradient, carries1)
-                    h2, _, new_states, new_carries, m2 = self._forward(
-                        p, states1, seg(features, split, T), train=True,
-                        rng=rng2, mask=seg(fmask, split, T),
-                        carries=carries1)
-                    mask1 = seg(lmask, 0, split) if lmask is not None else m1
-                    mask2 = seg(lmask, split, T) if lmask is not None else m2
-                    # per-timestep losses SUM over time, so head + tail ==
-                    # the single-call slice loss
-                    data_loss = (
-                        out_layer.compute_loss(p[-1], h1,
-                                               seg(labels, 0, split),
-                                               mask=mask1)
-                        + out_layer.compute_loss(p[-1], h2,
-                                                 seg(labels, split, T),
-                                                 mask=mask2))
-                reg = l1_l2_penalty(p, self.layers)
-                # aux losses (MoE balancing etc.) — keep parity with the
-                # standard step and the graph container's tBPTT step
-                return (data_loss + reg + _sum_aux_losses(new_states),
-                        (new_states, new_carries))
+            out_layer = self.layers[-1]
+            if split == 0:
+                h, _, new_states, new_carries, cur_mask = self._forward(
+                    p, states, features, train=True, rng=rng, mask=fmask,
+                    carries=carries)
+                mask = lmask if lmask is not None else cur_mask
+                data_loss = out_layer.compute_loss(p[-1], h, labels, mask=mask)
+            else:
+                rng1, rng2 = jax.random.split(rng)
+                h1, _, states1, carries1, m1 = self._forward(
+                    p, states, seg(features, 0, split), train=True,
+                    rng=rng1, mask=seg(fmask, 0, split), carries=carries)
+                h1 = jax.lax.stop_gradient(h1)
+                carries1 = jax.tree.map(jax.lax.stop_gradient, carries1)
+                h2, _, new_states, new_carries, m2 = self._forward(
+                    p, states1, seg(features, split, T), train=True,
+                    rng=rng2, mask=seg(fmask, split, T), carries=carries1)
+                mask1 = seg(lmask, 0, split) if lmask is not None else m1
+                mask2 = seg(lmask, split, T) if lmask is not None else m2
+                # per-timestep losses SUM over time, so head + tail ==
+                # the single-call slice loss
+                data_loss = (
+                    out_layer.compute_loss(p[-1], h1, seg(labels, 0, split),
+                                           mask=mask1)
+                    + out_layer.compute_loss(p[-1], h2, seg(labels, split, T),
+                                             mask=mask2))
+            reg = l1_l2_penalty(p, self.layers)
+            # aux losses (MoE balancing etc.) — keep parity with the
+            # standard step and the graph container's tBPTT step
+            return (data_loss + reg + _sum_aux_losses(new_states),
+                    (new_states, new_carries))
 
-            (loss, (new_states, new_carries)), grads = \
-                precision_value_and_grad(loss_for_grad, policy)(params)
-            new_params, new_opt = compute_updates(
-                tx, grads, opt_state, params, self.layers, training)
-            # stop gradients across tBPTT boundaries
-            new_carries = jax.tree.map(jax.lax.stop_gradient, new_carries)
-            if sentinel is None:
-                return new_params, new_opt, new_states, new_carries, loss
-            # non-finite guard incl. carries: a NaN window must not
-            # poison the next window's recurrent state
-            sel, bad = guard_update(
-                loss, grads, (params, opt_state, states, carries),
-                (new_params, new_opt, new_states, new_carries))
-            return sel[0], sel[1], sel[2], sel[3], loss, bad
-
-        return jax.jit(step, donate_argnums=(0, 1, 2))
+        return build_train_step(self, self.layers, loss_of, carried=True)
 
     def _fit_tbptt(self, dataset: DataSet) -> float:
         """Truncated BPTT over time slices, carrying RNN state
         (ref: MultiLayerNetwork.doTruncatedBPTT:1119-1183)."""
-        if not hasattr(self, "_tbptt_step_fn") or self._tbptt_step_fn is None:
-            self._tbptt_step_fn = self._build_tbptt_step()
-        self.last_grads = None  # tBPTT step doesn't collect gradients
-        fwd = self.conf.training.tbptt_fwd_length
-        T = dataset.features.shape[1]
         carries: list = [None] * len(self.layers)
         # materialize initial carries so the jit signature is stable
         B = dataset.features.shape[0]
@@ -479,30 +381,14 @@ class MultiLayerNetwork(LazyScoreMixin, EvalMixin, FitLoopMixin,
         for i, l in enumerate(self.layers):
             if getattr(l, "supports_carry", False):
                 carries[i] = l.initial_carry(B, dt)  # training dtype
-        total, slices = 0.0, 0
-        for start in range(0, T, fwd):
-            end = min(start + fwd, T)
-            feats = jnp.asarray(dataset.features[:, start:end])
-            labs = jnp.asarray(dataset.labels[:, start:end])
-            fm = (None if dataset.features_mask is None
-                  else jnp.asarray(dataset.features_mask[:, start:end]))
-            lm = (None if dataset.labels_mask is None
-                  else jnp.asarray(dataset.labels_mask[:, start:end]))
-            self._rng, step_rng = jax.random.split(self._rng)
-            out = self._tbptt_step_fn(self.params, self.opt_state,
-                                      self.states, feats, labs, fm, lm,
-                                      carries, step_rng)
-            self.params, self.opt_state, self.states, carries, loss = \
-                out[:5]
-            total = total + loss  # device accumulate — no per-slice sync
-            slices += 1
-            self.iteration_count += 1
-            self.score_value = loss
-            self._observe_sentinel(out[5] if len(out) > 5 else None)
-            for listener in self.listeners:
-                listener.iteration_done(self, self.iteration_count, self.score_value)
-        self.last_batch_size = dataset.num_examples()
-        return total / max(slices, 1)
+
+        def window(start, end):
+            seg = lambda a: None if a is None else jnp.asarray(a[:, start:end])
+            return (seg(dataset.features), seg(dataset.labels),
+                    seg(dataset.features_mask), seg(dataset.labels_mask))
+
+        return self._tbptt_steps(dataset, dataset.features.shape[1], carries,
+                                 window)
 
     # -------------------------------------------------------------------- fit
     def fit(self, data, labels=None, epochs: int = 1,

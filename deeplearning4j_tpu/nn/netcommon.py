@@ -16,6 +16,10 @@ two copies from drifting:
   compile and a single execution.
 - ``FitLoopMixin``: the epoch loop of ``fit`` and the standard step of
   ``fit_batch``, written once so that they are instrumented once.
+- ``apply_layer`` and ``build_train_step``: what applying one layer inside
+  a step means, and the shell of a compiled training step (cast seams,
+  gradient, update, guard, result, donation). Each container's
+  ``_forward`` keeps its own walk and its step builders their own loss.
 """
 
 from __future__ import annotations
@@ -24,6 +28,10 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.datasets.iterator import AsyncDataSetIterator
+from deeplearning4j_tpu.nn.remat import checkpoint_after_cotangent
+from deeplearning4j_tpu.nn.updater import (
+    PrecisionPolicy, cast_floats, compute_updates, precision_value_and_grad,
+)
 from deeplearning4j_tpu.optimize.listeners import TrainingListener
 from deeplearning4j_tpu.profiling.metrics import get_registry
 from deeplearning4j_tpu.profiling.tracer import get_tracer
@@ -79,8 +87,7 @@ class SentinelMixin:
         self._train_step_fn = None
         # derived caches key on _train_step_fn identity or are rebuilt
         # lazily; the tBPTT step is cached separately
-        if getattr(self, "_tbptt_step_fn", None) is not None:
-            self._tbptt_step_fn = None
+        self._tbptt_step_fn = None
         return self
 
     def _observe_sentinel(self, flag) -> None:
@@ -90,11 +97,133 @@ class SentinelMixin:
             self._sentinel.observe(flag, self.iteration_count)
 
 
+def apply_layer(layer, params, h, state, rng, mask, *, train: bool,
+                remat: bool, carried: bool = False, carry=None):
+    """One layer (or layer node) of a container's forward walk, after its
+    preprocessor and the split of its key: ``(h, state, carry, mask)``.
+
+    ``carried`` says that the walk threads RNN carries (tBPTT,
+    ``rnn_time_step``): a layer that supports one then runs ``scan`` from
+    ``carry`` (its initial carry when None) and hands the new one back;
+    every other layer runs ``apply`` and hands back None. ``remat``
+    (``conf.gradient_checkpointing`` in a training step) keeps the layer's
+    input and rebuilds its activations in the backward pass, trading
+    FLOPs for HBM. A frozen layer runs in inference mode and keeps its
+    state (BN running stats don't move)."""
+    layer_train = train and not layer.frozen
+    if carried and getattr(layer, "supports_carry", False):
+        if carry is None:
+            carry = layer.initial_carry(h.shape[0], h.dtype)
+        # scan() bypasses apply(): input dropout must still fire so
+        # tBPTT training regularizes like standard BPTT
+        h = layer._dropout_input(h, layer_train, rng)
+        scan_fn = jax.checkpoint(layer.scan) if remat else layer.scan
+        h, carry = scan_fn(params, h, carry, mask)
+    else:
+        def apply_fn(p, hh, s_in, r, m):
+            return layer.apply(p, hh, state=s_in, train=layer_train, rng=r,
+                               mask=m)
+        if remat:
+            # jax.checkpoint alone does not bound memory on the chip: the
+            # compiler runs the rebuild early (nn/remat.py)
+            apply_fn = checkpoint_after_cotangent(apply_fn)
+        h, new_state = apply_fn(params, h, state, rng, mask)
+        if not layer.frozen:
+            state = new_state
+        carry = None
+    # layers that consume or rearrange the time axis drop the mask
+    return h, state, carry, layer.propagate_mask(mask)
+
+
+def step_result(guard: bool, loss, grads, old: tuple, new: tuple, *rest):
+    """A compiled step's result: ``(*new, loss, *rest)``, and with a
+    divergence sentinel attached ``(*selected, loss, *rest, bad)``: the
+    non-finite guard selects ``old`` in-program (no host sync), so a
+    diverged update never lands, and the flag goes to the sentinel's
+    drain (``resilience/sentinel.py``)."""
+    if not guard:
+        return (*new, loss, *rest)
+    from deeplearning4j_tpu.resilience.sentinel import guard_update
+    selected, bad = guard_update(loss, grads, old, new)
+    return (*selected, loss, *rest, bad)
+
+
+def build_train_step(net, layers, loss_of, *, carried: bool = False,
+                     after_update=None):
+    """The jitted training step of a container, standard or tBPTT.
+
+    The container gives what differs: ``layers`` (the list
+    ``compute_updates`` walks), and ``loss_of(params, states, inputs,
+    labels, masks, lmasks, carries, rng) -> (loss, (new_states, extra))``
+    where ``extra`` is the new carries of a ``carried`` (tBPTT) step and
+    otherwise whatever ``after_update(params, new_params, extra, labels)
+    -> new_params`` wants of the forward pass. The shell owns the rest:
+    the precision policy and its two cast seams at the step's boundary
+    (forward and backward in the compute dtype, fp32 master parameters
+    stay the update's), the gradient, the update, the sentinel's guard,
+    and the donation of parameters, updater state and layer states
+    (ResNet-scale nets must not copy their whole state every step).
+
+    Returns ``train_step(params, opt_state, states, inputs, labels, masks,
+    lmasks, rng) -> (params, opt_state, states, loss, grads or None[,
+    bad])``, or when ``carried`` ``step(..., lmasks, carries, rng) ->
+    (params, opt_state, states, carries, loss[, bad])`` with the carries
+    guarded too (a NaN window must not poison the next window's recurrent
+    state). The trace's ``jit_train_step`` is the first one's name."""
+    tx, training = net._tx, net.conf.training
+    collect_grads = (not carried) and getattr(net, "_collect_grads", False)
+    guard = net._sentinel is not None
+    policy = PrecisionPolicy.parse(
+        getattr(training, "precision", None),
+        loss_scale=getattr(training, "loss_scale", None))
+
+    def run(params, opt_state, states, inputs, labels, masks, lmasks,
+            carries, rng):
+        if policy.mixed:
+            inputs = cast_floats(inputs, policy.compute_dtype)
+            masks = cast_floats(masks, policy.compute_dtype)
+
+        def loss_for_grad(p):
+            return loss_of(p, states, inputs, labels, masks, lmasks,
+                           carries, rng)
+
+        (loss, (new_states, extra)), grads = precision_value_and_grad(
+            loss_for_grad, policy)(params)
+        new_params, new_opt = compute_updates(
+            tx, grads, opt_state, params, layers, training)
+        if after_update is not None:
+            new_params = after_update(params, new_params, extra, labels)
+        if not carried:
+            return step_result(
+                guard, loss, grads, (params, opt_state, states),
+                (new_params, new_opt, new_states),
+                grads if collect_grads else None)
+        # stop gradients across tBPTT boundaries
+        new_carries = jax.tree.map(jax.lax.stop_gradient, extra)
+        return step_result(
+            guard, loss, grads, (params, opt_state, states, carries),
+            (new_params, new_opt, new_states, new_carries))
+
+    # the argument names reach the compiled step's metadata
+    if carried:
+        def step(params, opt_state, states, inputs, labels, masks, lmasks,
+                 carries, rng):
+            return run(params, opt_state, states, inputs, labels, masks,
+                       lmasks, carries, rng)
+        return jax.jit(step, donate_argnums=(0, 1, 2))
+
+    def train_step(params, opt_state, states, inputs, labels, masks, lmasks,
+                   rng):
+        return run(params, opt_state, states, inputs, labels, masks, lmasks,
+                   None, rng)
+    return jax.jit(train_step, donate_argnums=(0, 1, 2))
+
+
 class FitLoopMixin:
     """``fit``'s epoch loop and ``fit_batch``'s standard step for both
     containers, under one set of host spans (what hangs when a compile
     or a transfer wedges, and where the loop's time goes between two
-    steps) and counters:
+    steps) and counters, and the tBPTT steps of one batch:
 
         fit                       one per fit() call
           input:wait              the feed's queue (datasets/iterator.py)
@@ -109,6 +238,8 @@ class FitLoopMixin:
     spans of both threads carry. Containers provide ``_fit_batch(data)``
     (which takes the standard path through ``_standard_step``) and
     ``_fit_epoch_scan``."""
+
+    _tbptt_step_fn = None
 
     def _fit_epochs(self, data, epochs: int, use_async: bool,
                     scan_window: int):
@@ -189,6 +320,36 @@ class FitLoopMixin:
                 listener.iteration_done(self, self.iteration_count,
                                         self.score_value)
         return self._score_raw
+
+
+    def _tbptt_steps(self, data, T: int, carries, window) -> float:
+        """The truncated-BPTT steps of one batch of ``T`` time steps: one
+        compiled step per ``tbptt_fwd_length`` of them, the RNN carries
+        handed from each to the next. ``window(start, end)`` gives the
+        step's batch arguments for that slice of the time axis. Returns
+        the mean of the slices' losses (a device scalar)."""
+        if self._tbptt_step_fn is None:
+            self._tbptt_step_fn = self._build_tbptt_step()
+        self.last_grads = None  # tBPTT step doesn't collect gradients
+        fwd = self.conf.training.tbptt_fwd_length
+        total, slices = 0.0, 0
+        for start in range(0, T, fwd):
+            self._rng, step_rng = jax.random.split(self._rng)
+            out = self._tbptt_step_fn(
+                self.params, self.opt_state, self.states,
+                *window(start, min(start + fwd, T)), carries, step_rng)
+            (self.params, self.opt_state, self.states, carries,
+             loss) = out[:5]
+            total = total + loss  # device accumulate — no per-slice sync
+            slices += 1
+            self.iteration_count += 1
+            self.score_value = loss
+            self._observe_sentinel(out[5] if len(out) > 5 else None)
+            for listener in self.listeners:
+                listener.iteration_done(self, self.iteration_count,
+                                        self.score_value)
+        self.last_batch_size = data.num_examples()
+        return total / max(slices, 1)
 
 
 class EvalMixin:

@@ -9,8 +9,7 @@ ones an MFU hunt on a chip actually needs:
 
 - ``data_wait``   host blocked on the iterator for the next batch —
                   the INPUT STALL: ``export()`` surfaces its total as
-                  the top-level ``input_stall_s`` field (the same
-                  number every bench rung record carries), so
+                  the top-level ``input_stall_s`` field, so
                   input-bound vs compute-bound time is one comparison
 - ``shard``       host->device placement (device_put / batch sharding)
 - ``step``        device step wall time (the flag forces a

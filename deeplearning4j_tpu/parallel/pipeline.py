@@ -37,6 +37,7 @@ from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from deeplearning4j_tpu.datasets.dataset import DataSet
+from deeplearning4j_tpu.nn.netcommon import step_result
 from deeplearning4j_tpu.nn.updater import compute_updates, l1_l2_penalty
 from deeplearning4j_tpu.profiling import get_tracer
 
@@ -967,8 +968,6 @@ class PipelineTrainer(_RingFitMixin):
                     (new_sbuf, new_cbuf))
 
         sentinel = getattr(net, "_sentinel", None)
-        if sentinel is not None:
-            from deeplearning4j_tpu.resilience.sentinel import guard_update
 
         def step(params, opt_state, states, cbuf, xs, labels, rng):
             sbuf = pack_states(states)
@@ -976,15 +975,12 @@ class PipelineTrainer(_RingFitMixin):
                 loss_of, has_aux=True)(params, sbuf, cbuf, xs, labels, rng)
             new_params, new_opt = compute_updates(
                 tx, grads, opt_state, params, net.layers, training)
-            if sentinel is None:
-                return (new_params, new_opt, unpack_states(new_sbuf),
-                        new_cbuf, loss)
-            # non-finite guard incl. the carry buffer: a NaN window must
-            # not poison the next tBPTT window's carries
-            sel, bad = guard_update(
-                loss, grads, (params, opt_state, states, cbuf),
+            # the guard takes in the carry buffer: a NaN window must not
+            # poison the next tBPTT window's carries
+            return step_result(
+                sentinel is not None, loss, grads,
+                (params, opt_state, states, cbuf),
                 (new_params, new_opt, unpack_states(new_sbuf), new_cbuf))
-            return sel[0], sel[1], sel[2], sel[3], loss, bad
 
         return jax.jit(step, donate_argnums=(0, 1, 2, 3))
 
@@ -1384,8 +1380,6 @@ class GraphPipelineTrainer(_RingFitMixin):
             return data_loss + reg, (new_sbuf, new_cbuf)
 
         sentinel = getattr(net, "_sentinel", None)
-        if sentinel is not None:
-            from deeplearning4j_tpu.resilience.sentinel import guard_update
 
         def step(params, opt_state, states, cbuf, xs, labels, rng):
             sbuf = pack_states(states)
@@ -1393,15 +1387,12 @@ class GraphPipelineTrainer(_RingFitMixin):
                 loss_of, has_aux=True)(params, sbuf, cbuf, xs, labels, rng)
             new_params, new_opt = compute_updates(
                 tx, grads, opt_state, params, layer_list, training)
-            if sentinel is None:
-                return (new_params, new_opt, unpack_states(new_sbuf),
-                        new_cbuf, loss)
-            # non-finite guard incl. the carry buffer (see the MLN
-            # pipeline step above)
-            sel, bad = guard_update(
-                loss, grads, (params, opt_state, states, cbuf),
+            # the guard takes in the carry buffer (see the MLN pipeline
+            # step above)
+            return step_result(
+                sentinel is not None, loss, grads,
+                (params, opt_state, states, cbuf),
                 (new_params, new_opt, unpack_states(new_sbuf), new_cbuf))
-            return sel[0], sel[1], sel[2], sel[3], loss, bad
 
         return jax.jit(step, donate_argnums=(0, 1, 2, 3))
 

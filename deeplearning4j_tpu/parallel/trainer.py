@@ -25,7 +25,7 @@ import numpy as np
 from deeplearning4j_tpu.datasets.dataset import DataSet
 from deeplearning4j_tpu.datasets.iterator import AsyncDataSetIterator, DataSetIterator
 from deeplearning4j_tpu.nn.netcommon import (
-    ScanFitMixin, emit_scan_burst, make_scan_fit,
+    ScanFitMixin, emit_scan_burst, make_scan_fit, step_result,
 )
 from deeplearning4j_tpu.nn.updater import (
     PrecisionPolicy, cast_floats, compute_updates, compute_updates_sharded,
@@ -164,9 +164,6 @@ class ParallelTrainer:
         tx = net._tx
         accum = self.gradient_accumulation
         sentinel = getattr(net, "_sentinel", None)
-        if sentinel is not None:
-            from deeplearning4j_tpu.resilience.sentinel import guard_update
-
         layers = self._layers
         sharded = self.weight_update_sharding.enabled
         zero2 = self.weight_update_sharding.zero2
@@ -303,18 +300,15 @@ class ParallelTrainer:
             else:
                 new_params, new_opt = compute_updates(
                     tx, grads, opt_state, params, layers, training)
-            if sentinel is None:
-                return new_params, new_opt, new_states, loss
-            # non-finite guard: a diverged update never lands (old state
-            # selected in-program — no host sync). Under zero1/zero2
-            # `grads` are the sharded (dp, chunk) views, so the guard's
-            # grad-norm reduction is a psum of local-shard norms — same
-            # flag value, no extra gather. Under a mixed policy both
-            # loss and grads crossed the fp32 seam before reaching it.
-            sel, bad = guard_update(
-                loss, grads, (params, opt_state, states),
+            # the sentinel's guard: under zero1/zero2 `grads` are the
+            # sharded (dp, chunk) views, so its grad-norm reduction is a
+            # psum of local-shard norms — same flag value, no extra
+            # gather. Under a mixed policy both loss and grads crossed
+            # the fp32 seam before reaching it.
+            return step_result(
+                sentinel is not None, loss, grads,
+                (params, opt_state, states),
                 (new_params, new_opt, new_states))
-            return sel[0], sel[1], sel[2], loss, bad
 
         donate = (0, 1, 2) if self._donate else ()
         return jax.jit(step, donate_argnums=donate)

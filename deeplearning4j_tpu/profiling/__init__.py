@@ -10,8 +10,8 @@ diagnostics) and for the question a TPU port actually asks (where did
 
 - ``tracer`` — thread-safe span tracer exporting Chrome trace-event
   JSON (open the file in Perfetto / chrome://tracing). A process-global
-  default tracer (``get_tracer()``) is emitted into by the containers,
-  all three parallel trainers, and ``bench.py``; its *open-span stack*
+  default tracer (``get_tracer()``) is emitted into by the containers
+  and all three parallel trainers; its *open-span stack*
   names the phase in flight when something hangs.
 - ``metrics`` — process-global registry of counters / gauges /
   fixed-bucket histograms, exposed as JSON and Prometheus text on the
@@ -31,8 +31,7 @@ diagnostics) and for the question a TPU port actually asks (where did
   one back.
 
 No jax import at module load: the tracer/metrics/flightrec/watchdog
-legs are pure stdlib and must stay importable from the bench
-supervisor and lint tooling.
+legs are pure stdlib and must stay importable from the lint tooling.
 """
 
 from deeplearning4j_tpu.profiling.tracer import (  # noqa: F401

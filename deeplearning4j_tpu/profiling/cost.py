@@ -11,17 +11,14 @@ picked convolution configurations from per-layer cost models instead of
 device sweeps.
 
 ``train_step_cost(net, batch)`` drives it for either container (and for
-the SPMD ``ParallelTrainer``'s step via the net it wraps). The numbers
-feed three consumers: ``bench.py`` rung records (``flops_per_step``,
-``analytic_mfu``), ``TrainingStats.export()`` (set ``stats.set_cost``),
-and direct calls from perf work.
+the SPMD ``ParallelTrainer``'s step via the net it wraps). Its readers:
+``net.cost_analysis``, ``TrainingStats.export()`` (``stats.set_cost``),
+the autotuner's model and shardcheck's comm-bytes rule.
 
-``weight_update_cost(net, dp, ...)`` models the data-parallel trainers'
-weight-update traffic and updater-state/gradient HBM per chip for all
-three layouts (replicated, ``weight_update_sharding="zero1"``,
-``"zero2"``) — the ``comm_bytes_per_step`` / ``updater_hbm_bytes`` /
-``gradient_hbm_bytes`` fields BENCH records carry so a real-TPU ladder
-can attribute an MFU delta to the layout.
+``dp_comm_bytes_per_update`` and ``dp_gradient_hbm_bytes`` model the
+data-parallel trainers' weight-update traffic and gradient HBM per chip
+for the three layouts (replicated, ``weight_update_sharding="zero1"``,
+``"zero2"``).
 
 NOTE: the AOT ``lower().compile()`` pays one real XLA compile and its
 executable is NOT reused by later ``net.fit_batch`` calls (jax's jit
@@ -116,8 +113,7 @@ def dp_comm_bytes_per_update(param_count: int, dp: int,
                anchored replicated copy.
 
     At ``gradient_accumulation=4`` that is 8x vs 5x the reduce-scatter
-    unit — the win BENCH records quantify against the replicated
-    baseline. dp=1 is 0 either way (no cross-chip axis).
+    unit. dp=1 is 0 either way (no cross-chip axis).
     """
     from deeplearning4j_tpu.analysis.graphcheck import SHARDED_WUS_MODES
     dp = max(1, int(dp))
@@ -129,22 +125,6 @@ def dp_comm_bytes_per_update(param_count: int, dp: int,
     if weight_update_sharding in SHARDED_WUS_MODES:
         return (k + 1) * unit
     return 2 * k * unit
-
-
-def dp_updater_hbm_bytes(param_count: int, updater: str, dp: int,
-                         dtype_bytes: int = 4,
-                         weight_update_sharding: str = "off") -> int:
-    """Per-chip standing HBM of the optax updater state: ``slots . P.b``
-    replicated, divided by ``dp`` under zero1/zero2 (flattened
-    pad-to-divisible shards; per-leaf padding is < dp elements and
-    below this model's resolution)."""
-    from deeplearning4j_tpu.analysis.graphcheck import SHARDED_WUS_MODES
-    from deeplearning4j_tpu.analysis.memory import UPDATER_STATE_SLOTS
-    slots = UPDATER_STATE_SLOTS.get((updater or "").lower(), 2)
-    total = int(param_count) * int(dtype_bytes) * slots
-    if weight_update_sharding in SHARDED_WUS_MODES and dp > 1:
-        return -(-total // int(dp))
-    return total
 
 
 def dp_gradient_hbm_bytes(param_count: int, dp: int,
@@ -166,7 +146,7 @@ def dp_gradient_hbm_bytes(param_count: int, dp: int,
 
 
 # Per-net census cache (ISSUE 13): the autotuner's configuration sweeps
-# call weight_update_cost / train_step_cost once per CANDIDATE, but the
+# call param_census / train_step_cost once per CANDIDATE, but the
 # underlying numbers depend only on the net (param sizes, updater) and —
 # for the compiled census — the batch signature. Keyed on the net object
 # itself (weak: a released net must not pin its params' metadata — and
@@ -225,34 +205,6 @@ def _batch_signature(batch) -> tuple:
             sig(getattr(batch, "labels", None)),
             sig(getattr(batch, "features_mask", None)),
             sig(getattr(batch, "labels_mask", None)))
-
-
-def weight_update_cost(net, dp: int,
-                       gradient_accumulation: int = 1,
-                       weight_update_sharding: str = "off") -> dict:
-    """Both weight-update cost fields for an initialized container (or
-    a ``ParallelTrainer``'s wrapped net): analytic per-update comm bytes
-    and per-chip updater-state HBM, for the given data-parallel degree
-    and layout. Pure metadata — reads only param sizes and the conf
-    (memoized per net via :func:`param_census`, so a config sweep never
-    re-walks the model)."""
-    census = param_census(net)
-    param_count = census["param_count"]
-    dtype_bytes = census["dtype_bytes"]
-    updater = census["updater"]
-    return {
-        "weight_update_sharding": weight_update_sharding,
-        "dp": int(dp),
-        "gradient_accumulation": int(gradient_accumulation),
-        "comm_bytes_per_step": dp_comm_bytes_per_update(
-            param_count, dp, dtype_bytes, gradient_accumulation,
-            weight_update_sharding),
-        "updater_hbm_bytes": dp_updater_hbm_bytes(
-            param_count, updater, dp, dtype_bytes,
-            weight_update_sharding),
-        "gradient_hbm_bytes": dp_gradient_hbm_bytes(
-            param_count, dp, dtype_bytes, weight_update_sharding),
-    }
 
 
 def _normalize_cost(raw) -> dict:

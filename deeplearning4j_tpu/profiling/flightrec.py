@@ -18,7 +18,7 @@ Design notes:
   and never calls back into other subsystems (no tracer, no registry,
   no I/O).
 - No jax import at module load: the recorder must be importable from
-  the bench supervisor and the lint tooling without touching a backend.
+  the lint tooling without touching a backend.
 """
 
 from __future__ import annotations

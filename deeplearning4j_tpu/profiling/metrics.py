@@ -343,9 +343,9 @@ class MetricsRegistry:
 
     def snapshot(self, prefix: str = "") -> Dict[str, float]:
         """Scalar (counter/gauge) values whose name starts with
-        ``prefix`` — the cheap point-in-time view failure records embed
-        (bench.py stamps the ``resilience_*`` counters into rung
-        failures so a crash report carries its own fault history)."""
+        ``prefix`` — the cheap point-in-time view a failure record can
+        embed (the ``resilience_*`` counters, so that a crash report
+        carries its own fault history)."""
         with self._lock:
             items = list(self._metrics.items())
         return {name: m.value for name, m in sorted(items)

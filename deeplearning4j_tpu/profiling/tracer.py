@@ -14,7 +14,7 @@ batches" vs "backend init" is the whole diagnosis (VERDICT r5: three
 rounds dead with zero diagnostics).
 
 A process-global default tracer (``get_tracer()``) is what the
-containers, the parallel trainers, and ``bench.py`` emit into; the
+containers and the parallel trainers emit into; the
 buffer is bounded (oldest events drop, counted) so a week-long training
 run cannot leak memory into the tracer. Timing is host wall time
 (``perf_counter_ns``, whole nanoseconds): a span around an unsynced jit

@@ -163,7 +163,7 @@ class StallWatchdog:
     """Daemon monitor: stale heartbeat past its deadline -> bundle on
     disk. One bundle per stall episode (re-arms when the heartbeat
     recovers); ``dump()`` can also be called directly for externally
-    detected failures (bench's dead backend probe)."""
+    detected failures."""
 
     def __init__(self, bundle_dir: str, interval_s: float = 1.0,
                  exit_dump: bool = False, name: str = "stall-watchdog"):

@@ -1,7 +1,7 @@
 """Where JAX keeps its persistent compilation cache.
 
 One rule for every entry point that compiles at full size
-(``chip_smoke.py``, ``bench.py``, ``tools/profile_resnet.py``): the cache
+(``chip_smoke.py``, ``benchmark/run.py``): the cache
 lives where ``JAX_COMPILATION_CACHE_DIR`` says — JAX reads that variable
 itself, so nothing is set in code — and otherwise at ``.jax_cache`` in the
 checkout. The path is part of the cache key's neighbourhood (a directory

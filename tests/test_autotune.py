@@ -339,13 +339,3 @@ def test_train_step_cost_memoized_on_batch_signature():
     net._train_step_fn = net._build_train_step()
     cost.train_step_cost(net, ds)
     assert len(cost._STEP_COST[net][1]) == 1
-
-
-def test_weight_update_cost_uses_census():
-    from deeplearning4j_tpu.profiling import cost
-    net = small_net()
-    wuc = cost.weight_update_cost(net, dp=2, weight_update_sharding="zero1")
-    n_params = int(sum(np.prod(np.shape(p)) for p in
-                       __import__("jax").tree_util.tree_leaves(net.params)))
-    assert wuc["comm_bytes_per_step"] == cost.dp_comm_bytes_per_update(
-        n_params, 2, 4, 1, "zero1")

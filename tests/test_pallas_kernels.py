@@ -148,7 +148,8 @@ def test_fused_lstm_finite_difference():
 def test_padding_exact_nonaligned_shape(monkeypatch):
     """Pad-to-tile (VERDICT r3 #3): a shape far from the (8, 128) grid
     must produce bit-meaningful parity with scan, fwd AND grads — the
-    same (H=200, B=6) check bench.py runs compiled on hardware."""
+    same (H=200, B=6) check ``chip_smoke.py`` P3 runs compiled on the
+    chip."""
     Bn, Tn, Fn, Hn = 6, 5, 72, 200
     layer = GravesLSTM(n_out=Hn)  # peephole: exercises [3, H] pad too
     layer.n_in = Fn

@@ -1,7 +1,7 @@
 """Profiling subsystem: span tracer (Chrome trace-event schema), metrics
 registry (JSON + Prometheus text), compile watcher, memory watermark,
 compiled-step cost analysis (analytic MFU vs a hand-computed LeNet FLOP
-count), the bench failure-record/watchdog path, and the black-box
+count), and the black-box
 diagnostics leg — flight recorder ring, stall watchdog bundles (the
 ISSUE-17 acceptance gates: a wedged trainer step and a hung backend
 probe must both leave a bundle naming the stalled phase), and the
@@ -584,46 +584,6 @@ def test_training_stats_folds_cost_analysis():
     assert "analytic_mfu" not in s2.export()
 
 
-# ------------------------------------------------- bench failure records
-
-def test_bench_failure_record_names_open_span():
-    import bench
-
-    tr = Tracer()
-    h = tr.begin("rung:full")
-    tr.begin("warmup")
-    rec = bench._failure_record("m", "detail", tr.open_span_stack(),
-                                kind="timeout")
-    assert rec["failed"] is True and rec["value"] == 0.0
-    assert rec["error"]["open_spans"] == ["rung:full", "warmup"]
-    assert json.loads(json.dumps(rec)) == rec  # JSON-clean
-    del h
-
-
-def test_bench_rung_watchdog_simulated_timeout():
-    """The acceptance path: a rung exceeding its wall emits a failure
-    record naming the open span stack — without killing anything."""
-    import bench
-
-    tr = Tracer()
-    emitted = []
-    h = tr.begin("rung:lenet")
-    tr.begin("stage_batches")
-    with bench._RungWatchdog("lenet_metric", 0.05, tr,
-                             emit=emitted.append):
-        time.sleep(0.3)  # the "hung" rung
-    assert len(emitted) == 1
-    rec = json.loads(emitted[0])
-    assert rec["failed"] and rec["error"]["kind"] == "timeout"
-    assert rec["error"]["open_spans"] == ["rung:lenet", "stage_batches"]
-    # a fast rung never fires
-    emitted.clear()
-    with bench._RungWatchdog("m", 5.0, tr, emit=emitted.append):
-        pass
-    assert emitted == []
-    del h
-
-
 def test_ui_server_serves_metrics_endpoints():
     import urllib.request
 
@@ -920,40 +880,6 @@ def test_wedged_trainer_step_bundle_names_straggle(tmp_path, fresh_diag):
         faultinject.clear()
         wd.close()
         trainer.close()
-
-
-def test_bench_ladder_refuses_cpu_and_fails_on_a_raising_rung(
-        tmp_path, fresh_diag, monkeypatch, capsys):
-    """bench.py hides nothing: on a CPU backend it refuses to measure
-    unless BENCH_SMOKE=1 asks for the smoke, and a rung that raises
-    leaves a failure record naming the rung's span AND a non-zero exit."""
-    import bench
-
-    def records():
-        return [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
-                if ln.startswith("{")]
-
-    # the env var alone places the cache: nothing is configured in-process
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
-    monkeypatch.setenv("BENCH_BUNDLE_DIR", str(tmp_path))
-    monkeypatch.setenv("BENCH_RUNGS", "lenet")
-    monkeypatch.delenv("BENCH_SMOKE", raising=False)
-    monkeypatch.delenv("BENCH_SMALL", raising=False)
-    assert bench.main() == 1
-    assert records() == [], "a CPU run without BENCH_SMOKE=1 printed a record"
-
-    def boom(*_a, **_k):
-        raise RuntimeError("forced rung failure")
-
-    monkeypatch.setenv("BENCH_SMOKE", "1")
-    monkeypatch.setattr(bench, "_run_rung", boom)
-    assert bench.main() == 1
-    (rec,) = records()
-    assert rec["failed"] is True
-    assert rec["metric"].endswith("_SMOKE")
-    assert rec["error"]["kind"] == "exception"
-    assert "forced rung failure" in rec["error"]["detail"]
-    assert "rung:lenet" in rec["error"]["open_spans"]
 
 
 # ----------------------------------------------------- postmortem reader

@@ -304,8 +304,7 @@ def test_zero1_memory_report_divides_updater_state():
 
 
 def test_zero1_comm_bytes_model():
-    from deeplearning4j_tpu.profiling.cost import (dp_comm_bytes_per_update,
-                                                   weight_update_cost)
+    from deeplearning4j_tpu.profiling.cost import dp_comm_bytes_per_update
     P, dp = 1_000_000, 8
     # accumulation k=4: 2k units replicated vs k+1 units zero1
     rep = dp_comm_bytes_per_update(P, dp, 4, gradient_accumulation=4)
@@ -316,12 +315,6 @@ def test_zero1_comm_bytes_model():
     assert (dp_comm_bytes_per_update(P, dp, 4, 1, "zero1")
             == dp_comm_bytes_per_update(P, dp, 4, 1, "off"))
     assert dp_comm_bytes_per_update(P, 1, 4, 4, "zero1") == 0
-    net = _net()
-    wuc = weight_update_cost(net, dp=8, gradient_accumulation=4,
-                             weight_update_sharding="zero1")
-    assert wuc["comm_bytes_per_step"] > 0
-    assert wuc["updater_hbm_bytes"] < weight_update_cost(
-        net, dp=8, gradient_accumulation=4)["updater_hbm_bytes"]
 
 
 def test_zero1_earlystopping_passthrough():

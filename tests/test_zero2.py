@@ -426,8 +426,7 @@ def test_gc015_precision_rule():
 
 def test_zero2_cost_model():
     from deeplearning4j_tpu.profiling.cost import (dp_comm_bytes_per_update,
-                                                   dp_gradient_hbm_bytes,
-                                                   weight_update_cost)
+                                                   dp_gradient_hbm_bytes)
     P, dp = 1_000_000, 8
     # zero2 comm == zero1 comm <= replicated at every accumulation depth
     for k in (1, 4):
@@ -440,16 +439,6 @@ def test_zero2_cost_model():
     assert dp_gradient_hbm_bytes(P, dp, 4, "zero1") == 4 * P
     assert dp_gradient_hbm_bytes(P, dp, 4, "zero2") == -(-4 * P // dp)
     assert dp_gradient_hbm_bytes(P, 1, 4, "zero2") == 4 * P  # dp=1 degrades
-
-    net = _net()
-    wuc = weight_update_cost(net, dp=8, gradient_accumulation=4,
-                             weight_update_sharding="zero2")
-    wuc1 = weight_update_cost(net, dp=8, gradient_accumulation=4,
-                              weight_update_sharding="zero1")
-    assert wuc["comm_bytes_per_step"] <= wuc1["comm_bytes_per_step"]
-    assert wuc["gradient_hbm_bytes"] * 8 >= wuc1["gradient_hbm_bytes"]
-    assert wuc["gradient_hbm_bytes"] < wuc1["gradient_hbm_bytes"]
-    assert wuc["updater_hbm_bytes"] == wuc1["updater_hbm_bytes"]
 
 
 def test_zero2_memory_report_divides_gradients():

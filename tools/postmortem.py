@@ -15,8 +15,7 @@ evidence newest-first.
 assemble/atomic-write/read/summarize path and exits nonzero if any leg
 breaks; tools/analyze.py routes it as the ``postmortem`` layer.
 
-Stdlib-only, no jax import: must run in the bench supervisor's
-environment and in CI's static stages.
+Stdlib-only, no jax import: must run in CI's static stages.
 """
 
 from __future__ import annotations
