@@ -12,7 +12,9 @@ weights from a seed), and checks what comes out by the repo's own means:
                 KerasClient.generate against singleton ``greedy_generate``
   P3 kernels    char-LSTM and GPT trainers with the compiled Pallas kernels
                 (Mosaic custom call present in the lowered step) and kernel
-                vs XLA-reference parity at aligned and unaligned shapes
+                vs XLA-reference parity at aligned and unaligned shapes;
+                the delta rule's chunk-local kernels against its XLA path
+                at the hybrid cell's shape, values and gradients
   P4 multichip  ResNet-50 over ``ParallelTrainer`` on four chips, when the
                 machine has them
 
@@ -408,6 +410,63 @@ def attention_parity(B, H, T, D, dtype="float32") -> dict:
         (q, k, v), cot, tol=5e-4 if dtype == "float32" else 6e-2)
 
 
+def delta_rule_parity(B, T, H, dk, dv, dtype="bfloat16") -> dict:
+    """The gated delta rule with its chunk-local work in the Pallas kernels
+    (forward and backward) against the same function on the XLA path, on
+    the same inputs: the output and the gradients of all five inputs, the
+    largest error over the largest entry. Both paths make the same
+    products in ``dtype``, so what parts them is where each rounds:
+    bfloat16's 2^-8 on a few entries, where a real bug (a mask, a decay's
+    index, a missing term of a cotangent) is O(0.1-1) on many."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.nn.layers.linear_attention import (
+        gated_delta_rule_chunked)
+    from deeplearning4j_tpu.profiling.metrics import get_registry
+
+    rng = np.random.default_rng(13)
+    unit = lambda a: a / np.linalg.norm(a, axis=-1, keepdims=True)
+    f32 = lambda a: jnp.asarray(a, jnp.float32)
+    args = (f32(unit(rng.normal(size=(B, T, H, dk))) / np.sqrt(dk)),
+            f32(unit(rng.normal(size=(B, T, H, dk)))),
+            jnp.asarray(rng.normal(size=(B, T, H, dv)), dtype),
+            f32(np.log(rng.uniform(0.9, 0.9999, (B, T, H)))),
+            f32(rng.uniform(0.2, 2.0, (B, T, H))))
+    cot = f32(rng.normal(size=(B, T, H, dv)))
+
+    def run(pallas):
+        before = os.environ.get("DL4J_TPU_PALLAS")
+        os.environ["DL4J_TPU_PALLAS"] = pallas      # read once a trace
+        try:
+            fn = lambda *a: gated_delta_rule_chunked(*a, compute_dtype=dtype)
+            loss = lambda *a: jnp.sum(fn(*a) * cot)
+            return (jax.jit(fn)(*args), *jax.jit(
+                jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(*args))
+        finally:
+            os.environ.pop("DL4J_TPU_PALLAS")
+            if before is not None:
+                os.environ["DL4J_TPU_PALLAS"] = before
+
+    traces = get_registry().labeled_counter("pallas_gdn_chunk_traces_total")
+    ref = run("off")
+    kernel_traces = traces.labels(path="kernel").value
+    got = run("interpret" if DRY else "auto")
+    kernel_traces = traces.labels(path="kernel").value - kernel_traces
+    name = f"gated_delta_rule B={B} T={T} H={H} dk={dk} dv={dv} {dtype}"
+    check(kernel_traces == 2, f"{name}: {kernel_traces:.0f} traces took the "
+          "kernel path, of the output's and the gradient's two")
+    rec = {}
+    for what, a, b in zip("o dq dk dv dlog_alpha dbeta".split(), got, ref):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        err = float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+        rec[what] = float(f"{err:.2e}")
+    tol = 2e-2 if dtype == "bfloat16" else 1e-3
+    check(max(rec.values()) < tol, f"{name}: kernel against XLA path {rec} "
+          f"(tol {tol:.0e} of the largest entry)")
+    return rec
+
+
 def _lowered_step_text(net, batch) -> str:
     from deeplearning4j_tpu.profiling.cost import step_example_args
     return net._train_step_fn.lower(
@@ -432,6 +491,8 @@ def _kernel_trainer(name, net, batches) -> dict:
 
 
 def p3_kernels() -> dict:
+    import jax
+
     from deeplearning4j_tpu import InputType, NeuralNetConfiguration
     from deeplearning4j_tpu.models.gpt import gpt_decoder
     from deeplearning4j_tpu.nn.graph import ComputationGraph
@@ -449,6 +510,20 @@ def p3_kernels() -> dict:
     rec = {"parity_max_abs_err": parity}
     say("P3 kernels: parity vs HIGHEST-precision XLA reference, outputs "
         f"and gradients, max abs err {parity}")
+    # the hybrid cell's shape (a padded length at the tiny size); a
+    # float32 one whose length is no multiple of the chunk and, on the
+    # chip, runs in blocks of 4 chunks (33 as 36); one in blocks of 8
+    shapes = ((1, 200, 2, 8, 16), (2, 100, 2, 8, 16), (1, 320, 2, 8, 16)) \
+        if DRY else ((1, 8192, 30, 96, 192), (2, 2100, 4, 96, 192),
+                     (1, 4200, 4, 96, 192))
+    with jax.default_matmul_precision("highest"):
+        wide = delta_rule_parity(*shapes[1], dtype="float32")
+    rec["delta_rule_rel_err"] = {
+        f"{shapes[0]} bfloat16": delta_rule_parity(*shapes[0]),
+        f"{shapes[1]} float32": wide,
+        f"{shapes[2]} bfloat16": delta_rule_parity(*shapes[2])}
+    say("P3 kernels: gated delta rule, kernels against the XLA path, error "
+        f"over the largest entry {rec['delta_rule_rel_err']}")
 
     hidden, T, K, B = (32, 8, 16, 4) if DRY else (256, 64, 96, 32)
     lstm = MultiLayerNetwork(

@@ -37,6 +37,7 @@ layer's compute dtype (the input's: bfloat16 under ``PrecisionPolicy
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import List
 
@@ -49,7 +50,9 @@ from deeplearning4j_tpu.nn.layers.base import (
     Array, BaseLayerConf, Params, register_layer,
 )
 from deeplearning4j_tpu.nn.layers.normalization import rms_normalize
-from deeplearning4j_tpu.nn.remat import checkpoint_after_cotangent
+from deeplearning4j_tpu.nn.remat import (
+    backward_after_cotangent, checkpoint_after_cotangent,
+)
 
 #: tokens a chunk: the side of the triangular system, and the MXU's tile
 CHUNK = 64
@@ -102,22 +105,77 @@ def unit_lower_inverse(a: Array) -> Array:
     return blocks[0]
 
 
+def chunk_local_xla(q: Array, k: Array, v: Array, g: Array, beta: Array, *,
+                    compute_dtype) -> tuple:
+    """The 64 x 64 work of every chunk as XLA operations: ``q, k [B, H, N,
+    C, d_k]``, ``v [B, H, N, C, d_v]``, ``g`` (``log gamma_t``, the running
+    sum of ``log alpha`` inside the chunk) and ``beta [B, H, N, C]``.
+    Returns ``w, u0, attn, q_in, k_out`` in the order the scan reads them,
+    ``[N, B, H, C, .]``. The path of float64 and of every shape the kernels'
+    gate refuses, and the reference of the kernels' tests."""
+    C = q.shape[-2]
+    acc = g.dtype
+    cd = jnp.dtype(compute_dtype)
+
+    def mm(spec, x, y):
+        return jnp.einsum(spec, x.astype(cd), y.astype(cd),
+                          preferred_element_type=acc)
+
+    t = jnp.arange(C)
+    diff = g[..., :, None] - g[..., None, :]                # [.., t, i]
+    decay = jnp.exp(jnp.where(t[:, None] >= t[None, :], diff, -jnp.inf))
+    kk = mm("bhnck,bhndk->bhncd", k, k)
+    a = jnp.where(t[:, None] > t[None, :], beta[..., None] * kk * decay, 0.0)
+    inv = unit_lower_inverse(a)
+    gamma = jnp.exp(g)
+    w = mm("bhnct,bhntk->bhnck", inv,
+           k.astype(acc) * (beta * gamma)[..., None])
+    u0 = mm("bhnct,bhntv->bhncv", inv, v.astype(acc) * beta[..., None])
+    attn = mm("bhnck,bhndk->bhncd", q, k) * decay
+    q_in = q.astype(acc) * gamma[..., None]
+    to_end = jnp.exp(g[..., -1:] - g)                       # gamma_C / gamma
+    k_out = k.astype(acc) * to_end[..., None]
+    # what the scan keeps for its backward is what it is handed: the
+    # products' operands in the compute dtype, u0 wide
+    narrow = lambda x: x.astype(cd)
+    return tuple(jnp.moveaxis(x, 2, 0) for x in (
+        narrow(w), u0, narrow(attn), narrow(q_in), narrow(k_out)))
+
+
 def gated_delta_rule_chunked(q: Array, k: Array, v: Array, log_alpha: Array,
                              beta: Array, *, chunk_size: int = CHUNK,
-                             compute_dtype=None) -> Array:
+                             compute_dtype=None, layer=None) -> Array:
     """The recurrence of the module's docstring from ``S_0 = 0``.
 
     ``q, k [B, T, H, d_k]``, ``v [B, T, H, d_v]``, ``log_alpha, beta
     [B, T, H]`` (float32 or wider). Returns ``o [B, T, H, d_v]`` in the
     gates' dtype. ``T`` need not be a multiple of ``chunk_size``: the tail
     is padded with tokens that write nothing (``beta = 0``) and decay
-    nothing (``log_alpha = 0``)."""
+    nothing (``log_alpha = 0``).
+
+    The chunk-local work runs in the Pallas kernels of
+    ``ops/pallas_delta_rule.py`` where their gate allows (float32 gates, a
+    chunk of 64, a block that fits VMEM; ``DL4J_TPU_PALLAS`` not "off") and
+    as XLA operations under a checkpoint of their own otherwise; a refusal
+    counts under ``layer``'s name, where the caller is a layer."""
+    from deeplearning4j_tpu.ops import pallas_delta_rule as pdr
+    from deeplearning4j_tpu.ops.pallas_attention import attention_mode
+    from deeplearning4j_tpu.ops.pallas_kernels import count_gate_fallback
+
     B, T, H, dk = q.shape
     dv = v.shape[-1]
     C = chunk_size
     N = -(-T // C)
     acc = log_alpha.dtype
     cd = acc if compute_dtype is None else jnp.dtype(compute_dtype)
+
+    mode = attention_mode()
+    kernel = mode != "off" and pdr.gdn_chunk_ok(N, dk, dv, C, acc, cd)
+    if mode != "off" and not kernel and layer is not None:
+        count_gate_fallback(layer, "gdn_chunk_local")
+    pdr.count_trace("kernel" if kernel else "xla")
+    if kernel:
+        N = pdr.padded_chunks(N)        # whole blocks of chunks
 
     def chunks(x):      # [B, T, H, ...] -> [B, H, N, C, ...]
         x = jnp.pad(x, ((0, 0), (0, N * C - T)) + ((0, 0),) * (x.ndim - 2))
@@ -128,32 +186,17 @@ def gated_delta_rule_chunked(q: Array, k: Array, v: Array, log_alpha: Array,
         return jnp.einsum(spec, x.astype(cd), y.astype(cd),
                           preferred_element_type=acc)
 
-    @checkpoint_after_cotangent     # the 64 x 64 work, rebuilt backward
-    def chunk_local(q, k, v, log_alpha, beta):
-        g = jnp.cumsum(log_alpha, axis=-1)                  # log gamma_t
-        t = jnp.arange(C)
-        diff = g[..., :, None] - g[..., None, :]            # [.., t, i]
-        decay = jnp.exp(jnp.where(t[:, None] >= t[None, :], diff, -jnp.inf))
-        kk = mm("bhnck,bhndk->bhncd", k, k)
-        a = jnp.where(t[:, None] > t[None, :],
-                      beta[..., None] * kk * decay, 0.0)
-        inv = unit_lower_inverse(a)
-        gamma = jnp.exp(g)
-        w = mm("bhnct,bhntk->bhnck", inv,
-               k.astype(acc) * (beta * gamma)[..., None])
-        u0 = mm("bhnct,bhntv->bhncv", inv, v.astype(acc) * beta[..., None])
-        attn = mm("bhnck,bhndk->bhncd", q, k) * decay
-        q_in = q.astype(acc) * gamma[..., None]
-        to_end = jnp.exp(g[..., -1:] - g)                   # gamma_C / gamma
-        k_out = k.astype(acc) * to_end[..., None]
-        # what the scan keeps for its backward is what it is handed: the
-        # products' operands in the compute dtype, u0 and the decay wide
-        narrow = lambda x: x.astype(cd)
-        return (narrow(w), u0, narrow(attn), narrow(q_in), narrow(k_out),
-                gamma[..., -1])
-
     with jax.named_scope("gdn:chunk_local"):
-        local = chunk_local(*map(chunks, (q, k, v, log_alpha, beta)))
+        q, k, v, log_alpha, beta = map(chunks, (q, k, v, log_alpha, beta))
+        g = jnp.cumsum(log_alpha, axis=-1)                  # log gamma_t
+        if kernel:      # the kernels' own rule: inputs kept, rebuilt in VMEM
+            local = backward_after_cotangent(functools.partial(
+                pdr.gdn_chunk_local, compute_dtype=cd,
+                interpret=mode == "interpret"))(q, k, v, g, beta)
+        else:           # the 64 x 64 work, rebuilt backward
+            local = checkpoint_after_cotangent(functools.partial(
+                chunk_local_xla, compute_dtype=cd))(q, k, v, g, beta)
+        end = jnp.moveaxis(jnp.exp(g[..., -1]), 2, 0)       # gamma_C [N, B, H]
 
     def step(s, xs):    # s [B, H, d_v, d_k]
         w_n, u0_n, attn_n, q_n, k_n, end_n = xs
@@ -164,8 +207,7 @@ def gated_delta_rule_chunked(q: Array, k: Array, v: Array, log_alpha: Array,
 
     with jax.named_scope("gdn:chunk_scan"):
         s0 = jnp.zeros((B, H, dv, dk), acc)
-        _, o = lax.scan(step, s0, tuple(
-            jnp.moveaxis(x, 2, 0) for x in local))
+        _, o = lax.scan(step, s0, tuple(local) + (end,))
     o = jnp.moveaxis(o, 0, 1)                               # [B, N, H, C, d_v]
     return jnp.moveaxis(o, 2, 3).reshape(B, N * C, H, dv)[:, :T]
 
@@ -278,7 +320,7 @@ class GatedDeltaNetLayer(BaseLayerConf):
             beta = beta * mask[..., None]
             log_alpha = log_alpha * mask[..., None]
         o = gated_delta_rule_chunked(q, k, v, log_alpha, beta,
-                                     compute_dtype=x.dtype)
+                                     compute_dtype=x.dtype, layer=self)
         with jax.named_scope("gdn:gate_norm"):
             gate = jax.nn.silu(heads(x @ params["Wg"], dv).astype(acc))
             o = rms_normalize(o, self.norm_eps) * params["gamma"].astype(acc)

@@ -54,3 +54,24 @@ def checkpoint_after_cotangent(fn):
 
     kept.defvjp(forward, backward)
     return kept
+
+
+def backward_after_cotangent(fn):
+    """``fn`` with its own backward rule held between the same two
+    barriers: for a function that already keeps its inputs alone and
+    rebuilds the rest backward (a kernel under its own ``custom_vjp``).
+    Reverse mode only."""
+
+    @jax.custom_vjp
+    def held(*args):
+        return fn(*args)
+
+    def forward(*args):
+        return jax.vjp(fn, *args)       # the rule, with what fn keeps
+
+    def backward(vjp, ct):
+        vjp, ct = _together((vjp, ct))
+        return _together(vjp(ct))
+
+    held.defvjp(forward, backward)
+    return held

@@ -17,6 +17,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from deeplearning4j_tpu.ops.pallas_attention import flash_attention
+from deeplearning4j_tpu.ops.pallas_delta_rule import (
+    gdn_chunk_local, padded_chunks)
 
 
 @pytest.fixture(scope="module")
@@ -65,3 +67,38 @@ def test_flash_kernels_compile_for_a_v5e(one_chip, no_compile_cache, shape,
         for fn, kernels in ((fwd, 1), (bwd, 3)):
             text = jax.jit(fn).lower(x, x, x).compile().as_text()
             assert text.count("tpu_custom_call") == kernels
+
+
+@pytest.mark.parametrize("precision", ["default", "highest"])
+@pytest.mark.parametrize(
+    "shape,dtype",
+    [((1, 30, 8192, 96, 192), jnp.bfloat16),
+     ((1, 30, 8192, 96, 192), jnp.float32),
+     ((1, 30, 8320, 96, 192), jnp.bfloat16), ((2, 2, 100, 8, 16), jnp.float32),
+     ((1, 30, 2100, 96, 192), jnp.bfloat16),
+     ((1, 30, 4200, 96, 192), jnp.float32)],
+    ids=["hybrid_cell-bfloat16", "hybrid_cell-float32", "padded-bfloat16",
+         "tiny-float32", "blocks_of_4-bfloat16", "blocks_of_8-float32"])
+def test_delta_rule_kernels_compile_for_a_v5e(one_chip, no_compile_cache,
+                                              shape, dtype, precision):
+    """The chunk-local forward kernel and the backward one at the hybrid
+    cell's shape with bfloat16 and with float32 operands, at a length that
+    is padded to whole blocks of chunks (130 chunks run as 144), at the
+    tests' own widths, and at lengths that run in blocks of 4 and of 8
+    chunks (33 as 36, 66 as 72); under the ambient precision "highest" too (the
+    inverse's float32 products name HIGHEST themselves, the bfloat16 ones
+    the default)."""
+    B, H, T, dk, dv = shape
+    N = padded_chunks(-(-T // 64))
+    arg = lambda last, dt: jax.ShapeDtypeStruct(
+        (B, H, N, 64) + last, dt, sharding=one_chip)
+    args = (arg((dk,), jnp.float32), arg((dk,), jnp.float32),
+            arg((dv,), dtype), arg((), jnp.float32), arg((), jnp.float32))
+    fwd = functools.partial(gdn_chunk_local, compute_dtype=dtype)
+    bwd = jax.grad(lambda *a: sum(x.astype(jnp.float32).sum()
+                                  for x in fwd(*a)), argnums=(0, 1, 2, 3, 4))
+    with jax.default_matmul_precision(precision):
+        for fn, name in ((fwd, "gdn_chunk_local_fwd"),
+                         (bwd, "gdn_chunk_local_bwd")):
+            text = jax.jit(fn).lower(*args).compile().as_text()
+            assert text.count("tpu_custom_call") == 1 and name in text
