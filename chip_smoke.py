@@ -14,7 +14,11 @@ weights from a seed), and checks what comes out by the repo's own means:
                 (Mosaic custom call present in the lowered step) and kernel
                 vs XLA-reference parity at aligned and unaligned shapes;
                 the delta rule's chunk-local kernels against its XLA path
-                at the hybrid cell's shape, values and gradients
+                at the hybrid cell's shape, values and gradients; the flash
+                kernels with a window against the written-out mask and the
+                chunked selective scan against the token-by-token one, at
+                the SambaY cell's shapes in bfloat16 and at lengths that
+                are no multiple of a block in float32
   P4 multichip  ResNet-50 over ``ParallelTrainer`` on four chips, when the
                 machine has them
 
@@ -410,6 +414,17 @@ def attention_parity(B, H, T, D, dtype="float32") -> dict:
         (q, k, v), cot, tol=5e-4 if dtype == "float32" else 6e-2)
 
 
+def _rel_errors(names: str, got, ref) -> dict:
+    """The largest error over the largest entry, for each of ``names``."""
+    import jax.numpy as jnp
+    rec = {}
+    for what, a, b in zip(names.split(), got, ref):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        err = float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+        rec[what] = float(f"{err:.2e}")
+    return rec
+
+
 def delta_rule_parity(B, T, H, dk, dv, dtype="bfloat16") -> dict:
     """The gated delta rule with its chunk-local work in the Pallas kernels
     (forward and backward) against the same function on the XLA path, on
@@ -456,14 +471,94 @@ def delta_rule_parity(B, T, H, dk, dv, dtype="bfloat16") -> dict:
     name = f"gated_delta_rule B={B} T={T} H={H} dk={dk} dv={dv} {dtype}"
     check(kernel_traces == 2, f"{name}: {kernel_traces:.0f} traces took the "
           "kernel path, of the output's and the gradient's two")
-    rec = {}
-    for what, a, b in zip("o dq dk dv dlog_alpha dbeta".split(), got, ref):
-        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
-        err = float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
-        rec[what] = float(f"{err:.2e}")
+    rec = _rel_errors("o dq dk dv dlog_alpha dbeta", got, ref)
     tol = 2e-2 if dtype == "bfloat16" else 1e-3
     check(max(rec.values()) < tol, f"{name}: kernel against XLA path {rec} "
           f"(tol {tol:.0e} of the largest entry)")
+    return rec
+
+
+def window_attention_parity(B, H, T, D, Dv, window, dtype) -> dict:
+    """The flash kernels with a window and a value wider than its key (as
+    a differential attention layer calls them) against
+    ``attention_reference`` in float32 with the mask written out: the
+    output and ``dq, dk, dv``, the largest error over the largest entry."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.nn.layers.attention import attention_reference
+    from deeplearning4j_tpu.ops.pallas_attention import flash_attention
+
+    rng = np.random.default_rng(17)
+    draw = lambda d: jnp.asarray(rng.normal(size=(B, H, T, d)), jnp.float32)
+    q, k, v, cot = draw(D), draw(D), draw(Dv), draw(Dv)
+    kernel = lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window, interpret=DRY)
+    plain = lambda q, k, v: attention_reference(
+        q, k, v, causal=True, window=window)
+
+    def run(fn, args):
+        loss = lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * cot)
+        return (jax.jit(fn)(*args),
+                *jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(*args))
+
+    with jax.default_matmul_precision("highest"):
+        ref = run(plain, (q, k, v))
+        got = run(kernel, tuple(x.astype(dtype) for x in (q, k, v)))
+    rec = _rel_errors("o dq dk dv", got, ref)
+    tol = 3e-2 if dtype == "bfloat16" else 1e-3
+    name = f"flash_attention window={window} T={T} D={D}/{Dv} {dtype}"
+    check(max(rec.values()) < tol, f"{name}: kernel against the written-out "
+          f"mask {rec} (tol {tol:.0e} of the largest entry)")
+    return rec
+
+
+def selective_scan_parity(B, T, D, N, dtype) -> dict:
+    """The chunked selective scan against the token-by-token one on the
+    same inputs (``x`` in ``dtype``, the rest float32): the output and the
+    gradients of all five inputs, the largest error over the largest
+    entry. Steps near 0 and decays down to ``exp(-30)`` a token. In float32
+    the token-by-token side runs in float64 on the host, so that the gap
+    read is the chunked form's own and not the rounding of a thousand
+    chained float32 steps on either side (PERF.md section 6, PR 33)."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.nn.layers.state_space import (
+        selective_scan_chunked, selective_scan_recurrent)
+
+    rng = np.random.default_rng(19)
+    f32 = lambda a: jnp.asarray(a, jnp.float32)
+    args = (jnp.asarray(rng.normal(size=(B, T, D)), dtype),
+            f32(np.exp(rng.uniform(np.log(1e-3), np.log(2.0), (B, T, D)))),
+            f32(-np.exp(rng.uniform(np.log(1e-2), np.log(16.0), (N, D)))),
+            f32(rng.normal(size=(B, T, N))), f32(rng.normal(size=(B, T, N))))
+    cot = f32(rng.normal(size=(B, T, D)))
+
+    def run(fn, args, cot):
+        loss = lambda *a: jnp.sum(fn(*a) * cot)
+        return (jax.jit(fn)(*args), *jax.jit(
+            jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(*args))
+
+    got = run(selective_scan_chunked, args, cot)
+    if dtype == "float32":
+        host = jax.devices("cpu")[0]
+        with jax.enable_x64(True):
+            wide = [jax.device_put(np.asarray(a, np.float64), host)
+                    for a in args + (cot,)]
+            ref = [np.asarray(r) for r in run(
+                selective_scan_recurrent, wide[:-1], wide[-1])]
+    else:
+        ref = run(selective_scan_recurrent, args, cot)
+    rec = _rel_errors("y dx ddelta da db dc", got, ref)
+    # float32: 1.2e-5 to 3.2e-5 read on the v5e, whose float32 exp is
+    # biased by -1e-6 and the chunked form chains sixteen steps, sixteen
+    # runs and T / 256 blocks of it (2e-7 on the CPU); the token-by-token
+    # form on the chip chains T and reads 6e-4 against the same float64
+    tol = 2e-2 if dtype == "bfloat16" else 1e-4
+    name = f"selective_scan B={B} T={T} d_in={D} N={N} {dtype}"
+    check(max(rec.values()) < tol, f"{name}: chunked against token by token "
+          f"{rec} (tol {tol:.0e} of the largest entry)")
     return rec
 
 
@@ -524,6 +619,29 @@ def p3_kernels() -> dict:
         f"{shapes[2]} bfloat16": delta_rule_parity(*shapes[2])}
     say("P3 kernels: gated delta rule, kernels against the XLA path, error "
         f"over the largest entry {rec['delta_rule_rel_err']}")
+
+    # the SambaY cell's shapes (four of its forty kernel heads: the plain
+    # reference keeps every score), then lengths that are no multiple of a
+    # block, in float32
+    shapes = ((1, 2, 300, 16, 32, 24), (2, 2, 100, 16, 32, 40)) if DRY else (
+        (1, 4, 8192, 64, 128, 512), (2, 2, 1100, 64, 128, 512))
+    rec["window_attention_rel_err"] = {
+        f"{shapes[0]} bfloat16": window_attention_parity(
+            *shapes[0], dtype="bfloat16"),
+        f"{shapes[1]} float32": window_attention_parity(
+            *shapes[1], dtype="float32")}
+    say("P3 kernels: flash kernels with a window against the written-out "
+        "mask, error over the largest entry "
+        f"{rec['window_attention_rel_err']}")
+    shapes = ((1, 300, 128, 4), (2, 100, 128, 4)) if DRY else (
+        (1, 8192, 5120, 16), (2, 1100, 1024, 16))
+    rec["selective_scan_rel_err"] = {
+        f"{shapes[0]} bfloat16": selective_scan_parity(
+            *shapes[0], dtype="bfloat16"),
+        f"{shapes[1]} float32": selective_scan_parity(
+            *shapes[1], dtype="float32")}
+    say("P3 kernels: chunked selective scan against token by token, error "
+        f"over the largest entry {rec['selective_scan_rel_err']}")
 
     hidden, T, K, B = (32, 8, 16, 4) if DRY else (256, 64, 96, 32)
     lstm = MultiLayerNetwork(

@@ -1014,11 +1014,11 @@ def _walk_graph_shapes(conf, order: List[str],
             continue  # upstream unresolved (missing input_types or errors)
         in_ts = [types[i] for i in node.inputs]
         if node.kind == "layer":
-            if len(node.inputs) != 1:
+            if len(node.inputs) != node.layer.N_INPUTS:
                 findings.append(Finding(
                     "GC012", Severity.ERROR, name,
-                    f"layer node takes exactly 1 input, got "
-                    f"{len(node.inputs)}",
+                    f"layer node takes exactly {node.layer.N_INPUTS} "
+                    f"input(s), got {len(node.inputs)}",
                     "merge multiple inputs with a MergeVertex first"))
                 continue
             cur = in_ts[0]
@@ -1039,6 +1039,8 @@ def _walk_graph_shapes(conf, order: List[str],
                 import copy
                 probe = copy.deepcopy(node.layer)
                 probe.set_n_in(cur)
+                if len(in_ts) > 1:
+                    probe.set_side_inputs(in_ts[1:])
                 types[name] = probe.infer_output_type(cur)
             except Exception as e:
                 findings.append(Finding(

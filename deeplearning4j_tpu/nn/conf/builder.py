@@ -49,7 +49,9 @@ _RNN_LAYERS = {"LSTM", "GravesLSTM", "GravesBidirectionalLSTM", "SimpleRnn",
                "LastTimeStepLayer", "TimeDistributedLayer",
                "ZeroPadding1DLayer", "PositionalEmbeddingLayer",
                "TiedRnnOutputLayer", "GatedDeltaNetLayer",
-               "QKNormAttentionLayer"}
+               "QKNormAttentionLayer", "KeyValueProjectionLayer",
+               "DifferentialAttentionLayer", "SelectiveScanLayer",
+               "GatedMemoryUnitLayer"}
 _IDS_LAYERS = {"TokenEmbeddingLayer"}
 _ANY_LAYERS = {"BatchNormalization", "GlobalPoolingLayer", "ActivationLayer",
                "DropoutLayer", "LossLayer", "ReshapeLayer", "PermuteLayer",

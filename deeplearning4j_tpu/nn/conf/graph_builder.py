@@ -173,6 +173,13 @@ class ComputationGraphConfiguration:
                 continue
             in_ts = [types[i] for i in node.inputs]
             if node.kind == "layer":
+                # (a one-input layer wired to several is graphcheck's GC012)
+                if node.layer.N_INPUTS > 1 and (
+                        len(in_ts) != node.layer.N_INPUTS):
+                    raise ValueError(
+                        f"Layer node {name!r} ({type(node.layer).__name__}) "
+                        f"takes {node.layer.N_INPUTS} input(s), got "
+                        f"{len(in_ts)}")
                 cur = in_ts[0]
                 if node.preprocessor is None:
                     p = auto_preprocessor(cur, expected_input_kind(node.layer))
@@ -180,6 +187,8 @@ class ComputationGraphConfiguration:
                 if node.preprocessor is not None:
                     cur = node.preprocessor.infer_output_type(cur)
                 node.layer.set_n_in(cur)
+                if len(in_ts) > 1:
+                    node.layer.set_side_inputs(in_ts[1:])
                 types[name] = node.layer.infer_output_type(cur)
             else:
                 want = node.vertex.n_inputs()

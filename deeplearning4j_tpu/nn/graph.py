@@ -214,6 +214,13 @@ class ComputationGraph(LazyScoreMixin, EvalMixin, FitLoopMixin, ScanFitMixin,
                     h = node.preprocessor.transform(h, None)
                     cur_mask = node.preprocessor.transform_mask(cur_mask, None)
                 layer = node.layer
+                if layer.N_INPUTS > 1:
+                    # the stream and other nodes' outputs, as ordinary
+                    # edges: the gradient of an output that several nodes
+                    # read is the sum autodiff makes, and under remat the
+                    # reader keeps all its inputs and rebuilds none of
+                    # their producers
+                    h = (h, *in_acts[1:])
                 if rng is not None:
                     rng, sub = jax.random.split(rng)
                 else:

@@ -28,13 +28,18 @@ NEG_INF = -1e30
 
 def attention_reference(q: Array, k: Array, v: Array,
                         causal: bool = False,
-                        mask: Optional[Array] = None) -> Array:
-    """Plain softmax(QK^T/sqrt(d))V. q,k,v: [B, H, T, D]."""
+                        mask: Optional[Array] = None,
+                        window: Optional[int] = None) -> Array:
+    """Plain softmax(QK^T/sqrt(d))V. q,k: [B, H, T, D], v: [B, H, T, Dv].
+    ``window`` (self-attention, with ``causal``): key ``s`` is seen from
+    ``t`` when ``0 <= t - s < window``."""
     d = q.shape[-1]
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(d)
     if causal:
         tq, tk = logits.shape[-2], logits.shape[-1]
         cm = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
+        if window is not None:
+            cm = cm & ~jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq - window)
         logits = jnp.where(cm, logits, NEG_INF)
     if mask is not None:
         logits = jnp.where(mask[:, None, None, :] > 0, logits, NEG_INF)
@@ -239,31 +244,38 @@ class SelfAttentionLayer(BaseLayerConf):
 
     def _attend(self, q, k, v, mask):
         """Softmax attention of projected ``q, k, v [B, T, H*D]`` by head,
-        heads merged again: the Pallas flash kernel where its shape gate
-        allows, else the blockwise or the plain XLA path."""
-        q, k, v = map(self._split_heads, (q, k, v))
+        heads merged again."""
+        out = self._attend_heads(*map(self._split_heads, (q, k, v)), mask)
+        B, H, T, D = out.shape
+        return out.transpose(0, 2, 1, 3).reshape(B, T, H * D)
+
+    def _attend_heads(self, q, k, v, mask, window: Optional[int] = None):
+        """Softmax attention of ``q, k [B, H, T, D]`` and ``v [B, H, T,
+        Dv]``: the Pallas flash kernel where its shape gate allows, else the
+        blockwise or the plain XLA path (the plain one alone knows a window
+        and a value wider than its key)."""
         # helper seam (the cuDNN-discovery analog, like the fused LSTM):
         # MXU-native flash attention when the Pallas kernel applies
         from deeplearning4j_tpu.ops.pallas_attention import (
             attention_mode, flash_attention, flash_ok)
         from deeplearning4j_tpu.ops.pallas_kernels import count_gate_fallback
         amode = attention_mode()
+        plain = window is None and v.shape[-1] == q.shape[-1]
         use_flash = amode != "off" and flash_ok(
-            q.shape[2], self.head_dim, q.dtype.itemsize)
+            q.shape[2], max(q.shape[-1], v.shape[-1]), q.dtype.itemsize)
         if amode != "off" and not use_flash:
             count_gate_fallback(self, "flash_attention")
         if use_flash:
-            out = flash_attention(q, k, v, causal=self.causal,
-                                  kv_mask=mask,
-                                  interpret=amode == "interpret")
-        elif self.use_blockwise:
+            return flash_attention(q, k, v, causal=self.causal,
+                                   kv_mask=mask,
+                                   interpret=amode == "interpret",
+                                   window=window)
+        if self.use_blockwise and plain:
             out, _, lse = blockwise_attention(q, k, v, block_size=self.block_size,
                                               causal=self.causal, kv_mask=mask)
-            out = finalize_attention(out, lse)
-        else:
-            out = attention_reference(q, k, v, causal=self.causal, mask=mask)
-        B, H, T, D = out.shape
-        return out.transpose(0, 2, 1, 3).reshape(B, T, H * D)
+            return finalize_attention(out, lse)
+        return attention_reference(q, k, v, causal=self.causal, mask=mask,
+                                   window=window)
 
     # ------------------------------------------------- incremental decode
     def cache_shape(self, rows: int, max_len: int) -> Tuple[int, ...]:
@@ -357,6 +369,162 @@ class QKNormAttentionLayer(SelfAttentionLayer):
         out = self._attend(norm(x @ params["Wq"], "q_gamma"),
                            norm(x @ params["Wk"], "k_gamma"),
                            x @ params["Wv"], mask) @ params["Wo"]
+        if mask is not None:
+            out = out * mask[..., None]
+        return out, state
+
+
+@register_layer
+@dataclass
+class KeyValueProjectionLayer(BaseLayerConf):
+    """The keys and values of one attention layer as a node's own output:
+    ``[B, T, F] -> [B, T, 2 G D]``, ``[W_k u + b_k ; W_v u + b_v]`` for ``G``
+    key/value heads of ``D``, in one product. The attention layer after it
+    reads them as its second input, and so may any layer above that owns
+    queries alone (``DifferentialAttentionLayer``): one K, V read across
+    layers. Params: ``W [F, 2 G D]`` (keys first), ``b [2 G D]``."""
+    n_kv_heads: int = 8
+    head_dim: int = 64
+
+    def set_n_in(self, in_type: InputType) -> None:
+        if in_type.kind != "rnn":
+            raise ValueError(
+                f"KeyValueProjectionLayer expects RNN input, got {in_type}")
+        self.n_in = in_type.size
+
+    def infer_output_type(self, in_type: InputType) -> InputType:
+        return InputType.recurrent(2 * self.n_kv_heads * self.head_dim,
+                                   in_type.timesteps)
+
+    def init_params(self, rng, dtype=jnp.float32) -> Params:
+        F, W = self.n_in, 2 * self.n_kv_heads * self.head_dim
+        return {"W": self._init_w(rng, (F, W), F, W, dtype),
+                "b": self._init_b((W,), dtype)}
+
+    def apply(self, params, x, *, state, train, rng, mask=None):
+        x = self._dropout_input(x, train, rng)
+        return x @ params["W"] + params["b"], state
+
+
+@register_layer
+@dataclass
+class DifferentialAttentionLayer(SelfAttentionLayer):
+    """Causal differential attention (Ye et al., arXiv:2410.05258) over two
+    inputs: the stream's ``u [B, T, F]``, which it projects to queries, and
+    the keys and values ``[B, T, 2 G D]`` of a ``KeyValueProjectionLayer``,
+    its own block's or a layer's further down (a cross layer: it owns no
+    ``W_k``, ``W_v``). No positional term of any kind.
+
+    ``n_heads`` query heads and ``G = n_kv_heads`` key/value heads of ``D =
+    head_dim`` make ``n_heads / 2`` query pairs and ``G / 2`` key/value
+    pairs: query pair ``p`` is heads ``(2p, 2p+1)`` and reads key/value pair
+    ``p // (n_heads / G)``, whose two keys score one map each over ONE value
+    ``V = [v_a ; v_b]`` of ``2 D``::
+
+        A^j  = softmax(q^j k^jT / sqrt(D) + mask),  j = 1, 2
+        lam  = exp(l_q1 . l_k1) - exp(l_q2 . l_k2) + lambda_init
+        o_p  = (1 - lambda_init) gamma * RMSNorm_2D((A^1 - lam A^2) V)
+        y    = W_o concat_p o_p + b_o
+
+    ``lambda_init = 0.8 - 0.6 exp(-0.3 depth)`` with ``depth`` the layer's
+    index in the whole model. With ``window`` key ``s`` is seen from ``t``
+    when ``0 <= t - s < window``. A map is one head of the flash kernels:
+    ``q^j, k^j`` of ``D`` beside a value of ``2 D`` (the kernels' scale is
+    ``1 / sqrt(D)`` of the key's width), the keys and values repeated to
+    the query heads' count. Trains; no incremental decode, no sequence-parallel ring.
+
+    Params: ``Wq [F, H D]``, ``bq``, ``lq1, lk1, lq2, lk2 [D]``, ``gamma
+    [2 D]``, ``Wo [H D, F]``, ``bo``."""
+    n_kv_heads: int = 0         # default n_heads
+    window: Optional[int] = None
+    depth: int = 0
+    norm_eps: float = 1e-5
+    causal: bool = True
+    sequence_parallel: bool = False
+
+    N_INPUTS = 2
+    supports_kv_cache = False
+
+    @property
+    def lambda_init(self) -> float:
+        return 0.8 - 0.6 * math.exp(-0.3 * self.depth)
+
+    def set_n_in(self, in_type: InputType) -> None:
+        super().set_n_in(in_type)
+        if not self.n_kv_heads:
+            self.n_kv_heads = self.n_heads
+        if (self.n_heads % 2 or self.n_kv_heads % 2
+                or self.n_heads % self.n_kv_heads):
+            raise ValueError(
+                f"DifferentialAttentionLayer({self.name!r}): pairs need "
+                f"even head counts and n_heads a multiple of n_kv_heads, "
+                f"got {self.n_heads} and {self.n_kv_heads}")
+
+    def set_side_inputs(self, in_types) -> None:
+        (kv,) = in_types
+        want = 2 * self.n_kv_heads * self.head_dim
+        if kv.kind != "rnn" or kv.size != want:
+            raise ValueError(
+                f"DifferentialAttentionLayer({self.name!r}): keys and "
+                f"values of width {want} expected as second input, got {kv}")
+
+    def param_order(self) -> List[str]:
+        return ["Wq", "bq", "lq1", "lk1", "lq2", "lk2", "gamma", "Wo", "bo"]
+
+    def regularization(self):
+        reg = super().regularization()
+        for p in ("bq", "bo", "lq1", "lk1", "lq2", "lk2"):
+            reg[p] = (self.l1_bias or 0.0, self.l2_bias or 0.0)
+        return reg
+
+    def init_params(self, rng, dtype=jnp.float32) -> Params:
+        F, D = self.n_in, self.head_dim
+        HD = self.n_heads * D
+        ks = jax.random.split(rng, 6)
+        p = {"Wq": self._init_w(ks[0], (F, HD), F, HD, dtype),
+             "bq": self._init_b((HD,), dtype),
+             "gamma": jnp.ones((2 * D,), dtype),
+             "Wo": self._init_w(ks[1], (HD, F), HD, F, dtype),
+             "bo": self._init_b((F,), dtype)}
+        for name, k in zip(("lq1", "lk1", "lq2", "lk2"), ks[2:]):
+            # each l_* normal(0, 0.1), as the differential transformer's
+            p[name] = (0.1 * jax.random.normal(k, (D,))).astype(dtype)
+        return p
+
+    def apply(self, params, x, *, state, train, rng, mask=None):
+        u, kv = x
+        u = self._dropout_input(u, train, rng)
+        B, T, _ = u.shape
+        H, G, D = self.n_heads, self.n_kv_heads, self.head_dim
+        pairs, kv_pairs = H // 2, G // 2
+        acc = jnp.promote_types(u.dtype, jnp.float32)
+        q = self._split_heads(u @ params["Wq"] + params["bq"])
+
+        def to_query_heads(a, width):
+            # [B, T, kv_pairs, m, width] -> [B, H, T, width]: kernel head
+            # 2p + j takes entry j % m of key/value pair p // (pairs /
+            # kv_pairs)
+            m = a.shape[3]
+            a = a.transpose(0, 2, 3, 1, 4)[:, :, None, None]
+            a = jnp.broadcast_to(a, (B, kv_pairs, pairs // kv_pairs, 2 // m,
+                                     m, T, width))
+            return a.reshape(B, H, T, width)
+
+        k = to_query_heads(kv[..., :G * D].reshape(B, T, kv_pairs, 2, D), D)
+        v = to_query_heads(
+            kv[..., G * D:].reshape(B, T, kv_pairs, 1, 2 * D), 2 * D)
+        out = self._attend_heads(q, k, v, mask, window=self.window)
+        with jax.named_scope("attn:diff_norm"):
+            wide = lambda name: params[name].astype(acc)
+            lam = (jnp.exp(jnp.sum(wide("lq1") * wide("lk1")))
+                   - jnp.exp(jnp.sum(wide("lq2") * wide("lk2")))
+                   + self.lambda_init)
+            out = out.reshape(B, pairs, 2, T, 2 * D).astype(acc)
+            out = rms_normalize(out[:, :, 0] - lam * out[:, :, 1],
+                                self.norm_eps)
+            out = out * (wide("gamma") * (1.0 - self.lambda_init))
+            out = out.transpose(0, 2, 1, 3).reshape(B, T, H * D)
+        out = out.astype(u.dtype) @ params["Wo"] + params["bo"]
         if mask is not None:
             out = out * mask[..., None]
         return out, state

@@ -72,6 +72,12 @@ class BaseLayerConf:
     # filled by the builder:
     n_in: Optional[int] = None
 
+    #: inputs a graph node of this layer takes. A layer of more than one is
+    #: handed them as a tuple in ``apply``'s ``x``, the first being the
+    #: stream (the one a preprocessor, the mask and ``set_n_in`` go by) and
+    #: the rest other nodes' outputs, described by ``set_side_inputs``
+    N_INPUTS = 1
+
     # ------------------------------------------------------------------ serde
     @classmethod
     def type_tag(cls) -> str:
@@ -137,6 +143,11 @@ class BaseLayerConf:
 
     def set_n_in(self, in_type: InputType) -> None:
         self.n_in = in_type.flat_size()
+
+    def set_side_inputs(self, in_types) -> None:
+        """The types of the inputs after the first, for a layer of
+        ``N_INPUTS > 1`` to check and to size its parameters by."""
+        raise NotImplementedError
 
     def infer_output_type(self, in_type: InputType) -> InputType:
         raise NotImplementedError

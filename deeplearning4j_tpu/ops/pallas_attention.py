@@ -31,10 +31,19 @@ bfloat16 q), the additive bias and the causal select, the running max
 and normaliser, ``exp``, the logsumexp, ``D = rowsum(dO * O)``, the
 accumulators and the masked-row gates on ``NEG_INF``; the outputs are
 cast to the inputs' dtype at the end.
-``pallas_flash_traces_total{operands=...}`` counts the traces by that
-dtype.
+``pallas_flash_traces_total`` counts the traces by that dtype.
 
-Shapes: q, k, v are [B, H, T, D] (self-attention: same T). The kernel
+A causal ``window`` (key ``s`` seen from ``t`` when ``0 <= t - s <
+window``) is a second select beside the causal one AND a bound of each
+kernel's loop: the forward and dq start at the first block of keys the
+window reaches, dk/dv stops after the last block of queries that see its
+keys, so at a block of 512 and a window of 512 a grid step makes two tiles
+and not up to seventeen. With ``window=None`` the three kernels lower to
+what they were before the option (PERF.md, PR 33).
+``pallas_flash_traces_total{operands=..., window=...}`` counts the traces.
+
+Shapes: q, k are [B, H, T, D] and v [B, H, T, Dv] (self-attention: same
+T; ``Dv`` may differ, all three are padded to one lane width). The kernel
 pads D to 128 and T to its block internally (``_padded_len``: 512, 256 or
 128 rows, the largest that costs no more than an eighth of padding);
 padded KV columns are masked with the same additive bias that carries
@@ -174,8 +183,22 @@ def _lanes(x, B: int):
 # forward kernel
 # ---------------------------------------------------------------------------
 
+def _first_key_block(qi, B: int, window: int):
+    """The first block of keys that a block of queries sees through a
+    window: its first query ``qi B`` sees key ``s`` when ``qi B - s <
+    window``."""
+    return jnp.maximum(qi * B - (window - 1), 0) // B
+
+
+def _last_query_block(ki, B: int, window: int, n_blocks: int):
+    """One past the last block of queries that see a block of keys through
+    a window: its last key ``(ki + 1) B - 1`` is seen from ``t`` while ``t -
+    (ki + 1) B + 1 < window``."""
+    return jnp.minimum(((ki + 1) * B + window - 2) // B + 1, n_blocks)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
-                causal: bool, scale: float):
+                causal: bool, scale: float, window: Optional[int] = None):
     # MXU operands (q, k, v, and p below) keep the input dtype; the
     # scale multiplies the float32 scores, never a bfloat16 q
     q = q_ref[0]                                      # [B, Dp]
@@ -192,6 +215,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
         if causal:
             k_pos = j * B + jax.lax.broadcasted_iota(jnp.int32, (B, B), 1)
             s = jnp.where(k_pos <= q_pos, s, NEG_INF)
+            if window is not None:
+                s = jnp.where(q_pos - k_pos < window, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
@@ -206,7 +231,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
     # skip them instead of feeding NEG_INF tiles to the MXU (square
     # blocks, so block j is live iff j <= qi)
     hi = (qi + 1) if causal else k_ref.shape[1] // B
-    acc, m, l = jax.lax.fori_loop(0, hi, body, (acc0, m0, l0))
+    # a window: blocks wholly before it are skipped too. A row whose window
+    # starts past the first block visited sees that block all masked, and
+    # what it adds there is wiped when the diagonal block raises m
+    lo = 0 if window is None else _first_key_block(qi, B, window)
+    acc, m, l = jax.lax.fori_loop(lo, hi, body, (acc0, m0, l0))
     l_safe = jnp.maximum(l, 1e-30)
     # a fully-masked row (zero valid keys) never raises m off NEG_INF —
     # float absorption keeps l > 0 there (exp(s - m) == exp(0)), so the
@@ -219,13 +248,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
     lse_ref[0] = jnp.broadcast_to(lse, (B, _BLK))
 
 
-def _run_fwd(q, k, v, bias, causal, interpret, scale):
+def _run_fwd(q, k, v, bias, causal, interpret, scale, window=None):
     """q,k,v: [G, Tp, Dp]; bias: [G, 1, Tp] additive (0 / NEG_INF).
     Returns (out [G, Tp, Dp], lse [G, Tp, _BLK] lane-replicated)."""
     G, Tp, Dp = q.shape
     B = _block(Tp)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, causal=causal, scale=scale),
+        functools.partial(_fwd_kernel, causal=causal, scale=scale,
+                          window=window),
         grid=(G, Tp // B),
         in_specs=[
             pl.BlockSpec((1, B, Dp), lambda g, i: (g, i, 0)),
@@ -252,7 +282,8 @@ def _run_fwd(q, k, v, bias, causal, interpret, scale):
 # ---------------------------------------------------------------------------
 
 def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, dvec_ref,
-               dq_ref, *, causal: bool, scale: float):
+               dq_ref, *, causal: bool, scale: float,
+               window: Optional[int] = None):
     q = q_ref[0]                                      # [B, Dp]
     do = do_ref[0]
     B = q.shape[0]
@@ -269,6 +300,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, dvec_ref,
         if causal:
             k_pos = j * B + jax.lax.broadcasted_iota(jnp.int32, (B, B), 1)
             s = jnp.where(k_pos <= q_pos, s, NEG_INF)
+            if window is not None:
+                s = jnp.where(q_pos - k_pos < window, s, NEG_INF)
         # fully-masked query rows (zero valid keys) carry lse == NEG_INF
         # from the forward; exp(s - lse) there is garbage (float
         # absorption, not inf) — gate them to zero probability so the
@@ -280,11 +313,13 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, dvec_ref,
 
     dq0 = jnp.zeros(q.shape, jnp.float32)
     hi = (qi + 1) if causal else k_ref.shape[1] // B
-    dq_ref[0] = jax.lax.fori_loop(0, hi, body, dq0).astype(dq_ref.dtype)
+    lo = 0 if window is None else _first_key_block(qi, B, window)
+    dq_ref[0] = jax.lax.fori_loop(lo, hi, body, dq0).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, dvec_ref,
-                dk_ref, dv_ref, *, causal: bool, scale: float):
+                dk_ref, dv_ref, *, causal: bool, scale: float,
+                window: Optional[int] = None):
     """One grid step holds B keys and walks the queries in blocks of B, on
     TRANSPOSED score tiles ``S^T = K Q^T``: keys along the sublanes,
     queries along the lanes. ``P^T dO`` and ``dS^T Q`` are then plain
@@ -309,6 +344,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, dvec_ref,
         if causal:
             q_pos = i * B + jax.lax.broadcasted_iota(jnp.int32, (B, B), 1)
             st = jnp.where(k_pos <= q_pos, st, NEG_INF)
+            if window is not None:
+                st = jnp.where(q_pos - k_pos < window, st, NEG_INF)
         # same masked-row gate as _dq_kernel: queries with lse == NEG_INF
         # (no valid key) must contribute zero to dk/dv
         pt = jnp.where(lse > NEG_INF / 2, jnp.exp(st - lse), 0.0)
@@ -321,12 +358,17 @@ def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, dvec_ref,
     z = jnp.zeros(kblk.shape, jnp.float32)
     # causal: q blocks above the diagonal never attend to this KV block
     lo = ki if causal else 0
-    dk, dv = jax.lax.fori_loop(lo, q_ref.shape[1] // B, body, (z, z))
+    n_blocks = q_ref.shape[1] // B
+    # a window: nor do the q blocks wholly past it
+    hi = n_blocks if window is None else _last_query_block(
+        ki, B, window, n_blocks)
+    dk, dv = jax.lax.fori_loop(lo, hi, body, (z, z))
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
-def _run_bwd(q, k, v, bias, do, out, lse, causal, interpret, scale):
+def _run_bwd(q, k, v, bias, do, out, lse, causal, interpret, scale,
+             window=None):
     G, Tp, Dp = q.shape
     B = _block(Tp)
     dvec = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
@@ -338,7 +380,8 @@ def _run_bwd(q, k, v, bias, do, out, lse, causal, interpret, scale):
     fullrow = pl.BlockSpec((1, 1, Tp), lambda g, i: (g, 0, 0))
     params = _params(Tp, Dp, q.dtype.itemsize)
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, causal=causal, scale=scale),
+        functools.partial(_dq_kernel, causal=causal, scale=scale,
+                          window=window),
         grid=(G, Tp // B),
         in_specs=[blkspec, fullspec, fullspec, fullrow, blkspec, vecspec,
                   vecspec],
@@ -351,7 +394,8 @@ def _run_bwd(q, k, v, bias, do, out, lse, causal, interpret, scale):
     # dk/dv read the per-query vectors as [1, Tp] rows and the keys' bias
     # as a [B, 1] column
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, causal=causal, scale=scale),
+        functools.partial(_dkv_kernel, causal=causal, scale=scale,
+                          window=window),
         grid=(G, Tp // B),
         in_specs=[fullspec, blkspec, blkspec, colspec, fullspec, fullrow,
                   fullrow],
@@ -370,54 +414,65 @@ def _run_bwd(q, k, v, bias, do, out, lse, causal, interpret, scale):
 # differentiable core + public entry
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _flash_core(q, k, v, bias, causal, interpret, scale):
-    out, _ = _run_fwd(q, k, v, bias, causal, interpret, scale)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash_core(q, k, v, bias, causal, interpret, scale, window):
+    out, _ = _run_fwd(q, k, v, bias, causal, interpret, scale, window)
     return out
 
 
-def _flash_core_fwd(q, k, v, bias, causal, interpret, scale):
-    out, lse = _run_fwd(q, k, v, bias, causal, interpret, scale)
+def _flash_core_fwd(q, k, v, bias, causal, interpret, scale, window):
+    out, lse = _run_fwd(q, k, v, bias, causal, interpret, scale, window)
     return out, (q, k, v, bias, out, lse)
 
 
-def _flash_core_bwd(causal, interpret, scale, res, g):
+def _flash_core_bwd(causal, interpret, scale, window, res, g):
     q, k, v, bias, out, lse = res
     dq, dk, dv = _run_bwd(q, k, v, bias, g, out, lse, causal, interpret,
-                          scale)
+                          scale, window)
     return dq, dk, dv, jnp.zeros_like(bias)
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 
-def _count_trace(dtype) -> None:
+def _count_trace(dtype, window: Optional[int]) -> None:
     """Which products a run's kernels make, counted once per trace (not
-    per step) under the operands' dtype."""
+    per step) under the operands' dtype and the window's width."""
     from deeplearning4j_tpu.profiling.metrics import get_registry
     get_registry().labeled_counter(
         "pallas_flash_traces_total",
-        "flash-attention traces by the dtype of the MXU operands (per trace)",
-    ).labels(operands=jnp.dtype(dtype).name).inc()
+        "flash-attention traces by the dtype of the MXU operands and the "
+        "window (per trace)",
+    ).labels(operands=jnp.dtype(dtype).name,
+             window="none" if window is None else window).inc()
 
 
 def flash_attention(q, k, v, *, causal: bool = False,
                     kv_mask: Optional[jnp.ndarray] = None,
-                    interpret: bool = False) -> jnp.ndarray:
-    """softmax(QK^T/sqrt(D))V via the Pallas kernels. q,k,v: [B,H,T,D]
-    (self-attention: shared T). ``kv_mask``: [B, T] key validity.
+                    interpret: bool = False,
+                    window: Optional[int] = None) -> jnp.ndarray:
+    """softmax(QK^T/sqrt(D))V via the Pallas kernels. q, k: [B,H,T,D], v:
+    [B,H,T,Dv] (self-attention: shared T; ``Dv`` may differ from ``D``, as
+    where two score maps of head 64 share a value of 128). ``kv_mask``:
+    [B, T] key validity. ``window`` (with ``causal``): key ``s`` is seen
+    from ``t`` when ``0 <= t - s < window``; the kernels' loops leave out
+    the blocks of keys wholly outside it.
 
     The products' operands have ``q.dtype`` (k and v are brought to it)
     and their accumulators are float32: bfloat16 inputs reach the MXU as
     they are, float32 inputs are not narrowed. The softmax scale is that
-    of the UNPADDED head dim and multiplies the float32 scores."""
+    of the UNPADDED head dim ``D`` and multiplies the float32 scores."""
     B, H, T, D = q.shape
-    Tp, Dp = _padded_len(T), _round_up(D, _BLK)
-    _count_trace(q.dtype)
+    Dv = v.shape[-1]
+    if window is not None and (not causal or window < 1):
+        raise ValueError("a window is causal and at least 1 wide, got "
+                         f"causal={causal}, window={window}")
+    Tp, Dp = _padded_len(T), _round_up(max(D, Dv), _BLK)
+    _count_trace(q.dtype, window)
 
     def prep(x):
-        x = jnp.pad(x.astype(q.dtype),
-                    ((0, 0), (0, 0), (0, Tp - T), (0, Dp - D)))
+        x = jnp.pad(x.astype(q.dtype), ((0, 0), (0, 0), (0, Tp - T),
+                                        (0, Dp - x.shape[-1])))
         return x.reshape(B * H, Tp, Dp)
 
     qf, kf, vf = prep(q), prep(k), prep(v)
@@ -427,5 +482,5 @@ def flash_attention(q, k, v, *, causal: bool = False,
     bias = jnp.where(valid > 0, 0.0, NEG_INF).astype(jnp.float32)
     bias = jnp.repeat(bias, H, axis=0)[:, None, :]     # [B*H, 1, Tp]
     out = _flash_core(qf, kf, vf, bias, causal, interpret,
-                      1.0 / math.sqrt(D))
-    return out.reshape(B, H, Tp, Dp)[:, :, :T, :D]
+                      1.0 / math.sqrt(D), window)
+    return out.reshape(B, H, Tp, Dp)[:, :, :T, :Dv]

@@ -289,10 +289,10 @@ def test_flash_traces_are_counted_by_operand_dtype():
         fn(*args)
         fn(*args)
         counted = registry.labeled_counter("pallas_flash_traces_total")
-        assert counted.labels(operands="bfloat16").value == 1
-        assert counted.labels(operands="float32").value == 0
+        assert counted.labels(operands="bfloat16", window="none").value == 1
+        assert counted.labels(operands="float32", window="none").value == 0
         fn(*_qkv(B=1, H=1, T=16, D=8))
-        assert counted.labels(operands="float32").value == 1
+        assert counted.labels(operands="float32", window="none").value == 1
         assert counted.value == 2
     finally:
         set_registry(previous)

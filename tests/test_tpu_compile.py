@@ -102,3 +102,30 @@ def test_delta_rule_kernels_compile_for_a_v5e(one_chip, no_compile_cache,
                          (bwd, "gdn_chunk_local_bwd")):
             text = jax.jit(fn).lower(*args).compile().as_text()
             assert text.count("tpu_custom_call") == 1 and name in text
+
+
+@pytest.mark.parametrize("precision", ["default", "highest"])
+@pytest.mark.parametrize(
+    "heads,T,window,dtype",
+    [(40, 8192, 512, jnp.bfloat16), (40, 8192, 512, jnp.float32),
+     (40, 8192, None, jnp.bfloat16), (4, 1100, 512, jnp.bfloat16),
+     (4, 8192, 1, jnp.bfloat16), (4, 8192, 700, jnp.float32)],
+    ids=["sambay_window-bfloat16", "sambay_window-float32",
+         "sambay_full-bfloat16", "padded_window-bfloat16",
+         "window_of_one-bfloat16", "window_across_blocks-float32"])
+def test_windowed_flash_kernels_compile_for_a_v5e(
+        one_chip, no_compile_cache, heads, T, window, dtype, precision):
+    """The flash kernels as a differential attention layer calls them: a
+    key of 64 beside a value of 128, with the window's select and loop
+    bounds (and without: the full and the cross layer), at the SambaY
+    cell's shape in both widths, at a padded length, and at windows of one
+    token and of no whole number of blocks."""
+    qk = jax.ShapeDtypeStruct((1, heads, T, 64), dtype, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, heads, T, 128), dtype, sharding=one_chip)
+    fwd = functools.partial(flash_attention, causal=True, window=window)
+    bwd = jax.grad(lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
+                   argnums=(0, 1, 2))
+    with jax.default_matmul_precision(precision):
+        for fn, kernels in ((fwd, 1), (bwd, 3)):
+            text = jax.jit(fn).lower(qk, qk, v).compile().as_text()
+            assert text.count("tpu_custom_call") == kernels
