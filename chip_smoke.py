@@ -16,9 +16,10 @@ weights from a seed), and checks what comes out by the repo's own means:
                 the delta rule's chunk-local kernels against its XLA path
                 at the hybrid cell's shape, values and gradients; the flash
                 kernels with a window against the written-out mask and the
-                chunked selective scan against the token-by-token one, at
-                the SambaY cell's shapes in bfloat16 and at lengths that
-                are no multiple of a block in float32
+                selective scan, by its kernels and by the chunked form,
+                against the token-by-token one, at the SambaY cell's shapes
+                in bfloat16 and at lengths that are no multiple of a block
+                in float32
   P4 multichip  ResNet-50 over ``ParallelTrainer`` on four chips, when the
                 machine has them
 
@@ -33,6 +34,7 @@ result line. It is never the default and proves nothing about the chip.
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import sys
@@ -43,6 +45,12 @@ import traceback
 import numpy as np
 
 DRY = "--dry-cpu" in sys.argv[1:]
+#: the selective scan's kernels in float32 against float64 on the host: they
+#: chain all T decays, as the token-by-token form does, and the v5e's
+#: float32 exp is biased by -1e-6 (1.6e-4 to 6.0e-4 read at T 1,100 for
+#: both, PERF.md section 6, PRs 33 and 34); against the chip's own
+#: token-by-token float32 they read 2e-7 and are held to 1e-5
+SCAN_KERNEL_TOL = 2e-3
 TAG = "[DRY-CPU] " if DRY else ""
 OUT_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                        "chiprun_out", "chip_smoke")
@@ -514,18 +522,21 @@ def window_attention_parity(B, H, T, D, Dv, window, dtype) -> dict:
 
 
 def selective_scan_parity(B, T, D, N, dtype) -> dict:
-    """The chunked selective scan against the token-by-token one on the
-    same inputs (``x`` in ``dtype``, the rest float32): the output and the
-    gradients of all five inputs, the largest error over the largest
-    entry. Steps near 0 and decays down to ``exp(-30)`` a token. In float32
-    the token-by-token side runs in float64 on the host, so that the gap
-    read is the chunked form's own and not the rounding of a thousand
-    chained float32 steps on either side (PERF.md section 6, PR 33)."""
+    """The selective scan by both paths, the Pallas kernels and the chunked
+    XLA form, against the token-by-token one on the same inputs (``x`` in
+    ``dtype``, the rest float32): the output and the gradients of all five
+    inputs, the largest error over the largest entry. Steps near 0 and
+    decays down to ``exp(-30)`` a token. In float32 the token-by-token side
+    runs in float64 on the host, so that the gap read is each form's own
+    and not the rounding of a thousand chained float32 steps on the other
+    side (PERF.md section 6, PR 33)."""
     import jax
     import jax.numpy as jnp
 
     from deeplearning4j_tpu.nn.layers.state_space import (
         selective_scan_chunked, selective_scan_recurrent)
+    from deeplearning4j_tpu.ops.pallas_selective_scan import (
+        selective_scan, selective_scan_ok)
 
     rng = np.random.default_rng(19)
     f32 = lambda a: jnp.asarray(a, jnp.float32)
@@ -534,13 +545,14 @@ def selective_scan_parity(B, T, D, N, dtype) -> dict:
             f32(-np.exp(rng.uniform(np.log(1e-2), np.log(16.0), (N, D)))),
             f32(rng.normal(size=(B, T, N))), f32(rng.normal(size=(B, T, N))))
     cot = f32(rng.normal(size=(B, T, D)))
+    check(selective_scan_ok(T, D, N, jnp.float32, dtype),
+          f"the selective scan's gate refuses T={T} d_in={D} N={N} {dtype}")
 
     def run(fn, args, cot):
         loss = lambda *a: jnp.sum(fn(*a) * cot)
         return (jax.jit(fn)(*args), *jax.jit(
             jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(*args))
 
-    got = run(selective_scan_chunked, args, cot)
     if dtype == "float32":
         host = jax.devices("cpu")[0]
         with jax.enable_x64(True):
@@ -550,16 +562,27 @@ def selective_scan_parity(B, T, D, N, dtype) -> dict:
                 selective_scan_recurrent, wide[:-1], wide[-1])]
     else:
         ref = run(selective_scan_recurrent, args, cot)
-    rec = _rel_errors("y dx ddelta da db dc", got, ref)
-    # float32: 1.2e-5 to 3.2e-5 read on the v5e, whose float32 exp is
-    # biased by -1e-6 and the chunked form chains sixteen steps, sixteen
-    # runs and T / 256 blocks of it (2e-7 on the CPU); the token-by-token
-    # form on the chip chains T and reads 6e-4 against the same float64
-    tol = 2e-2 if dtype == "bfloat16" else 1e-4
+    # float32, chunked: 1.2e-5 to 3.2e-5 read on the v5e, whose float32 exp
+    # is biased by -1e-6 and the chunked form chains sixteen steps, sixteen
+    # runs and T / 256 blocks of it (2e-7 on the CPU)
+    tols = {"chunked": 2e-2 if dtype == "bfloat16" else 1e-4,
+            "kernel": 2e-2 if dtype == "bfloat16" else SCAN_KERNEL_TOL}
+    got = {"chunked": run(selective_scan_chunked, args, cot),
+           "kernel": run(functools.partial(selective_scan, interpret=DRY),
+                         args, cot)}
+    refs = {path: (ref, tol) for path, tol in tols.items()}
+    if dtype == "float32":      # the same chain of the same exp
+        got["kernel_same_device"] = got["kernel"]
+        refs["kernel_same_device"] = (
+            run(selective_scan_recurrent, args, cot), 1e-5)
     name = f"selective_scan B={B} T={T} d_in={D} N={N} {dtype}"
-    check(max(rec.values()) < tol, f"{name}: chunked against token by token "
-          f"{rec} (tol {tol:.0e} of the largest entry)")
-    return rec
+    out = {}
+    for path, (want, tol) in refs.items():
+        rec = _rel_errors("y dx ddelta da db dc", got[path], want)
+        check(max(rec.values()) < tol, f"{name}: {path} against token by "
+              f"token {rec} (tol {tol:.0e} of the largest entry)")
+        out[path] = rec
+    return out
 
 
 def _lowered_step_text(net, batch) -> str:
@@ -633,15 +656,16 @@ def p3_kernels() -> dict:
     say("P3 kernels: flash kernels with a window against the written-out "
         "mask, error over the largest entry "
         f"{rec['window_attention_rel_err']}")
-    shapes = ((1, 300, 128, 4), (2, 100, 128, 4)) if DRY else (
+    shapes = ((1, 300, 128, 8), (2, 100, 128, 8)) if DRY else (
         (1, 8192, 5120, 16), (2, 1100, 1024, 16))
     rec["selective_scan_rel_err"] = {
         f"{shapes[0]} bfloat16": selective_scan_parity(
             *shapes[0], dtype="bfloat16"),
         f"{shapes[1]} float32": selective_scan_parity(
             *shapes[1], dtype="float32")}
-    say("P3 kernels: chunked selective scan against token by token, error "
-        f"over the largest entry {rec['selective_scan_rel_err']}")
+    say("P3 kernels: selective scan, kernels and chunked form against token "
+        f"by token, error over the largest entry "
+        f"{rec['selective_scan_rel_err']}")
 
     hidden, T, K, B = (32, 8, 16, 4) if DRY else (256, 64, 96, 32)
     lstm = MultiLayerNetwork(

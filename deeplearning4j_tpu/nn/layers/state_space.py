@@ -13,8 +13,26 @@ There is no matrix form of it (the decay is no product of a factor a
 channel and a factor a state), so the work is elementwise: the VPU's, and
 the EUP's for the exponentials. Token by token it is ``T`` dependent steps
 on ``d_in N`` numbers, and all ``T`` states at once are ``T d_in N`` float32
-(2.7 GB at 8,192 tokens of 5,120 channels and 16 states), so
-``selective_scan_chunked`` does neither. A ``lax.scan`` walks the sequence
+(2.7 GB at 8,192 tokens of 5,120 channels and 16 states).
+
+``selective_scan`` is the seam, and one algorithm with two implementations
+behind it. **On a TPU, in float32, for whole lanes of channels** (``d_in``
+a multiple of 128, ``N`` of 8, blocks inside the kernels' VMEM gate) the
+recurrence runs token by token in the two Pallas kernels of
+``ops/pallas_selective_scan.py``, forward and backward, which hold a tile
+of channels' state in vector registers, so that what made token-by-token
+slow in XLA (the state's trip through HBM every step) is gone; they add no
+``while`` to a step. **Everything else takes** ``selective_scan_chunked``:
+float64 (the gradient checks), ``DL4J_TPU_PALLAS=off``, a CPU (unless the
+variable says ``interpret``), and every shape the gate refuses, the tests'
+tiny models among them (a refusal with the kernels on counts under
+``pallas_gate_fallbacks_total{kernel="selective_scan"}``). It stays for
+them, and as the kernels' reference in the tests beside
+``selective_scan_recurrent``. ``ssm_scan_traces_total{path=}`` says which
+of the two a trace took.
+
+``selective_scan_chunked`` neither steps token by token nor keeps all
+states. A ``lax.scan`` walks the sequence
 in blocks of ``steps * lanes`` tokens and carries ``h [B, N, d_in]``. A
 block is ``lanes`` runs of ``steps`` consecutive tokens. All runs take their
 ``steps`` steps side by side from a zero state (the loop is unrolled:
@@ -35,6 +53,7 @@ pass keeps the carries at the blocks' edges (``T / (steps lanes)`` of
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import List, Sequence
 
@@ -49,6 +68,7 @@ from deeplearning4j_tpu.nn.layers.base import (
 from deeplearning4j_tpu.nn.layers.linear_attention import (
     causal_depthwise_conv,
 )
+from deeplearning4j_tpu.nn.remat import backward_after_cotangent
 
 #: consecutive tokens a run takes one after another (unrolled)
 STEPS = 16
@@ -60,13 +80,38 @@ DT_MIN, DT_MAX = 1e-3, 1e-1
 
 def _count_trace(path: str) -> None:
     """``ssm_scan_traces_total{path=}``: which path a scan took, once a
-    trace. ``"xla"`` is the chunked scan below; ``"kernel"`` waits for a
-    Pallas kernel to count under."""
+    trace. ``"xla"`` is the chunked scan below, which counts itself
+    (whoever calls it); ``"kernel"`` the Pallas kernels, counted at the
+    seam: exactly one of the two a scan."""
     from deeplearning4j_tpu.profiling.metrics import get_registry
     get_registry().labeled_counter(
         "ssm_scan_traces_total",
         "selective-scan traces by the path they took (per trace)",
     ).labels(path=path).inc()
+
+
+def selective_scan(x: Array, delta: Array, a: Array, b: Array, c: Array, *,
+                   layer=None) -> Array:
+    """``y_t[c] = sum_n h_t[c, n] C_t[n]`` of the module's recurrence from
+    ``h_0 = 0``, by the Pallas kernels where their gate allows and by
+    :func:`selective_scan_chunked` (whose arguments these are) otherwise; a
+    refusal counts under ``layer``'s name, where the caller is a layer."""
+    from deeplearning4j_tpu.ops import pallas_selective_scan as pss
+    from deeplearning4j_tpu.ops.pallas_attention import attention_mode
+    from deeplearning4j_tpu.ops.pallas_kernels import count_gate_fallback
+
+    mode = attention_mode()
+    if mode != "off":
+        if pss.selective_scan_ok(x.shape[1], x.shape[2], a.shape[0],
+                                 delta.dtype, x.dtype):
+            _count_trace("kernel")
+            # the kernels' own rule: inputs and block starts kept
+            return backward_after_cotangent(functools.partial(
+                pss.selective_scan, interpret=mode == "interpret"))(
+                    x, delta, a, b, c)
+        if layer is not None:
+            count_gate_fallback(layer, "selective_scan")
+    return selective_scan_chunked(x, delta, a, b, c)
 
 
 def selective_scan_chunked(x: Array, delta: Array, a: Array, b: Array,
@@ -230,8 +275,8 @@ class SelectiveScanLayer(BaseLayerConf):
             b_t = proj[..., R:R + N].astype(acc)
             c_t = proj[..., R + N:].astype(acc)
         with jax.named_scope("ssm:scan"):
-            y = selective_scan_chunked(inner_c, delta, -jnp.exp(wide("A_log")),
-                                       b_t, c_t)
+            y = selective_scan(inner_c, delta, -jnp.exp(wide("A_log")),
+                               b_t, c_t, layer=self)
             out = (y + wide("D") * inner).astype(x.dtype)
         return out, state
 

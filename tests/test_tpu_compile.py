@@ -19,6 +19,8 @@ from jax.sharding import SingleDeviceSharding
 from deeplearning4j_tpu.ops.pallas_attention import flash_attention
 from deeplearning4j_tpu.ops.pallas_delta_rule import (
     gdn_chunk_local, padded_chunks)
+from deeplearning4j_tpu.ops.pallas_selective_scan import (
+    selective_scan, selective_scan_ok)
 
 
 @pytest.fixture(scope="module")
@@ -129,3 +131,37 @@ def test_windowed_flash_kernels_compile_for_a_v5e(
         for fn, kernels in ((fwd, 1), (bwd, 3)):
             text = jax.jit(fn).lower(qk, qk, v).compile().as_text()
             assert text.count("tpu_custom_call") == kernels
+
+
+@pytest.mark.parametrize("precision", ["default", "highest"])
+@pytest.mark.parametrize(
+    "B,T,D,dtype",
+    [(1, 256, 5120, jnp.bfloat16), (1, 300, 5120, jnp.bfloat16),
+     (1, 256, 5120, jnp.float32), (2, 70, 1152, jnp.bfloat16)],
+    ids=["sambay_cell-bfloat16", "padded-bfloat16", "sambay_cell-float32",
+         "one_block_tiles_of_128-bfloat16"])
+def test_selective_scan_kernels_compile_for_a_v5e(
+        one_chip, no_compile_cache, B, T, D, dtype, precision):
+    """The selective scan's forward kernel and its backward one at the
+    SambaY cell's channel count (5,120 channels in tiles of 1,024, 16
+    states, ``x`` in bfloat16 and in float32) over two time blocks (a
+    kernel's body does not grow with the sequence, so the cell's 8,192
+    tokens add nothing that Mosaic could refuse), at a length padded to
+    three, and at a short one of a single block of 80 tokens whose 1,152
+    channels run in tiles of 128; under the ambient precision "highest"
+    too (the kernels multiply no matrices)."""
+    N = 16
+    assert selective_scan_ok(T, D, N, jnp.float32, dtype)
+    arg = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    f32 = jnp.float32
+    args = (arg((B, T, D), dtype), arg((B, T, D), f32), arg((N, D), f32),
+            arg((B, T, N), f32), arg((B, T, N), f32))
+    bwd = jax.grad(lambda *a: selective_scan(*a).sum(),
+                   argnums=(0, 1, 2, 3, 4))
+    with jax.default_matmul_precision(precision):
+        for fn, names in ((selective_scan, ("selective_scan_fwd",)),
+                          (bwd, ("selective_scan_fwd", "selective_scan_bwd"))):
+            text = jax.jit(fn).lower(*args).compile().as_text()
+            assert text.count("tpu_custom_call") == len(names)
+            assert all(name in text for name in names)

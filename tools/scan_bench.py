@@ -1,15 +1,17 @@
 """One layer's selective scan alone at the SambaY cell's shape (1 x 8,192
 tokens, 5,120 channels, 16 states, ``x`` in bfloat16), forward and forward
-with backward, in the forms ``PERF.md`` section 6 (PR 33) compares: wall
-milliseconds a call over five calls, on whatever device JAX has.
+with backward, in the forms ``PERF.md`` section 6 (PRs 33, 34) compares:
+wall milliseconds a call over five calls, on whatever device JAX has.
 
-    chiprun -- python tools/scan_bench.py 16x16 32x16 16x32 8x32 8x64 0x64 0x256
+    chiprun -- python tools/scan_bench.py kernel 16x16 32x16 0x64 kernel:256x512
 
-``<steps>x<lanes>`` is ``selective_scan_chunked`` with that block (16x16 is
-what the layer runs); ``0x<L>`` is the form the issue gave as its example,
-``lax.associative_scan`` over the ``L`` tokens of a chunk, kept here and
-nowhere in the program. The readings land in
-``chiprun_out/pr33/scan_bench.json``. No test and no run of the benchmark
+``kernel`` is the Pallas kernels of ``ops/pallas_selective_scan.py`` as the
+layer runs them on a TPU, and ``kernel:<tokens>x<channels>`` the same with
+another block a grid step; ``<steps>x<lanes>`` is ``selective_scan_chunked``
+with that block (16x16 is the XLA path's); ``0x<L>`` is the form issue 33
+gave as its example, ``lax.associative_scan`` over the ``L`` tokens of a
+chunk, kept here and nowhere in the program. The readings land in
+``chiprun_out/pr34/scan_bench.json``. No test and no run of the benchmark
 calls this.
 """
 
@@ -26,6 +28,7 @@ import numpy as np
 from jax import lax
 
 from deeplearning4j_tpu.nn.layers.state_space import selective_scan_chunked
+from deeplearning4j_tpu.ops import pallas_selective_scan as pss
 
 B, T, D, N = 1, 8192, 5120, 16
 CALLS = 5
@@ -54,6 +57,20 @@ def scan_associative(x, delta, a, b, c, chunk):
     return jnp.moveaxis(y.reshape(T, B, D), 0, 1)
 
 
+def scan_form(form):
+    """The scan a form's name stands for, as a function of ``x, delta, a,
+    b, c``."""
+    if form.startswith("kernel"):
+        if ":" in form:     # read when the jitted runners trace
+            pss.BLOCK_T, pss.BLOCK_D = map(int, form.split(":")[1].split("x"))
+            jax.clear_caches()
+        return pss.selective_scan
+    steps, lanes = map(int, form.split("x"))
+    if not steps:
+        return lambda *z: scan_associative(*z, chunk=lanes)
+    return lambda *z: selective_scan_chunked(*z, steps=steps, lanes=lanes)
+
+
 def main(forms) -> int:
     rng = np.random.default_rng(0)
     f32 = lambda z: jnp.asarray(z, jnp.float32)
@@ -64,9 +81,7 @@ def main(forms) -> int:
     cot = f32(rng.normal(size=(B, T, D)))
     out = {"device": jax.devices()[0].device_kind}
     for form in forms:
-        steps, lanes = map(int, form.split("x"))
-        fn = (lambda *z: scan_associative(*z, chunk=lanes)) if not steps else (
-            lambda *z: selective_scan_chunked(*z, steps=steps, lanes=lanes))
+        fn = scan_form(form)
         both = jax.grad(lambda *z: jnp.sum(fn(*z) * cot),
                         argnums=(0, 1, 2, 3, 4))
         rec = {}
@@ -82,11 +97,11 @@ def main(forms) -> int:
                          "compile_s": round(compile_s, 1)}
         out[form] = rec
         print(form, json.dumps(rec), flush=True)
-    os.makedirs("chiprun_out/pr33", exist_ok=True)
-    with open("chiprun_out/pr33/scan_bench.json", "w") as f:
+    os.makedirs("chiprun_out/pr34", exist_ok=True)
+    with open("chiprun_out/pr34/scan_bench.json", "w") as f:
         json.dump(out, f, indent=1)
     return 0
 
 
 if __name__ == "__main__":
-    sys.exit(main(sys.argv[1:] or ["16x16"]))
+    sys.exit(main(sys.argv[1:] or ["kernel", "16x16"]))
