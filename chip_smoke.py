@@ -521,6 +521,117 @@ def window_attention_parity(B, H, T, D, Dv, window, dtype) -> dict:
     return rec
 
 
+def selected_attention_parity(B, H, T, D, topk, dtype) -> dict:
+    """The flash kernels under a selection (``select=``: the ``topk`` keys
+    ``s <= t`` of largest random score a query, as an indexer hands them)
+    against a softmax over the mask written out in float32: the output and
+    ``dq, dk, dv``, the largest error over the largest entry."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.nn.layers.attention import top_keys
+    from deeplearning4j_tpu.ops.pallas_attention import flash_attention
+
+    rng = np.random.default_rng(19)
+    draw = lambda: jnp.asarray(rng.normal(size=(B, H, T, D)), jnp.float32)
+    q, k, v, cot = draw(), draw(), draw(), draw()
+    select = jax.jit(lambda s: top_keys(s, 0, topk))(
+        jnp.asarray(rng.normal(size=(B, T, T)), jnp.float32))
+    kept = float(jnp.sum(select.astype(jnp.float32)))
+    want = B * sum(min(t + 1, topk) for t in range(T))
+    check(kept == want, f"selection keeps {kept:.0f} pairs of {want}")
+    kernel = lambda q, k, v: flash_attention(
+        q, k, v, causal=True, select=select, interpret=DRY)
+
+    def plain(q, k, v):
+        seen = (select != 0)[:, None]
+        scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * D ** -0.5
+        maps = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
+        return jnp.einsum("bhqk,bhkd->bhqd", maps, v)
+
+    def run(fn, args):
+        loss = lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * cot)
+        return (jax.jit(fn)(*args),
+                *jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(*args))
+
+    with jax.default_matmul_precision("highest"):
+        ref = run(plain, (q, k, v))
+        got = run(kernel, tuple(x.astype(dtype) for x in (q, k, v)))
+    rec = _rel_errors("o dq dk dv", got, ref)
+    tol = 3e-2 if dtype == "bfloat16" else 1e-3
+    name = f"flash_attention select top {topk} T={T} D={D} {dtype}"
+    check(max(rec.values()) < tol, f"{name}: kernel against the written-out "
+          f"mask {rec} (tol {tol:.0e} of the largest entry)")
+    return rec
+
+
+def routed_experts_parity(N, F, M, E, K, first, count, dtype) -> dict:
+    """The expert layer (sorted assignments, grouped products, one
+    scatter-add) against a loop over its held experts, each run on every
+    token and weighted token by token, in float32: the output and the
+    gradients of the input and the four parameters, the largest error over
+    the largest entry; and no assignment to a held expert dropped."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu import InputType
+    from deeplearning4j_tpu.nn.layers import RoutedExpertsLayer
+
+    layer = RoutedExpertsLayer(n_experts=E, top_k=K, n_hidden=M, first=first,
+                               count=count, activation="silu")
+    layer.set_n_in(InputType.recurrent(F, N))
+    rng = np.random.default_rng(23)
+    draw = lambda *s: jnp.asarray(0.05 * rng.normal(size=s), jnp.float32)
+    params = {"W_r": draw(F, E), "W_gate": draw(count, F, M),
+              "W_up": draw(count, F, M), "W_down": draw(count, M, F)}
+    u, cot = 20 * draw(1, N, F), draw(1, N, F)
+
+    def kernel(p, u):
+        return layer.apply(p, u, state=layer.init_state(), train=True,
+                           rng=None)
+
+    def plain(p, u):
+        prob = jax.nn.softmax(u @ p["W_r"], axis=-1)
+        top = jax.lax.top_k(prob, K)[0]
+        weight = jnp.where(prob >= top[..., -1:], prob, 0.0) / jnp.sum(
+            top, axis=-1, keepdims=True)
+        y = 0.0
+        for e in range(count):
+            out = (jax.nn.silu(u @ p["W_gate"][e]) * (u @ p["W_up"][e])
+                   ) @ p["W_down"][e]
+            y = y + weight[..., first + e, None] * out
+        return y
+
+    def run(fn, p, u):
+        loss = lambda p, u: jnp.sum(fn(p, u).astype(jnp.float32) * cot)
+        g = jax.jit(jax.grad(loss, argnums=(0, 1)))(p, u)
+        return (jax.jit(fn)(p, u), g[1], g[0]["W_r"], g[0]["W_gate"],
+                g[0]["W_up"], g[0]["W_down"])
+
+    cast = lambda t: jax.tree.map(lambda a: a.astype(dtype), t)
+    # both sides route the same rounded numbers, so that a token changes
+    # experts on neither
+    params, u = jax.tree.map(lambda a: a.astype(dtype).astype(jnp.float32),
+                             (params, u))
+    with jax.default_matmul_precision("highest"):
+        ref = run(plain, params, u)
+        got = run(lambda p, u: kernel(p, u)[0], cast(params), cast(u))
+        assigned = jax.jit(kernel)(cast(params), cast(u))[1]["assigned"]
+        prob = jax.nn.softmax(u @ params["W_r"], axis=-1)
+        held = jax.lax.top_k(prob, K)[1]
+        want = int(jnp.sum((held >= first) & (held < first + count)))
+    name = f"routed experts {count} of {E} held, {K} a token, N={N} {dtype}"
+    check(int(assigned.sum()) == want, f"{name}: {int(assigned.sum())} "
+          f"assignments multiplied of {want} made")
+    rec = _rel_errors("y du dW_r dW_gate dW_up dW_down", got, ref)
+    rec["assigned"] = int(assigned.sum())
+    tol = 4e-2 if dtype == "bfloat16" else 1e-3
+    check(max(v for k, v in rec.items() if k != "assigned") < tol,
+          f"{name}: layer against the loop over experts {rec} (tol "
+          f"{tol:.0e} of the largest entry)")
+    return rec
+
+
 def selective_scan_parity(B, T, D, N, dtype) -> dict:
     """The selective scan by both paths, the Pallas kernels and the chunked
     XLA form, against the token-by-token one on the same inputs (``x`` in
@@ -666,6 +777,31 @@ def p3_kernels() -> dict:
     say("P3 kernels: selective scan, kernels and chunked form against token "
         f"by token, error over the largest entry "
         f"{rec['selective_scan_rel_err']}")
+
+    # the sparse cell's shapes (four of its 32 heads: the plain side keeps
+    # every score; its sixteen held of 128 experts at the cell's widths),
+    # then a padded length and a range that starts further on, in float32
+    shapes = ((1, 2, 300, 16, 40), (2, 2, 100, 16, 24)) if DRY else (
+        (1, 4, 8192, 128, 2048), (2, 2, 1100, 128, 512))
+    rec["selected_attention_rel_err"] = {
+        f"{shapes[0]} bfloat16": selected_attention_parity(
+            *shapes[0], dtype="bfloat16"),
+        f"{shapes[1]} float32": selected_attention_parity(
+            *shapes[1], dtype="float32")}
+    say("P3 kernels: flash kernels under a selection against the "
+        "written-out mask, error over the largest entry "
+        f"{rec['selected_attention_rel_err']}")
+    shapes = ((64, 32, 16, 16, 4, 0, 4), (50, 32, 16, 16, 4, 8, 4)) \
+        if DRY else ((8192, 2048, 768, 128, 8, 0, 16),
+                     (1100, 256, 128, 128, 8, 48, 16))
+    rec["routed_experts_rel_err"] = {
+        f"{shapes[0]} bfloat16": routed_experts_parity(
+            *shapes[0], dtype="bfloat16"),
+        f"{shapes[1]} float32": routed_experts_parity(
+            *shapes[1], dtype="float32")}
+    say("P3 kernels: routed experts, grouped products against the loop "
+        "over experts, error over the largest entry "
+        f"{rec['routed_experts_rel_err']}")
 
     hidden, T, K, B = (32, 8, 16, 4) if DRY else (256, 64, 96, 32)
     lstm = MultiLayerNetwork(

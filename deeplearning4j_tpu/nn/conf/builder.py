@@ -51,7 +51,8 @@ _RNN_LAYERS = {"LSTM", "GravesLSTM", "GravesBidirectionalLSTM", "SimpleRnn",
                "TiedRnnOutputLayer", "GatedDeltaNetLayer",
                "QKNormAttentionLayer", "KeyValueProjectionLayer",
                "DifferentialAttentionLayer", "SelectiveScanLayer",
-               "GatedMemoryUnitLayer"}
+               "GatedMemoryUnitLayer", "SparseIndexerLayer",
+               "GroupedQueryAttentionLayer"}
 _IDS_LAYERS = {"TokenEmbeddingLayer"}
 _ANY_LAYERS = {"BatchNormalization", "GlobalPoolingLayer", "ActivationLayer",
                "DropoutLayer", "LossLayer", "ReshapeLayer", "PermuteLayer",
@@ -59,7 +60,8 @@ _ANY_LAYERS = {"BatchNormalization", "GlobalPoolingLayer", "ActivationLayer",
                # between attention blocks must keep its rnn-typed input
                # (an auto Rnn->FF preprocessor here would strip the time
                # axis the transformer's residual stream carries)
-               "LayerNormalization", "RMSNorm", "GatedFeedForwardLayer"}
+               "LayerNormalization", "RMSNorm", "GatedFeedForwardLayer",
+               "RoutedExpertsLayer"}
 
 
 def expected_input_kind(layer: BaseLayerConf) -> str:

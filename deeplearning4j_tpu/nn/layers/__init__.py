@@ -59,15 +59,18 @@ from deeplearning4j_tpu.nn.layers.shape import (  # noqa: F401
 from deeplearning4j_tpu.nn.layers.variational import VariationalAutoencoder  # noqa: F401
 from deeplearning4j_tpu.nn.layers.attention import (  # noqa: F401
     DifferentialAttentionLayer,
+    GroupedQueryAttentionLayer,
     KeyValueProjectionLayer,
     QKNormAttentionLayer,
     SelfAttentionLayer,
+    SparseIndexerLayer,
 )
 from deeplearning4j_tpu.nn.layers.embedding import (  # noqa: F401
     PositionalEmbeddingLayer,
     TiedRnnOutputLayer,
     TokenEmbeddingLayer,
 )
+from deeplearning4j_tpu.nn.layers.experts import RoutedExpertsLayer  # noqa: F401
 from deeplearning4j_tpu.nn.layers.feedforward import GatedFeedForwardLayer  # noqa: F401
 from deeplearning4j_tpu.nn.layers.linear_attention import GatedDeltaNetLayer  # noqa: F401
 from deeplearning4j_tpu.nn.layers.state_space import (  # noqa: F401

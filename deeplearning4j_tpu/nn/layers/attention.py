@@ -249,11 +249,15 @@ class SelfAttentionLayer(BaseLayerConf):
         B, H, T, D = out.shape
         return out.transpose(0, 2, 1, 3).reshape(B, T, H * D)
 
-    def _attend_heads(self, q, k, v, mask, window: Optional[int] = None):
+    def _attend_heads(self, q, k, v, mask, window: Optional[int] = None,
+                      select: Optional[Array] = None):
         """Softmax attention of ``q, k [B, H, T, D]`` and ``v [B, H, T,
         Dv]``: the Pallas flash kernel where its shape gate allows, else the
         blockwise or the plain XLA path (the plain one alone knows a window
-        and a value wider than its key)."""
+        and a value wider than its key). ``select [B, T, T]``: the keys
+        each query reads (causal; no key mask on its XLA path,
+        ``attention_selected``); a refusal of the gate is then counted
+        under ``kernel="flash_select"``."""
         # helper seam (the cuDNN-discovery analog, like the fused LSTM):
         # MXU-native flash attention when the Pallas kernel applies
         from deeplearning4j_tpu.ops.pallas_attention import (
@@ -262,14 +266,18 @@ class SelfAttentionLayer(BaseLayerConf):
         amode = attention_mode()
         plain = window is None and v.shape[-1] == q.shape[-1]
         use_flash = amode != "off" and flash_ok(
-            q.shape[2], max(q.shape[-1], v.shape[-1]), q.dtype.itemsize)
+            q.shape[2], max(q.shape[-1], v.shape[-1]), q.dtype.itemsize,
+            selected=select is not None)
         if amode != "off" and not use_flash:
-            count_gate_fallback(self, "flash_attention")
+            count_gate_fallback(self, "flash_attention" if select is None
+                                else "flash_select")
         if use_flash:
             return flash_attention(q, k, v, causal=self.causal,
                                    kv_mask=mask,
                                    interpret=amode == "interpret",
-                                   window=window)
+                                   window=window, select=select)
+        if select is not None:
+            return attention_selected(q, k, v, select)
         if self.use_blockwise and plain:
             out, _, lse = blockwise_attention(q, k, v, block_size=self.block_size,
                                               causal=self.causal, kv_mask=mask)
@@ -525,6 +533,282 @@ class DifferentialAttentionLayer(SelfAttentionLayer):
             out = out * (wide("gamma") * (1.0 - self.lambda_init))
             out = out.transpose(0, 2, 1, 3).reshape(B, T, H * D)
         out = out.astype(u.dtype) @ params["Wo"] + params["bo"]
+        if mask is not None:
+            out = out * mask[..., None]
+        return out, state
+
+
+# ---------------------------------------------------------------------------
+# rotary positions, a learned selection of keys, grouped-query attention
+# ---------------------------------------------------------------------------
+
+def rotary(x: Array, positions: Array, theta: float) -> Array:
+    """``x [..., T, D]`` rotated by position (Su et al., arXiv:2104.09864),
+    the half-split form: entry ``i < D / 2`` pairs with entry ``i + D / 2``
+    and the pair turns by ``positions[t] * theta ** (-2 i / D)``. Angles,
+    sines and the rotation in float32, the result in ``x``'s dtype.
+    ``positions [T]``: ``arange(T)`` in training; an argument, so that a
+    cache can hand the positions it is at."""
+    half = x.shape[-1] // 2
+    wide = jnp.promote_types(x.dtype, jnp.float32)
+    freq = theta ** (-jnp.arange(half, dtype=wide) / half)
+    angle = positions.astype(wide)[:, None] * freq          # [T, D / 2]
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    a, b = x[..., :half].astype(wide), x[..., half:].astype(wide)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def query_chunks(a: Array, axis: int, size: int) -> Array:
+    """``a`` cut along its query ``axis`` into chunks of ``size`` (the last
+    padded with zeros), the chunks in front: ``[n, ..., size, ...]``, for a
+    ``lax.map`` over them."""
+    pad = -a.shape[axis] % size
+    widths = [(0, 0)] * a.ndim
+    widths[axis] = (0, pad)
+    a = jnp.pad(a, widths)
+    a = a.reshape(a.shape[:axis] + (-1, size) + a.shape[axis + 1:])
+    return jnp.moveaxis(a, axis, 0)
+
+
+def top_keys(scores: Array, first: Array, topk: int) -> Array:
+    """For each query row of ``scores [..., Q, T]`` (query ``first + i`` in
+    row ``i``) the ``topk`` keys ``s <= t`` of largest score, all of them
+    while ``t < topk``, as int8 ``[..., Q, T]``; of equal scores the lower
+    ``s`` first. The ``topk``-th largest score of a row is its threshold
+    (``lax.top_k``, which the v5e's compiler lowers to a sort of the row:
+    30.2 ms for 8,192 rows of 8,192 where a sort of the values alone took
+    34.7, PERF.md PR 35): what lies above is in, and of what equals it the
+    first as many as are still wanted (a running count along the row)."""
+    Q, T = scores.shape[-2:]
+    t = first + jnp.arange(Q)[:, None]
+    seen = jnp.arange(T)[None, :] <= t
+    scores = jnp.where(seen, scores, -jnp.inf)
+    if topk >= T:
+        return jnp.broadcast_to(seen, scores.shape).astype(jnp.int8)
+    edge = jax.lax.top_k(scores, topk)[0][..., -1:]
+    above = scores > edge
+    level = scores == edge
+    wanted = topk - jnp.sum(above, axis=-1, keepdims=True)
+    among = jnp.cumsum(level.astype(jnp.int32), axis=-1)
+    return (seen & (above | (level & (among <= wanted)))).astype(jnp.int8)
+
+
+@register_layer
+@dataclass
+class SparseIndexerLayer(BaseLayerConf):
+    """The learned indexer of a sparse attention layer (DeepSeek-V3.2-Exp's
+    "lightning indexer") as a node of its own: from the block's normed input
+    ``u [B, T, F]`` to the selection ``[B, T, T]`` int8, nonzero where query
+    ``t`` reads key ``s``::
+
+        qI_t = rotary(W_q u_t)  as n_heads heads of head_dim
+        kI_s = rotary(LayerNorm(W_k u_s))  one head
+        I_ts = sum_j (W_w u_t)_j ReLU(qI_tj . kI_s)
+        S_t  = the topk keys s <= t of largest I_ts (all while t < topk)
+
+    The index scores are float32 and are made ``query_chunk`` queries at a
+    time (``[n_heads, query_chunk, T]`` a turn; the whole ``[n_heads, T,
+    T]`` is in no buffer). The selection is discrete: no gradient passes
+    it, so the layer is ``frozen`` by default and a language-model loss
+    leaves its leaves where they were. The attention layer reads the
+    selection as its second input, and under remat keeps it (``T^2`` bytes)
+    instead of selecting again in the backward.
+
+    Params: ``Wq [F, n_heads head_dim]``, ``Wk [F, head_dim]``, ``k_gamma,
+    k_beta [head_dim]``, ``Ww [F, n_heads]``."""
+    n_heads: int = 16
+    head_dim: int = 64
+    topk: int = 2048
+    rope_theta: float = 10000.0
+    norm_eps: float = 1e-6
+    query_chunk: int = 512
+    frozen: bool = True
+
+    def set_n_in(self, in_type: InputType) -> None:
+        if in_type.kind != "rnn":
+            raise ValueError(
+                f"SparseIndexerLayer expects RNN input, got {in_type}")
+        self.n_in = in_type.size
+
+    def infer_output_type(self, in_type: InputType) -> InputType:
+        # a row of the selection a query: as many entries as there are keys
+        return InputType.recurrent(in_type.timesteps or 0, in_type.timesteps)
+
+    def propagate_mask(self, mask):
+        return None
+
+    def param_order(self) -> List[str]:
+        return ["Wq", "Wk", "k_gamma", "k_beta", "Ww"]
+
+    def init_params(self, rng, dtype=jnp.float32) -> Params:
+        F, H, D = self.n_in, self.n_heads, self.head_dim
+        ks = jax.random.split(rng, 3)
+        return {"Wq": self._init_w(ks[0], (F, H * D), F, H * D, dtype),
+                "Wk": self._init_w(ks[1], (F, D), F, D, dtype),
+                "k_gamma": jnp.ones((D,), dtype),
+                "k_beta": jnp.zeros((D,), dtype),
+                "Ww": self._init_w(ks[2], (F, H), F, H, dtype)}
+
+    def index_parts(self, params, u, positions):
+        """``(qI [B, H, T, D], kI [B, T, D], w [B, T, H])``, float32."""
+        B, T, _ = u.shape
+        H, D = self.n_heads, self.head_dim
+        f32 = jnp.promote_types(u.dtype, jnp.float32)
+        with jax.named_scope("attn:rope"):
+            q = (u @ params["Wq"]).reshape(B, T, H, D).transpose(0, 2, 1, 3)
+            q = rotary(q.astype(f32), positions, self.rope_theta)
+            k = (u @ params["Wk"]).astype(f32)
+            mean = jnp.mean(k, axis=-1, keepdims=True)
+            var = jnp.mean((k - mean) ** 2, axis=-1, keepdims=True)
+            k = ((k - mean) * jax.lax.rsqrt(var + self.norm_eps)
+                 * params["k_gamma"].astype(f32)
+                 + params["k_beta"].astype(f32))
+            k = rotary(k, positions, self.rope_theta)
+        return q, k, (u @ params["Ww"]).astype(f32)
+
+    def apply(self, params, x, *, state, train, rng, mask=None):
+        from deeplearning4j_tpu.profiling.metrics import get_registry
+        u = jax.lax.stop_gradient(x)
+        params = jax.lax.stop_gradient(params)
+        B, T, _ = u.shape
+        q, k, w = self.index_parts(params, u, jnp.arange(T))
+        C = min(self.query_chunk, T)
+        qc, wc = query_chunks(q, 2, C), query_chunks(w, 1, C)
+        get_registry().labeled_counter(
+            "sparse_select_traces_total",
+            "selections of a sparse attention layer's keys by the path "
+            "that makes them (per trace)",
+        ).labels(path="all" if self.topk >= T else "top_k").inc()
+
+        def chunk(args):
+            q_i, w_i, first = args          # [B, H, C, D], [B, C, H]
+            with jax.named_scope("dsa:index"):
+                hits = jax.nn.relu(jnp.einsum(
+                    "bhqd,bkd->bhqk", q_i, k,
+                    preferred_element_type=jnp.float32))
+                # sixteen weighted maps summed where they are made, in
+                # float32 (a product over 16 would round them)
+                scores = jnp.sum(
+                    hits * w_i.transpose(0, 2, 1)[..., None], axis=1)
+            with jax.named_scope("dsa:topk"):
+                return top_keys(scores, first, self.topk)
+
+        sel = jax.lax.map(chunk, (qc, wc, jnp.arange(len(qc)) * C))
+        sel = jnp.moveaxis(sel, 0, 1).reshape(B, -1, T)[:, :T]  # [n,B,C,T]
+        return sel, state
+
+
+def attention_selected(q: Array, k: Array, v: Array, select: Array,
+                       chunk: int = 512) -> Array:
+    """Causal softmax attention of ``q, k, v [B, H, T, D]`` over the keys
+    ``select [B, T, T]`` names, in plain XLA, ``chunk`` queries at a time
+    (``[B, H, chunk, T]`` of scores a turn). A query with no key gives
+    zeros. The path of "off", of float64 and of shapes the flash kernels'
+    gate refuses."""
+    B, H, T, D = q.shape
+    C = min(chunk, T)
+    qc, sc = query_chunks(q, 2, C), query_chunks(select, 1, C)
+    scale = 1.0 / math.sqrt(D)
+
+    def rows(args):
+        q_i, s_i, first = args
+        t = first + jnp.arange(C)[:, None]
+        seen = (s_i != 0) & (jnp.arange(T)[None, :] <= t)     # [B, C, T]
+        logits = jnp.einsum("bhqd,bhkd->bhqk", q_i, k,
+                            preferred_element_type=jnp.float32) * scale
+        logits = jnp.where(seen[:, None], logits, NEG_INF)
+        p = jax.nn.softmax(logits, axis=-1)
+        p = jnp.where(jnp.any(seen, axis=-1)[:, None, :, None], p, 0.0)
+        return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
+
+    out = jax.lax.map(jax.checkpoint(rows),
+                      (qc, sc, jnp.arange(len(qc)) * C))
+    return jnp.moveaxis(out, 0, 2).reshape(B, H, -1, D)[:, :, :T]
+
+
+@register_layer
+@dataclass
+class GroupedQueryAttentionLayer(SelfAttentionLayer):
+    """Causal grouped-query attention with rotary positions and a norm by
+    head, over two inputs: the stream's ``u [B, T, F]`` and the selection
+    ``[B, T, T]`` of a ``SparseIndexerLayer`` (query ``t`` reads the keys
+    ``s`` its row names and no others)::
+
+        q = rotary(RMSNorm_D(W_q u) g_q)   n_heads heads of head_dim
+        k = rotary(RMSNorm_D(W_k u) g_k)   n_kv_heads heads
+        o_th = sum_{s in S_t} softmax_{s in S_t}(q_th . k_s / sqrt(D)) v_s
+        y = W_o concat_h o_h
+
+    with query head ``h`` reading key/value head ``h // (n_heads /
+    n_kv_heads)`` and one gain of ``head_dim`` each for q and k. No bias.
+    The selection is an operand of the flash kernels (``flash_attention(
+    ..., select=)``) behind ``SelfAttentionLayer``'s seam; where their
+    gate refuses, or the mode is off, the selected attention runs in XLA
+    in query chunks (``attention_selected``), a refusal counted under
+    ``kernel="flash_select"``.
+    Trains; no incremental decode, no sequence-parallel ring.
+
+    Params: ``Wq [F, H D]``, ``Wk, Wv [F, G D]``, ``q_gamma, k_gamma [D]``,
+    ``Wo [H D, F]``."""
+    n_kv_heads: int = 0         # default n_heads
+    rope_theta: float = 10000.0
+    norm_eps: float = 1e-6
+    causal: bool = True
+    sequence_parallel: bool = False
+
+    N_INPUTS = 2
+    supports_kv_cache = False
+
+    def set_n_in(self, in_type: InputType) -> None:
+        super().set_n_in(in_type)
+        if not self.n_kv_heads:
+            self.n_kv_heads = self.n_heads
+        if self.n_heads % self.n_kv_heads or self.head_dim % 2:
+            raise ValueError(
+                f"GroupedQueryAttentionLayer({self.name!r}): n_heads a "
+                f"multiple of n_kv_heads and an even head_dim, got "
+                f"{self.n_heads}, {self.n_kv_heads}, {self.head_dim}")
+
+    def set_side_inputs(self, in_types) -> None:
+        (sel,) = in_types
+        if sel.kind != "rnn":
+            raise ValueError(
+                f"GroupedQueryAttentionLayer({self.name!r}): a selection "
+                f"[B, T, T] expected as second input, got {sel}")
+
+    def param_order(self) -> List[str]:
+        return ["Wq", "Wk", "Wv", "q_gamma", "k_gamma", "Wo"]
+
+    def init_params(self, rng, dtype=jnp.float32) -> Params:
+        F, D = self.n_in, self.head_dim
+        HD, GD = self.n_heads * D, self.n_kv_heads * D
+        ks = jax.random.split(rng, 4)
+        return {"Wq": self._init_w(ks[0], (F, HD), F, HD, dtype),
+                "Wk": self._init_w(ks[1], (F, GD), F, GD, dtype),
+                "Wv": self._init_w(ks[2], (F, GD), F, GD, dtype),
+                "q_gamma": jnp.ones((D,), dtype),
+                "k_gamma": jnp.ones((D,), dtype),
+                "Wo": self._init_w(ks[3], (HD, F), HD, F, dtype)}
+
+    def apply(self, params, x, *, state, train, rng, mask=None):
+        u, select = x
+        u = self._dropout_input(u, train, rng)
+        B, T, _ = u.shape
+        H, G, D = self.n_heads, self.n_kv_heads, self.head_dim
+        heads = lambda a, n: a.reshape(B, T, n, D).transpose(0, 2, 1, 3)
+        with jax.named_scope("attn:rope"):
+            turned = lambda a, g: rotary(
+                (rms_normalize(a, self.norm_eps)
+                 * params[g].astype(jnp.promote_types(a.dtype, jnp.float32))
+                 ).astype(u.dtype), jnp.arange(T), self.rope_theta)
+            q = turned(heads(u @ params["Wq"], H), "q_gamma")
+            k = turned(heads(u @ params["Wk"], G), "k_gamma")
+        v = heads(u @ params["Wv"], G)
+        # query head h reads key/value head h // (H / G)
+        k, v = (jnp.repeat(a, H // G, axis=1) for a in (k, v))
+        out = self._attend_heads(q, k, v, mask, select=select)
+        out = out.transpose(0, 2, 1, 3).reshape(B, T, H * D) @ params["Wo"]
         if mask is not None:
             out = out * mask[..., None]
         return out, state

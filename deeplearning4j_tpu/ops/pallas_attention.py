@@ -40,7 +40,19 @@ window reaches, dk/dv stops after the last block of queries that see its
 keys, so at a block of 512 and a window of 512 a grid step makes two tiles
 and not up to seventeen. With ``window=None`` the three kernels lower to
 what they were before the option (PERF.md, PR 33).
-``pallas_flash_traces_total{operands=..., window=...}`` counts the traces.
+``pallas_flash_traces_total{operands=..., window=..., select=...}`` counts
+the traces.
+
+A ``select`` operand (``[B, T, T]``, nonzero where query ``t`` of a batch
+row reads key ``s``: the keys a learned indexer chose, shared by the row's
+heads) is one more select on the float32 scores of all three kernels. It
+reaches the forward and dq as int8 rows by query block (``[B, Tp]`` a grid
+step, each turn a ``[B, B]`` tile of it) and dk/dv as the same rows of its
+transpose, made once outside the kernels, because dk/dv's tiles are
+transposed. A query none of whose keys is selected is a fully masked row
+(zero output, zero gradients). No tile is skipped: the loops keep the
+causal bounds. With ``select=None`` the three kernels lower to what they
+were before the operand (PERF.md, PR 35).
 
 Shapes: q, k are [B, H, T, D] and v [B, H, T, Dv] (self-attention: same
 T; ``Dv`` may differ, all three are padded to one lane width). The kernel
@@ -68,6 +80,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -114,16 +127,20 @@ def _block(Tp: int) -> int:
     return next(b for b in (512, 256, _BLK) if Tp % b == 0)
 
 
-def _vmem_bytes(Tp: int, Dp: int, itemsize: int) -> int:
+def _vmem_bytes(Tp: int, Dp: int, itemsize: int,
+                selected: bool = False) -> int:
     B = _block(Tp)
     panels = 2 * Tp * Dp * itemsize
     blocks = 4 * B * Dp * itemsize
     vectors = (2 * B * _BLK + 2 * 8 * Tp) * 4
     tiles = (8 * B * B + 4 * B * Dp) * 4
-    return 2 * (panels + blocks + vectors) + tiles
+    # a selection's int8 rows, [B, Tp] a grid step, and one more tile
+    rows = B * Tp + 2 * B * B if selected else 0
+    return 2 * (panels + blocks + vectors + rows) + tiles
 
 
-def flash_vmem_bytes(T: int, D: int = 128, itemsize: int = 4) -> int:
+def flash_vmem_bytes(T: int, D: int = 128, itemsize: int = 4,
+                     selected: bool = False) -> int:
     """VMEM the largest of the three kernels asks for, counting what
     Pallas allocates, with B the block of the padded length. Every
     BlockSpec operand is double-buffered and has the inputs' ``itemsize``:
@@ -135,22 +152,27 @@ def flash_vmem_bytes(T: int, D: int = 128, itemsize: int = 4) -> int:
     to 8 sublanes (dk/dv's lse and rowsum(dO*O); the bias row elsewhere).
     The loop body's tiles are float32 too, and single: eight of [B, B]
     (s, p, dp, ds, the positions, the broadcast bias and vectors) and
-    four of [B, Dp] (the accumulators and their updates)."""
-    return _vmem_bytes(_padded_len(T), _round_up(D, _BLK), itemsize)
+    four of [B, Dp] (the accumulators and their updates). ``selected``:
+    the int8 ``[B, Tp]`` rows of a selection, double-buffered too, and
+    the tile of them a turn widens."""
+    return _vmem_bytes(_padded_len(T), _round_up(D, _BLK), itemsize,
+                       selected)
 
 
-def flash_ok(T: int, D: int = 128, itemsize: int = 4) -> bool:
+def flash_ok(T: int, D: int = 128, itemsize: int = 4,
+             selected: bool = False) -> bool:
     """Shape gate: the kernels keep whole-sequence panels on-chip, so a
     long T (or a very wide head) must go to the XLA path, not die in
     Mosaic. Counts :func:`flash_vmem_bytes` against ``VMEM_GATE_BYTES``."""
-    return flash_vmem_bytes(T, D, itemsize) <= VMEM_GATE_BYTES
+    return flash_vmem_bytes(T, D, itemsize, selected) <= VMEM_GATE_BYTES
 
 
-def _params(Tp: int, Dp: int, itemsize: int):
+def _params(Tp: int, Dp: int, itemsize: int, selected: bool = False):
     # no carry between grid steps in any of the three kernels
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel"),
-        vmem_limit_bytes=vmem_limit(_vmem_bytes(Tp, Dp, itemsize)))
+        vmem_limit_bytes=vmem_limit(_vmem_bytes(Tp, Dp, itemsize,
+                                                selected)))
 
 
 def _dot(a, b, contract_b: int = 0):
@@ -179,6 +201,12 @@ def _lanes(x, B: int):
     return jnp.concatenate([x] * (B // _BLK), axis=1)
 
 
+def _chosen(sel_ref, j, B: int):
+    """The ``[B, B]`` tile of a selection's rows at block ``j`` of the lane
+    axis, as a predicate."""
+    return sel_ref[0, :, _blk_slice(j, B)].astype(jnp.int32) != 0
+
+
 # ---------------------------------------------------------------------------
 # forward kernel
 # ---------------------------------------------------------------------------
@@ -197,8 +225,10 @@ def _last_query_block(ki, B: int, window: int, n_blocks: int):
     return jnp.minimum(((ki + 1) * B + window - 2) // B + 1, n_blocks)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
+def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, *rest,
                 causal: bool, scale: float, window: Optional[int] = None):
+    # with a selection its rows come before the outputs
+    *sel_ref, o_ref, lse_ref = rest
     # MXU operands (q, k, v, and p below) keep the input dtype; the
     # scale multiplies the float32 scores, never a bfloat16 q
     q = q_ref[0]                                      # [B, Dp]
@@ -217,6 +247,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
             s = jnp.where(k_pos <= q_pos, s, NEG_INF)
             if window is not None:
                 s = jnp.where(q_pos - k_pos < window, s, NEG_INF)
+        if sel_ref:
+            s = jnp.where(_chosen(sel_ref[0], j, B), s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
@@ -248,11 +280,22 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
     lse_ref[0] = jnp.broadcast_to(lse, (B, _BLK))
 
 
-def _run_fwd(q, k, v, bias, causal, interpret, scale, window=None):
-    """q,k,v: [G, Tp, Dp]; bias: [G, 1, Tp] additive (0 / NEG_INF).
-    Returns (out [G, Tp, Dp], lse [G, Tp, _BLK] lane-replicated)."""
+def _select_spec(select, G: int, B: int, Tp: int):
+    """The ``[B, Tp]`` rows of a ``[batch, Tp, Tp]`` selection at a grid
+    step: kernel head ``g`` belongs to batch row ``g // heads``."""
+    heads = G // select.shape[0]
+    return pl.BlockSpec((1, B, Tp), lambda g, i: (g // heads, i, 0))
+
+
+def _run_fwd(q, k, v, bias, causal, interpret, scale, window=None,
+             select=None):
+    """q,k,v: [G, Tp, Dp]; bias: [G, 1, Tp] additive (0 / NEG_INF);
+    ``select`` None or ``(rows, transposed rows)``, int8 ``[batch, Tp,
+    Tp]`` each. Returns (out [G, Tp, Dp], lse [G, Tp, _BLK]
+    lane-replicated)."""
     G, Tp, Dp = q.shape
     B = _block(Tp)
+    chosen = () if select is None else (select[0],)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, causal=causal, scale=scale,
                           window=window),
@@ -262,7 +305,7 @@ def _run_fwd(q, k, v, bias, causal, interpret, scale, window=None):
             pl.BlockSpec((1, Tp, Dp), lambda g, i: (g, 0, 0)),
             pl.BlockSpec((1, Tp, Dp), lambda g, i: (g, 0, 0)),
             pl.BlockSpec((1, 1, Tp), lambda g, i: (g, 0, 0)),
-        ],
+        ] + [_select_spec(x, G, B, Tp) for x in chosen],
         out_specs=[
             pl.BlockSpec((1, B, Dp), lambda g, i: (g, i, 0)),
             pl.BlockSpec((1, B, _BLK), lambda g, i: (g, i, 0)),
@@ -271,10 +314,10 @@ def _run_fwd(q, k, v, bias, causal, interpret, scale, window=None):
             jax.ShapeDtypeStruct((G, Tp, Dp), q.dtype),
             jax.ShapeDtypeStruct((G, Tp, _BLK), jnp.float32),
         ],
-        compiler_params=_params(Tp, Dp, q.dtype.itemsize),
+        compiler_params=_params(Tp, Dp, q.dtype.itemsize, bool(chosen)),
         interpret=interpret,
         name="flash_attention_fwd",
-    )(q, k, v, bias)
+    )(q, k, v, bias, *chosen)
 
 
 # ---------------------------------------------------------------------------
@@ -282,8 +325,9 @@ def _run_fwd(q, k, v, bias, causal, interpret, scale, window=None):
 # ---------------------------------------------------------------------------
 
 def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, dvec_ref,
-               dq_ref, *, causal: bool, scale: float,
+               *rest, causal: bool, scale: float,
                window: Optional[int] = None):
+    *sel_ref, dq_ref = rest
     q = q_ref[0]                                      # [B, Dp]
     do = do_ref[0]
     B = q.shape[0]
@@ -302,6 +346,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, dvec_ref,
             s = jnp.where(k_pos <= q_pos, s, NEG_INF)
             if window is not None:
                 s = jnp.where(q_pos - k_pos < window, s, NEG_INF)
+        if sel_ref:
+            s = jnp.where(_chosen(sel_ref[0], j, B), s, NEG_INF)
         # fully-masked query rows (zero valid keys) carry lse == NEG_INF
         # from the forward; exp(s - lse) there is garbage (float
         # absorption, not inf) — gate them to zero probability so the
@@ -318,14 +364,16 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, dvec_ref,
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, dvec_ref,
-                dk_ref, dv_ref, *, causal: bool, scale: float,
+                *rest, causal: bool, scale: float,
                 window: Optional[int] = None):
     """One grid step holds B keys and walks the queries in blocks of B, on
     TRANSPOSED score tiles ``S^T = K Q^T``: keys along the sublanes,
     queries along the lanes. ``P^T dO`` and ``dS^T Q`` are then plain
     products (no tile is transposed on its way to the MXU), and the
     per-query lse and rowsum(dO*O) are [1, B] rows that broadcast over
-    the sublanes."""
+    the sublanes. A selection comes transposed, keys by queries, as the
+    tiles are."""
+    *sel_ref, dk_ref, dv_ref = rest
     kblk = k_ref[0]                                   # [B, Dp]
     vblk = v_ref[0]
     B = kblk.shape[0]
@@ -346,6 +394,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, dvec_ref,
             st = jnp.where(k_pos <= q_pos, st, NEG_INF)
             if window is not None:
                 st = jnp.where(q_pos - k_pos < window, st, NEG_INF)
+        if sel_ref:
+            st = jnp.where(_chosen(sel_ref[0], i, B), st, NEG_INF)
         # same masked-row gate as _dq_kernel: queries with lse == NEG_INF
         # (no valid key) must contribute zero to dk/dv
         pt = jnp.where(lse > NEG_INF / 2, jnp.exp(st - lse), 0.0)
@@ -368,9 +418,11 @@ def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, dvec_ref,
 
 
 def _run_bwd(q, k, v, bias, do, out, lse, causal, interpret, scale,
-             window=None):
+             window=None, select=None):
     G, Tp, Dp = q.shape
     B = _block(Tp)
+    rows, columns = ((), ()) if select is None else (
+        (select[0],), (select[1],))
     dvec = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                    axis=-1, keepdims=True)             # [G, Tp, 1]
     blkspec = pl.BlockSpec((1, B, Dp), lambda g, i: (g, i, 0))
@@ -378,19 +430,19 @@ def _run_bwd(q, k, v, bias, do, out, lse, causal, interpret, scale,
     vecspec = pl.BlockSpec((1, B, _BLK), lambda g, i: (g, i, 0))
     colspec = pl.BlockSpec((1, B, 1), lambda g, i: (g, i, 0))
     fullrow = pl.BlockSpec((1, 1, Tp), lambda g, i: (g, 0, 0))
-    params = _params(Tp, Dp, q.dtype.itemsize)
+    params = _params(Tp, Dp, q.dtype.itemsize, select is not None)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, causal=causal, scale=scale,
                           window=window),
         grid=(G, Tp // B),
         in_specs=[blkspec, fullspec, fullspec, fullrow, blkspec, vecspec,
-                  vecspec],
+                  vecspec] + [_select_spec(x, G, B, Tp) for x in rows],
         out_specs=blkspec,
         out_shape=jax.ShapeDtypeStruct((G, Tp, Dp), q.dtype),
         compiler_params=params,
         interpret=interpret,
         name="flash_attention_dq",
-    )(q, k, v, bias, do, lse, jnp.broadcast_to(dvec, (G, Tp, _BLK)))
+    )(q, k, v, bias, do, lse, jnp.broadcast_to(dvec, (G, Tp, _BLK)), *rows)
     # dk/dv read the per-query vectors as [1, Tp] rows and the keys' bias
     # as a [B, 1] column
     dk, dv = pl.pallas_call(
@@ -398,7 +450,7 @@ def _run_bwd(q, k, v, bias, do, out, lse, causal, interpret, scale,
                           window=window),
         grid=(G, Tp // B),
         in_specs=[fullspec, blkspec, blkspec, colspec, fullspec, fullrow,
-                  fullrow],
+                  fullrow] + [_select_spec(x, G, B, Tp) for x in columns],
         out_specs=[blkspec, blkspec],
         out_shape=[jax.ShapeDtypeStruct((G, Tp, Dp), k.dtype),
                    jax.ShapeDtypeStruct((G, Tp, Dp), v.dtype)],
@@ -406,7 +458,7 @@ def _run_bwd(q, k, v, bias, do, out, lse, causal, interpret, scale,
         interpret=interpret,
         name="flash_attention_dkv",
     )(q, k, v, bias.reshape(G, Tp, 1), do, lse[:, :, 0].reshape(G, 1, Tp),
-      dvec.reshape(G, 1, Tp))
+      dvec.reshape(G, 1, Tp), *columns)
     return dq, dk, dv
 
 
@@ -414,49 +466,60 @@ def _run_bwd(q, k, v, bias, do, out, lse, causal, interpret, scale,
 # differentiable core + public entry
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash_core(q, k, v, bias, causal, interpret, scale, window):
-    out, _ = _run_fwd(q, k, v, bias, causal, interpret, scale, window)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _flash_core(q, k, v, bias, select, causal, interpret, scale, window):
+    out, _ = _run_fwd(q, k, v, bias, causal, interpret, scale, window,
+                      select)
     return out
 
 
-def _flash_core_fwd(q, k, v, bias, causal, interpret, scale, window):
-    out, lse = _run_fwd(q, k, v, bias, causal, interpret, scale, window)
-    return out, (q, k, v, bias, out, lse)
+def _flash_core_fwd(q, k, v, bias, select, causal, interpret, scale, window):
+    out, lse = _run_fwd(q, k, v, bias, causal, interpret, scale, window,
+                        select)
+    return out, (q, k, v, bias, select, out, lse)
 
 
 def _flash_core_bwd(causal, interpret, scale, window, res, g):
-    q, k, v, bias, out, lse = res
+    q, k, v, bias, select, out, lse = res
     dq, dk, dv = _run_bwd(q, k, v, bias, g, out, lse, causal, interpret,
-                          scale, window)
-    return dq, dk, dv, jnp.zeros_like(bias)
+                          scale, window, select)
+    # whole numbers take the placeholder cotangent; None has no leaf
+    nothing = jax.tree.map(
+        lambda x: np.zeros(x.shape, jax.dtypes.float0), select)
+    return dq, dk, dv, jnp.zeros_like(bias), nothing
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 
-def _count_trace(dtype, window: Optional[int]) -> None:
+def _count_trace(dtype, window: Optional[int], selected: bool) -> None:
     """Which products a run's kernels make, counted once per trace (not
-    per step) under the operands' dtype and the window's width."""
+    per step) under the operands' dtype, the window's width and whether a
+    selection came."""
     from deeplearning4j_tpu.profiling.metrics import get_registry
     get_registry().labeled_counter(
         "pallas_flash_traces_total",
-        "flash-attention traces by the dtype of the MXU operands and the "
-        "window (per trace)",
+        "flash-attention traces by the dtype of the MXU operands, the "
+        "window and the selection (per trace)",
     ).labels(operands=jnp.dtype(dtype).name,
-             window="none" if window is None else window).inc()
+             window="none" if window is None else window,
+             select="rows" if selected else "none").inc()
 
 
 def flash_attention(q, k, v, *, causal: bool = False,
                     kv_mask: Optional[jnp.ndarray] = None,
                     interpret: bool = False,
-                    window: Optional[int] = None) -> jnp.ndarray:
+                    window: Optional[int] = None,
+                    select: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """softmax(QK^T/sqrt(D))V via the Pallas kernels. q, k: [B,H,T,D], v:
     [B,H,T,Dv] (self-attention: shared T; ``Dv`` may differ from ``D``, as
     where two score maps of head 64 share a value of 128). ``kv_mask``:
     [B, T] key validity. ``window`` (with ``causal``): key ``s`` is seen
     from ``t`` when ``0 <= t - s < window``; the kernels' loops leave out
-    the blocks of keys wholly outside it.
+    the blocks of keys wholly outside it. ``select``: ``[B, T, T]``,
+    nonzero where query ``t`` reads key ``s``, the same for every head of a
+    batch row; a select beside the others, so with ``causal`` a selected
+    key past the query stays unseen.
 
     The products' operands have ``q.dtype`` (k and v are brought to it)
     and their accumulators are float32: bfloat16 inputs reach the MXU as
@@ -468,7 +531,7 @@ def flash_attention(q, k, v, *, causal: bool = False,
         raise ValueError("a window is causal and at least 1 wide, got "
                          f"causal={causal}, window={window}")
     Tp, Dp = _padded_len(T), _round_up(max(D, Dv), _BLK)
-    _count_trace(q.dtype, window)
+    _count_trace(q.dtype, window, select is not None)
 
     def prep(x):
         x = jnp.pad(x.astype(q.dtype), ((0, 0), (0, 0), (0, Tp - T),
@@ -481,6 +544,10 @@ def flash_attention(q, k, v, *, causal: bool = False,
     valid = jnp.pad(valid, ((0, 0), (0, Tp - T)))
     bias = jnp.where(valid > 0, 0.0, NEG_INF).astype(jnp.float32)
     bias = jnp.repeat(bias, H, axis=0)[:, None, :]     # [B*H, 1, Tp]
-    out = _flash_core(qf, kf, vf, bias, causal, interpret,
+    if select is not None:
+        rows = jnp.pad((select != 0).astype(jnp.int8),
+                       ((0, 0), (0, Tp - T), (0, Tp - T)))
+        select = (rows, rows.transpose(0, 2, 1))
+    out = _flash_core(qf, kf, vf, bias, select, causal, interpret,
                       1.0 / math.sqrt(D), window)
     return out.reshape(B, H, Tp, Dp)[:, :, :T, :Dv]

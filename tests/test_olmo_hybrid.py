@@ -215,8 +215,10 @@ def test_bf16_full_attention_through_the_kernel_follows_the_xla_path(
             set_registry(previous)
         assert acts["b3_mix"].dtype == jnp.bfloat16
         traces = registry.labeled_counter("pallas_flash_traces_total")
-        assert traces.labels(operands="float32", window="none").value == 0
-        bf16 = traces.labels(operands="bfloat16", window="none").value
+        assert traces.labels(
+            operands="float32", window="none", select="none").value == 0
+        bf16 = traces.labels(
+            operands="bfloat16", window="none", select="none").value
         assert (bf16 > 0) == (mode == "interpret")
         seen[mode] = (np.asarray(acts["b3_mix"], np.float32), jax.device_get(
             program.first_moment(net.opt_state)))

@@ -289,10 +289,112 @@ def test_flash_traces_are_counted_by_operand_dtype():
         fn(*args)
         fn(*args)
         counted = registry.labeled_counter("pallas_flash_traces_total")
-        assert counted.labels(operands="bfloat16", window="none").value == 1
-        assert counted.labels(operands="float32", window="none").value == 0
+        assert counted.labels(
+            operands="bfloat16", window="none", select="none").value == 1
+        assert counted.labels(
+            operands="float32", window="none", select="none").value == 0
         fn(*_qkv(B=1, H=1, T=16, D=8))
-        assert counted.labels(operands="float32", window="none").value == 1
+        assert counted.labels(
+            operands="float32", window="none", select="none").value == 1
         assert counted.value == 2
     finally:
         set_registry(previous)
+
+
+# ---------------------------------------------------------------------------
+# a selection of keys as an operand (PR 35)
+# ---------------------------------------------------------------------------
+
+def _written_out_selection(q, k, v, select):
+    """The mask written out over [T, T]: key ``s`` is read from ``t`` when
+    ``select[b, t, s]`` is not nought and ``s <= t``; a query with no key
+    gives zeros."""
+    T = q.shape[2]
+    seen = (select != 0)[:, None] & jnp.tril(jnp.ones((T, T), bool))
+    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * q.shape[-1] ** -0.5
+    maps = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
+    maps = jnp.where(jnp.any(seen, -1, keepdims=True), maps, 0.0)
+    return jnp.einsum("bhqk,bhkd->bhqd", maps, v)
+
+
+def _top_selection(key, B, T, topk):
+    """The ``topk`` keys ``s <= t`` of largest random score a query, all of
+    them while ``t < topk``: what an indexer hands the kernels."""
+    from deeplearning4j_tpu.nn.layers.attention import top_keys
+    return top_keys(jax.random.normal(key, (B, T, T)), 0, topk)
+
+
+@pytest.mark.parametrize("T,topk", [(256, 40), (300, 40), (100, 200),
+                                    (640, 128)],
+                         ids=["aligned", "padded", "t_below_topk",
+                              "blocks_of_128"])
+def test_flash_with_a_selection_is_the_written_out_mask(T, topk):
+    """Forward, dq, dk and dv under ``select=`` against the mask written
+    out, with 2 key/value heads repeated to 4 query heads: at an aligned
+    length, at a padded one (300 runs as 384: the padded queries keep no
+    key), with fewer tokens than ``topk`` (every ``s <= t`` is kept: the
+    causal mask) and over several blocks."""
+    ks = jax.random.split(jax.random.PRNGKey(T + topk), 5)
+    B, H, G, D = 2, 4, 2, 16
+    q = jax.random.normal(ks[0], (B, H, T, D))
+    k, v = (jax.random.normal(kk, (B, G, T, D)) for kk in ks[1:3])
+    cot = jax.random.normal(ks[3], (B, H, T, D))
+    select = _top_selection(ks[4], B, T, topk)
+    assert select.dtype == jnp.int8
+    if topk >= T:
+        assert bool(jnp.all((select != 0) == jnp.tril(jnp.ones((T, T), bool))))
+    heads = lambda a: jnp.repeat(a, H // G, axis=1)
+
+    def run(attend):
+        fn = lambda q, k, v: attend(q, heads(k), heads(v))
+        out, vjp = jax.vjp(fn, q, k, v)
+        return (out,) + vjp(cot)
+
+    got = run(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, interpret=True, select=select))
+    want = run(lambda q, k, v: _written_out_selection(q, k, v, select))
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        err = float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+        assert err < 2e-5, (name, err)
+
+
+def test_a_query_that_keeps_no_key_gives_zeros_and_no_gradient():
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    q, k, v = (jax.random.normal(kk, (1, 2, 130, 8)) for kk in ks[:3])
+    select = _top_selection(ks[3], 1, 130, 9).at[:, 7].set(0)
+    fn = lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                         interpret=True, select=select)
+    out = fn(q, k, v)
+    assert not np.asarray(out[:, :, 7]).any()
+    dq, dk, dv = jax.grad(lambda *a: fn(*a).sum(), argnums=(0, 1, 2))(q, k, v)
+    assert not np.asarray(dq[:, :, 7]).any()
+    want = jax.grad(lambda *a: _written_out_selection(*a, select).sum(),
+                    argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip((dq, dk, dv), want):
+        assert float(jnp.max(jnp.abs(a - b))) < 2e-5
+
+
+def test_a_selection_is_counted_and_widens_the_vmem_gate():
+    from deeplearning4j_tpu.ops.pallas_attention import flash_vmem_bytes
+    from deeplearning4j_tpu.profiling.metrics import (
+        MetricsRegistry, set_registry)
+    registry = MetricsRegistry()
+    previous = set_registry(registry)
+    try:
+        q, k, v = _qkv(B=1, H=1, T=16, D=8)
+        flash_attention(q, k, v, causal=True, interpret=True,
+                        select=jnp.ones((1, 16, 16), jnp.int8))
+        counted = registry.labeled_counter("pallas_flash_traces_total")
+        assert counted.labels(operands="float32", window="none",
+                              select="rows").value == 1
+        assert counted.value == 1
+    finally:
+        set_registry(previous)
+    # the cell's shape: int8 rows of [512, 8192], double-buffered, 8 MB
+    # more, inside the gate; 32,768 tokens with a selection are not
+    plain = flash_vmem_bytes(8192, 128, 2)
+    assert flash_vmem_bytes(8192, 128, 2, selected=True) - plain \
+        == 2 * (512 * 8192 + 2 * 512 * 512)
+    assert flash_ok(8192, 128, 2, selected=True)
+    assert flash_ok(32768, 128, 2) and not flash_ok(32768, 128, 2,
+                                                    selected=True)

@@ -287,8 +287,10 @@ def test_the_fit_spans_and_the_counters_cover_the_model():
     scans = registry.labeled_counter("ssm_scan_traces_total")
     assert scans.labels(path="xla").value == 3      # a layer, one trace
     flash = registry.labeled_counter("pallas_flash_traces_total")
-    assert flash.labels(operands="float32", window="24").value == 2
-    assert flash.labels(operands="float32", window="none").value == 4
+    assert flash.labels(operands="float32", window="24",
+                        select="none").value == 2
+    assert flash.labels(operands="float32", window="none",
+                        select="none").value == 4
     # four states fill no sublane tile: the scan's gate (PR 34) sends the
     # three layers to the XLA path by name; no attention layer fell back
     fallbacks = registry.labeled_counter("pallas_gate_fallbacks_total")

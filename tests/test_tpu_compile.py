@@ -165,3 +165,49 @@ def test_selective_scan_kernels_compile_for_a_v5e(
             text = jax.jit(fn).lower(*args).compile().as_text()
             assert text.count("tpu_custom_call") == len(names)
             assert all(name in text for name in names)
+
+
+@pytest.mark.parametrize("precision", ["default", "highest"])
+@pytest.mark.parametrize(
+    "heads,T,dtype",
+    [(32, 8192, jnp.bfloat16), (32, 8192, jnp.float32),
+     (4, 1100, jnp.bfloat16), (4, 300, jnp.float32)],
+    ids=["keye_cell-bfloat16", "keye_cell-float32", "padded-bfloat16",
+         "blocks_of_128-float32"])
+def test_selected_flash_kernels_compile_for_a_v5e(
+        one_chip, no_compile_cache, heads, T, dtype, precision):
+    """The flash kernels under a selection (``select=``: int8 rows of
+    ``[block, Tp]`` a grid step, the transpose for dk/dv) at the sparse
+    cell's shape, 32 heads of 128 over 8,192 tokens, in both widths; at a
+    padded length and in blocks of 128."""
+    x = jax.ShapeDtypeStruct((1, heads, T, 128), dtype, sharding=one_chip)
+    s = jax.ShapeDtypeStruct((1, T, T), jnp.int8, sharding=one_chip)
+    fwd = lambda q, k, v, sel: flash_attention(q, k, v, causal=True,
+                                               select=sel)
+    bwd = jax.grad(lambda q, k, v, sel: fwd(q, k, v, sel).astype(
+        jnp.float32).sum(), argnums=(0, 1, 2))
+    with jax.default_matmul_precision(precision):
+        for fn, kernels in ((fwd, 1), (bwd, 3)):
+            text = jax.jit(fn).lower(x, x, x, s).compile().as_text()
+            assert text.count("tpu_custom_call") == kernels
+
+
+def test_grouped_products_compile_for_a_v5e(one_chip, no_compile_cache):
+    """The expert layer's grouped product at the sparse cell's shape:
+    16,384 rows (twice a held share's) of 2,048 against sixteen experts'
+    ``[2048, 768]``, forward and both transposes, as grouped-product custom
+    calls and not as sixteen masked dense products."""
+    x = jax.ShapeDtypeStruct((16384, 2048), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((16, 2048, 768), jnp.bfloat16,
+                             sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip)
+    fwd = lambda x, w, s: jax.lax.ragged_dot(x, w, s)
+    bwd = jax.grad(lambda x, w, s: fwd(x, w, s).astype(jnp.float32).sum(),
+                   argnums=(0, 1))
+    for fn, calls in ((fwd, 1), (bwd, 2)):
+        compiled = jax.jit(fn).lower(x, w, sizes).compile()
+        text = compiled.as_text()
+        assert text.count("ragged-dot-none") + text.count(
+            "ragged-dot-") >= calls, text[:2000]
+        assert compiled.cost_analysis()["flops"] < 1.5 * calls * (
+            2 * 16384 * 2048 * 768)

@@ -97,8 +97,10 @@ def test_flash_traces_are_counted_by_window():
             flash_attention(q, q, q, causal=True, window=window,
                             interpret=True)
         counted = registry.labeled_counter("pallas_flash_traces_total")
-        assert counted.labels(operands="float32", window="none").value == 1
-        assert counted.labels(operands="float32", window="4").value == 1
+        assert counted.labels(
+            operands="float32", window="none", select="none").value == 1
+        assert counted.labels(
+            operands="float32", window="4", select="none").value == 1
     finally:
         set_registry(previous)
     with pytest.raises(ValueError, match="window"):
