@@ -19,7 +19,8 @@ weights from a seed), and checks what comes out by the repo's own means:
                 selective scan, by its kernels and by the chunked form,
                 against the token-by-token one, at the SambaY cell's shapes
                 in bfloat16 and at lengths that are no multiple of a block
-                in float32
+                in float32; the selection by the counting threshold
+                against the sort's at the sparse cell's shape, equal
   P4 multichip  ResNet-50 over ``ParallelTrainer`` on four chips, when the
                 machine has them
 
@@ -565,6 +566,41 @@ def selected_attention_parity(B, H, T, D, topk, dtype) -> dict:
     return rec
 
 
+def selection_threshold_parity(Q, T, topk) -> dict:
+    """``top_keys`` (its threshold by the counting search) against the
+    selection whose threshold comes from ``lax.top_k``'s sort, written out
+    here as ``top_keys`` stood before PR 36: the int8 masks of the last
+    ``Q`` of ``T`` queries EQUAL entry for entry, on plain rows and on rows
+    rounded to halves (hundreds of equal scores across the edge)."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.nn.layers.attention import top_keys
+
+    first = T - Q
+
+    def by_sort(scores):
+        seen = jnp.arange(T)[None, :] <= first + jnp.arange(Q)[:, None]
+        scores = jnp.where(seen, scores, -jnp.inf)
+        edge = jax.lax.top_k(scores, topk)[0][..., -1:]
+        above, level = scores > edge, scores == edge
+        wanted = topk - jnp.sum(above, axis=-1, keepdims=True)
+        among = jnp.cumsum(level.astype(jnp.int32), axis=-1)
+        return (seen & (above | (level & (among <= wanted)))).astype(jnp.int8)
+
+    plain = jnp.asarray(np.random.default_rng(23).normal(size=(Q, T)),
+                        jnp.float32)
+    rec = {}
+    for name, scores in (("plain", plain), ("halves", jnp.round(plain * 2) / 2)):
+        want = jax.jit(by_sort)(scores)
+        got = jax.jit(lambda s: top_keys(s, first, topk))(scores)
+        differ = int(jnp.sum(got != want))
+        check(differ == 0, f"the selection on {name} rows differs from the "
+              f"sort's in {differ} entries")
+        rec[name] = int(jnp.sum(got.astype(jnp.int32)))
+    return rec
+
+
 def routed_experts_parity(N, F, M, E, K, first, count, dtype) -> dict:
     """The expert layer (sorted assignments, grouped products, one
     scatter-add) against a loop over its held experts, each run on every
@@ -791,6 +827,11 @@ def p3_kernels() -> dict:
     say("P3 kernels: flash kernels under a selection against the "
         "written-out mask, error over the largest entry "
         f"{rec['selected_attention_rel_err']}")
+    shape = (64, 256, 40) if DRY else (512, 8192, 2048)
+    rec["selection_threshold_kept"] = selection_threshold_parity(*shape)
+    say(f"P3 kernels: the selection of {shape[2]} of {shape[1]} keys by the "
+        "counting threshold equals the sort's entry for entry, kept "
+        f"{rec['selection_threshold_kept']}")
     shapes = ((64, 32, 16, 16, 4, 0, 4), (50, 32, 16, 16, 4, 8, 4)) \
         if DRY else ((8192, 2048, 768, 128, 8, 0, 16),
                      (1100, 256, 128, 128, 8, 48, 16))

@@ -22,6 +22,7 @@ from deeplearning4j_tpu.nn.layers.base import (
     Array, BaseLayerConf, Params, register_layer,
 )
 from deeplearning4j_tpu.nn.layers.normalization import rms_normalize
+from deeplearning4j_tpu.ops.topk_threshold import topk_threshold
 
 NEG_INF = -1e30
 
@@ -571,22 +572,35 @@ def query_chunks(a: Array, axis: int, size: int) -> Array:
     return jnp.moveaxis(a, axis, 0)
 
 
+def seen_keys(first: Array, Q: int, T: int) -> Array:
+    """``[Q, T]`` bool: key ``s`` has come by query ``first + i``."""
+    return jnp.arange(T)[None, :] <= first + jnp.arange(Q)[:, None]
+
+
+def keeps_all(first: Array, Q: int, topk: int) -> Array:
+    """Whether none of the queries ``first .. first + Q - 1`` has seen more
+    keys than ``topk``: their rows of :func:`top_keys` are then
+    :func:`seen_keys` whatever the scores, which need not be made."""
+    return first + Q <= topk
+
+
 def top_keys(scores: Array, first: Array, topk: int) -> Array:
     """For each query row of ``scores [..., Q, T]`` (query ``first + i`` in
     row ``i``) the ``topk`` keys ``s <= t`` of largest score, all of them
     while ``t < topk``, as int8 ``[..., Q, T]``; of equal scores the lower
-    ``s`` first. The ``topk``-th largest score of a row is its threshold
-    (``lax.top_k``, which the v5e's compiler lowers to a sort of the row:
-    30.2 ms for 8,192 rows of 8,192 where a sort of the values alone took
-    34.7, PERF.md PR 35): what lies above is in, and of what equals it the
-    first as many as are still wanted (a running count along the row)."""
+    ``s`` first. The ``topk``-th largest score of a row is its threshold,
+    found by counting and not by sorting (``ops/topk_threshold.py``: the
+    value ``lax.top_k`` would put last, which on a v5e cost a sort of the
+    row with its indices, 1.85 ms for 512 rows of 8,192 where the search
+    takes 0.13; PERF.md PR 36): what lies above is in, and of what equals
+    it the first as many as are still wanted (a running count along the
+    row)."""
     Q, T = scores.shape[-2:]
-    t = first + jnp.arange(Q)[:, None]
-    seen = jnp.arange(T)[None, :] <= t
+    seen = seen_keys(first, Q, T)
     scores = jnp.where(seen, scores, -jnp.inf)
     if topk >= T:
         return jnp.broadcast_to(seen, scores.shape).astype(jnp.int8)
-    edge = jax.lax.top_k(scores, topk)[0][..., -1:]
+    edge = topk_threshold(scores, topk)
     above = scores > edge
     level = scores == edge
     wanted = topk - jnp.sum(above, axis=-1, keepdims=True)
@@ -679,10 +693,9 @@ class SparseIndexerLayer(BaseLayerConf):
             "sparse_select_traces_total",
             "selections of a sparse attention layer's keys by the path "
             "that makes them (per trace)",
-        ).labels(path="all" if self.topk >= T else "top_k").inc()
+        ).labels(path="all" if self.topk >= T else "threshold").inc()
 
-        def chunk(args):
-            q_i, w_i, first = args          # [B, H, C, D], [B, C, H]
+        def select(q_i, w_i, first):
             with jax.named_scope("dsa:index"):
                 hits = jax.nn.relu(jnp.einsum(
                     "bhqd,bkd->bhqk", q_i, k,
@@ -693,6 +706,16 @@ class SparseIndexerLayer(BaseLayerConf):
                     hits * w_i.transpose(0, 2, 1)[..., None], axis=1)
             with jax.named_scope("dsa:topk"):
                 return top_keys(scores, first, self.topk)
+
+        def every(q_i, w_i, first):
+            return jnp.broadcast_to(
+                seen_keys(first, C, T).astype(jnp.int8), (B, C, T))
+
+        def chunk(args):
+            # q_i [B, H, C, D], w_i [B, C, H]. No score is made for a chunk
+            # that keeps every key it has seen: the first ``topk // C``
+            return jax.lax.cond(keeps_all(args[2], C, self.topk), every,
+                                select, *args)
 
         sel = jax.lax.map(chunk, (qc, wc, jnp.arange(len(qc)) * C))
         sel = jnp.moveaxis(sel, 0, 1).reshape(B, -1, T)[:, :T]  # [n,B,C,T]

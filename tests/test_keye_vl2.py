@@ -288,7 +288,7 @@ def test_the_fit_spans_and_the_counters_cover_the_model():
     moe = registry.labeled_counter("moe_grouped_traces_total")
     assert moe.labels(path="ragged_dot").value == 2     # a layer, one trace
     select = registry.labeled_counter("sparse_select_traces_total")
-    assert select.labels(path="top_k").value == 2
+    assert select.labels(path="threshold").value == 2
     flash = registry.labeled_counter("pallas_flash_traces_total")
     assert flash.labels(operands="float32", window="none",
                         select="rows").value == 2

@@ -192,6 +192,19 @@ def test_selected_flash_kernels_compile_for_a_v5e(
             assert text.count("tpu_custom_call") == kernels
 
 
+def test_the_selection_compiles_for_a_v5e_without_a_sort(one_chip,
+                                                          no_compile_cache):
+    """``top_keys`` at the sparse cell's shape, a chunk of 512 queries over
+    8,192 keys keeping 2,048: its threshold comes from the counting search,
+    so the chip's program holds a loop, no sort and no kernel."""
+    from deeplearning4j_tpu.nn.layers.attention import top_keys
+    x = jax.ShapeDtypeStruct((1, 512, 8192), jnp.float32, sharding=one_chip)
+    text = jax.jit(lambda s: top_keys(s, 7680, 2048)).lower(
+        x).compile().as_text()
+    assert " while(" in text
+    assert " sort(" not in text and "tpu_custom_call" not in text
+
+
 def test_grouped_products_compile_for_a_v5e(one_chip, no_compile_cache):
     """The expert layer's grouped product at the sparse cell's shape:
     16,384 rows (twice a held share's) of 2,048 against sixteen experts'

@@ -7,11 +7,13 @@ forward and forward with backward, on whatever device JAX has.
     chiprun -- python tools/sparse_moe_bench.py [part ...]
 
 Parts: ``index`` (the indexer's selection: index scores and top-k),
-``topk`` (``lax.top_k`` of ``[512, 8192]`` float32 rows alone, 16 times,
-and a sort of the values alone),
+``topk`` (the threshold of ``[512, 8192]`` float32 rows alone, 16 times:
+``lax.top_k``, a sort of the values alone, and the counting search of
+``ops/topk_threshold.py``, its thresholds compared with ``lax.top_k``'s;
+``chip_smoke.py`` P3 compares the selections),
 ``flash`` (the flash kernels with the selection and without), ``experts``
 (the expert layer; and its grouped products at 8,192 rows, what the held
-share needs). The readings land in ``chiprun_out/pr35/sparse_moe_bench.json``.
+share needs). The readings land in ``chiprun_out/pr36/sparse_moe_bench.json``.
 No test and no run of the benchmark calls this.
 """
 
@@ -29,6 +31,7 @@ from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.layers.attention import SparseIndexerLayer
 from deeplearning4j_tpu.nn.layers.experts import RoutedExpertsLayer
 from deeplearning4j_tpu.ops.pallas_attention import flash_attention
+from deeplearning4j_tpu.ops.topk_threshold import topk_threshold
 
 B, T, F = 1, 8192, 2048
 CALLS = 5
@@ -72,7 +75,15 @@ def main(parts):
             lambda r: jax.lax.top_k(r, 2048)[0][:, -1], s), scores)
         out["sort"] = timed(lambda s: jax.lax.map(
             lambda r: jnp.sort(r, axis=-1)[:, T - 2048], s), scores)
-        print("topk", out["topk"], "sort", out["sort"], flush=True)
+        out["threshold"] = timed(lambda s: jax.lax.map(
+            lambda r: topk_threshold(r, 2048)[:, 0], s), scores)
+        print({k: out[k] for k in ("topk", "sort", "threshold")}, flush=True)
+        edges = [jax.jit(lambda s, f=f: jax.lax.map(f, s))(scores) for f in (
+            lambda r: jax.lax.top_k(r, 2048)[0][:, -1:],
+            lambda r: topk_threshold(r, 2048))]
+        out["thresholds_differ"] = int(jnp.sum(edges[0] != edges[1]))
+        print("thresholds that differ from lax.top_k's:",
+              out["thresholds_differ"], flush=True)
     if "flash" in parts:
         sel = select()
         print("selected a query", float(jnp.mean(jnp.sum(
@@ -114,8 +125,8 @@ def main(parts):
                     moe_p["W_gate"].astype(jnp.float32), sizes)
         print({k: v for k, v in out.items() if "experts" in k or "ragged"
                in k}, flush=True)
-    os.makedirs("chiprun_out/pr35", exist_ok=True)
-    with open("chiprun_out/pr35/sparse_moe_bench.json", "w") as f:
+    os.makedirs("chiprun_out/pr36", exist_ok=True)
+    with open("chiprun_out/pr36/sparse_moe_bench.json", "w") as f:
         json.dump(out, f, indent=1)
     print(json.dumps(out))
 

@@ -4,8 +4,9 @@ without a chip. Nothing runs, so nothing here is a result or a time.
 
     JAX_PLATFORMS=cpu python tools/step_for_v5e.py <cell> [out.txt]
 
-The configuration, the traffic's batch and length and the program's builder
-are the cell's own (``BENCHMARK.json``); parameters, momentum and states
+The configuration, the traffic's batch and length (or, for an image cell,
+the batch in the dtype its feed narrows to) and the program's builder are
+the cell's own (``BENCHMARK.json``); parameters, momentum and states
 are shapes (``jax.eval_shape``), never arrays. The program asks the backend
 which path its kernels take and here sees the CPU, so this script, and no
 option of the program, says "compiled" in its place. With ``out.txt`` the
@@ -88,14 +89,20 @@ def main(cell_name, out=None):
         build, jax.random.PRNGKey(0)))
     print(f"{cell_name}: {sum(a.size for a in jax.tree.leaves(params)):,} "
           "parameters", flush=True)
-    B, T = mix["batch"], mix["seq_len"]
-    ids = jax.ShapeDtypeStruct((B, T), jnp.int32, sharding=chip)
+    batch = lambda *shape, dtype: jax.ShapeDtypeStruct(
+        (mix["batch"],) + shape, dtype, sharding=chip)
+    if mix["data"] == "images":      # as the feed hands them: narrowed
+        x = batch(cfg["height"], cfg["width"], cfg["channels"],
+                  dtype=jnp.dtype(mix["feed_dtype"]))
+        y = batch(cfg["n_classes"], dtype=jnp.dtype(mix["feed_dtype"]))
+    else:
+        x = y = batch(mix["seq_len"], dtype=jnp.int32)
     key = on_chip(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
     net.params, net.opt_state, net.states = params, opt_state, states
     t0 = time.time()
     lowered = net._build_train_step().lower(
-        params, opt_state, states, {conf.network_inputs[0]: ids},
-        {conf.network_outputs[0]: ids}, None, None, key)
+        params, opt_state, states, {conf.network_inputs[0]: x},
+        {conf.network_outputs[0]: y}, None, None, key)
     compiled = lowered.compile()
     ma = compiled.memory_analysis()
     gb = lambda n: f"{n / 1e9:.3f} GB"
