@@ -169,10 +169,12 @@ _COLLECTIVE_KINDS = ("all-reduce", "all-gather", "reduce-scatter",
 _PASS_THROUGH_OPS = {"bitcast", "copy", "reshape", "transpose", "convert",
                      "get-tuple-element"}
 
-# `  %name = f32[16,8]{1,0} all-reduce(...)` / tuple-typed results
+# `  %name = f32[16,8]{1,0} all-reduce(...)` / tuple-typed results. A TPU
+# layout has brackets of its own (`bf16[8,128]{1,0:T(8,128)(2,1)S(1)}`) and a
+# tuple may hold tuples: the type is what stands before ` <opcode>(`
 _INSTR_RE = re.compile(
     r"^\s+(?:ROOT\s+)?%(?P<name>[\w.\-]+)\s*=\s*"
-    r"(?P<type>\([^)]*\)|[\w\[\]{},:\d]+)\s+(?P<op>[\w\-]+)\(")
+    r"(?P<type>\(.*?\)|\S+)\s+(?P<op>[\w\-]+)\(")
 _SHAPE_RE = re.compile(r"([a-z]+\d*(?:e\d+m\d+(?:fn)?)?)\[([\d,]*)\]")
 _COMP_HDR_RE = re.compile(r"^(?:ENTRY\s+)?%([\w.\-]+)\s*\(.*\)\s*->")
 _REPLICA_GROUPS_RE = re.compile(

@@ -16,6 +16,8 @@ from __future__ import annotations
 
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
+import weakref
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -365,8 +367,12 @@ class ComputationGraph(LazyScoreMixin, EvalMixin, FitLoopMixin, ScanFitMixin,
 
     # ------------------------------------------------------------- train step
     def _build_train_step(self):
+        # the step's closures reach the net through a weak proxy: with a
+        # cycle net -> step -> closure -> net a dropped net's device memory
+        # waits for the cyclic collector, some time (nn/netcommon.py)
+        net = weakref.proxy(self)
         def loss_of(p, states, inputs, labels, masks, lmasks, _, rng):
-            loss, new_states = self._loss_fn(p, states, inputs, labels,
+            loss, new_states = net._loss_fn(p, states, inputs, labels,
                                              masks, lmasks, rng)
             return loss, (new_states, None)
 
@@ -442,10 +448,13 @@ class ComputationGraph(LazyScoreMixin, EvalMixin, FitLoopMixin, ScanFitMixin,
 
     # ------------------------------------------------------------------ tBPTT
     def _build_tbptt_step(self):
+        # the step's closures reach the net through a weak proxy: with a
+        # cycle net -> step -> closure -> net a dropped net's device memory
+        # waits for the cyclic collector, some time (nn/netcommon.py)
+        net = weakref.proxy(self)
         training = self.conf.training
         fwd = training.tbptt_fwd_length
         bwd = training.tbptt_bwd_length or fwd
-        data_loss_of = self._data_loss
         rnn_inputs = self._tbptt_rnn_inputs()
 
         def loss_of(p, states, inputs, labels, masks, lmasks, carries, rng):
@@ -457,32 +466,33 @@ class ComputationGraph(LazyScoreMixin, EvalMixin, FitLoopMixin, ScanFitMixin,
             T = next(v.shape[1] for n, v in inputs.items() if n in rnn_inputs)
             split = max(T - bwd, 0) if bwd < fwd else 0
             if split == 0:
-                acts, om, new_states, new_carries = self._forward(
+                acts, om, new_states, new_carries = net._forward(
                     p, states, inputs, train=True, rng=rng, masks=masks,
                     carries=carries)
-                data_loss = data_loss_of(p, acts, om, labels, lmasks)
+                data_loss = net._data_loss(p, acts, om, labels, lmasks)
             else:
                 rng1, rng2 = (jax.random.split(rng) if rng is not None
                               else (None, None))
                 head = lambda d, m=3, o=None: _time_slice(d, 0, split, m, only=o)
                 tail = lambda d, m=3, o=None: _time_slice(d, split, T, m, only=o)
-                acts1, om1, states1, carries1 = self._forward(
+                acts1, om1, states1, carries1 = net._forward(
                     p, states, head(inputs, o=rnn_inputs), train=True,
                     rng=rng1, masks=head(masks, 2, rnn_inputs),
                     carries=carries)
                 acts1 = jax.tree.map(jax.lax.stop_gradient, acts1)
                 carries1 = jax.tree.map(jax.lax.stop_gradient, carries1)
-                acts2, om2, new_states, new_carries = self._forward(
+                acts2, om2, new_states, new_carries = net._forward(
                     p, states1, tail(inputs, o=rnn_inputs), train=True,
                     rng=rng2, masks=tail(masks, 2, rnn_inputs),
                     carries=carries1)
                 # per-timestep losses SUM over time: head + tail ==
                 # the single-call slice loss
                 data_loss = (
-                    data_loss_of(p, acts1, om1, head(labels), head(lmasks, 2))
-                    + data_loss_of(p, acts2, om2, tail(labels),
+                    net._data_loss(p, acts1, om1, head(labels),
+                                   head(lmasks, 2))
+                    + net._data_loss(p, acts2, om2, tail(labels),
                                    tail(lmasks, 2)))
-            return (self._with_penalties(data_loss, p, new_states),
+            return (net._with_penalties(data_loss, p, new_states),
                     (new_states, new_carries))
 
         return build_train_step(self, self._layer_list(), loss_of,

@@ -25,6 +25,8 @@ from __future__ import annotations
 import math
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+import weakref
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -264,16 +266,20 @@ class MultiLayerNetwork(LazyScoreMixin, EvalMixin, FitLoopMixin,
 
     # ------------------------------------------------------------- train step
     def _build_train_step(self):
+        # the step's closures reach the net through a weak proxy: with a
+        # cycle net -> step -> closure -> net a dropped net's device memory
+        # waits for the cyclic collector, some time (nn/netcommon.py)
+        net = weakref.proxy(self)
         from deeplearning4j_tpu.nn.layers.core import CenterLossOutputLayer
 
         def loss_of(p, states, features, labels, fmask, lmask, _, rng):
-            return self._loss_and_head_input(p, states, features, labels,
+            return net._loss_and_head_input(p, states, features, labels,
                                              fmask, lmask, rng)
 
         def move_centers(params, new_params, h_last, labels):
             # EMA center update outside the gradient step
             # (ref: CenterLossOutputLayer alpha semantics)
-            new_params[-1]["cL"] = self.layers[-1].updated_centers(
+            new_params[-1]["cL"] = net.layers[-1].updated_centers(
                 {"cL": params[-1]["cL"]}, h_last, labels)
             return new_params
 
@@ -317,6 +323,10 @@ class MultiLayerNetwork(LazyScoreMixin, EvalMixin, FitLoopMixin,
 
     # ------------------------------------------------------------------ tBPTT
     def _build_tbptt_step(self):
+        # the step's closures reach the net through a weak proxy: with a
+        # cycle net -> step -> closure -> net a dropped net's device memory
+        # waits for the cyclic collector, some time (nn/netcommon.py)
+        net = weakref.proxy(self)
         training = self.conf.training
         fwd = training.tbptt_fwd_length
         bwd = training.tbptt_bwd_length or fwd
@@ -337,21 +347,21 @@ class MultiLayerNetwork(LazyScoreMixin, EvalMixin, FitLoopMixin,
             def seg(x, lo, hi):
                 return None if x is None else x[:, lo:hi]
 
-            out_layer = self.layers[-1]
+            out_layer = net.layers[-1]
             if split == 0:
-                h, _, new_states, new_carries, cur_mask = self._forward(
+                h, _, new_states, new_carries, cur_mask = net._forward(
                     p, states, features, train=True, rng=rng, mask=fmask,
                     carries=carries)
                 mask = lmask if lmask is not None else cur_mask
                 data_loss = out_layer.compute_loss(p[-1], h, labels, mask=mask)
             else:
                 rng1, rng2 = jax.random.split(rng)
-                h1, _, states1, carries1, m1 = self._forward(
+                h1, _, states1, carries1, m1 = net._forward(
                     p, states, seg(features, 0, split), train=True,
                     rng=rng1, mask=seg(fmask, 0, split), carries=carries)
                 h1 = jax.lax.stop_gradient(h1)
                 carries1 = jax.tree.map(jax.lax.stop_gradient, carries1)
-                h2, _, new_states, new_carries, m2 = self._forward(
+                h2, _, new_states, new_carries, m2 = net._forward(
                     p, states1, seg(features, split, T), train=True,
                     rng=rng2, mask=seg(fmask, split, T), carries=carries1)
                 mask1 = seg(lmask, 0, split) if lmask is not None else m1
@@ -363,7 +373,7 @@ class MultiLayerNetwork(LazyScoreMixin, EvalMixin, FitLoopMixin,
                                            mask=mask1)
                     + out_layer.compute_loss(p[-1], h2, seg(labels, split, T),
                                              mask=mask2))
-            reg = l1_l2_penalty(p, self.layers)
+            reg = l1_l2_penalty(p, net.layers)
             # aux losses (MoE balancing etc.) — keep parity with the
             # standard step and the graph container's tBPTT step
             return (data_loss + reg + _sum_aux_losses(new_states),

@@ -33,6 +33,7 @@ from deeplearning4j_tpu.nn.updater import (
     PrecisionPolicy, cast_floats, compute_updates, precision_value_and_grad,
 )
 from deeplearning4j_tpu.optimize.listeners import TrainingListener
+from deeplearning4j_tpu.profiling import scopes
 from deeplearning4j_tpu.profiling.metrics import get_registry
 from deeplearning4j_tpu.profiling.tracer import get_tracer
 
@@ -169,7 +170,16 @@ def build_train_step(net, layers, loss_of, *, carried: bool = False,
     bad])``, or when ``carried`` ``step(..., lmasks, carries, rng) ->
     (params, opt_state, states, carries, loss[, bad])`` with the carries
     guarded too (a NaN window must not poison the next window's recurrent
-    state). The trace's ``jit_train_step`` is the first one's name."""
+    state). The trace's ``jit_train_step`` is the first one's name. The
+    containers' ``loss_of`` reach their net through a ``weakref.proxy``:
+    the jitted step hangs on the net, so a closure that held the net would
+    close a cycle, and a dropped net's parameters and updater state would
+    stay on the device until the cyclic collector came by (the benchmark
+    drops the net to make room for its float32 reference). The
+    shell's own operations carry the scopes ``train:cast`` (the policy's
+    seams: inputs and parameters into the compute dtype, gradients out of
+    it) and ``train:update`` in the compiled step's ``op_name``
+    (``profiling/scopes.py`` reads them)."""
     tx, training = net._tx, net.conf.training
     collect_grads = (not carried) and getattr(net, "_collect_grads", False)
     guard = net._sentinel is not None
@@ -180,8 +190,9 @@ def build_train_step(net, layers, loss_of, *, carried: bool = False,
     def run(params, opt_state, states, inputs, labels, masks, lmasks,
             carries, rng):
         if policy.mixed:
-            inputs = cast_floats(inputs, policy.compute_dtype)
-            masks = cast_floats(masks, policy.compute_dtype)
+            with jax.named_scope("train:cast"):
+                inputs = cast_floats(inputs, policy.compute_dtype)
+                masks = cast_floats(masks, policy.compute_dtype)
 
         def loss_for_grad(p):
             return loss_of(p, states, inputs, labels, masks, lmasks,
@@ -189,10 +200,11 @@ def build_train_step(net, layers, loss_of, *, carried: bool = False,
 
         (loss, (new_states, extra)), grads = precision_value_and_grad(
             loss_for_grad, policy)(params)
-        new_params, new_opt = compute_updates(
-            tx, grads, opt_state, params, layers, training)
-        if after_update is not None:
-            new_params = after_update(params, new_params, extra, labels)
+        with jax.named_scope("train:update"):
+            new_params, new_opt = compute_updates(
+                tx, grads, opt_state, params, layers, training)
+            if after_update is not None:
+                new_params = after_update(params, new_params, extra, labels)
         if not carried:
             return step_result(
                 guard, loss, grads, (params, opt_state, states),
@@ -240,6 +252,7 @@ class FitLoopMixin:
     ``_fit_epoch_scan``."""
 
     _tbptt_step_fn = None
+    _scoped_step = None     # the step whose program profiling/scopes.py has
 
     def _fit_epochs(self, data, epochs: int, use_async: bool,
                     scan_window: int):
@@ -297,11 +310,16 @@ class FitLoopMixin:
             batch_args = split(data)
         with tracer.span("fit:rng"):
             self._rng, step_rng = jax.random.split(self._rng)
+        args = (self.params, self.opt_state, self.states, *batch_args,
+                step_rng)
         with tracer.span("fit:dispatch") as dispatch:
-            out = self._train_step_fn(self.params, self.opt_state,
-                                      self.states, *batch_args, step_rng)
+            out = self._train_step_fn(*args)
             (self.params, self.opt_state, self.states, loss,
              self.last_grads) = out[:5]
+        if self._scoped_step is not self._train_step_fn:
+            # once a compiled step, after its first dispatch
+            self._scoped_step = self._train_step_fn
+            scopes.record_step("jit_train_step", self._train_step_fn, args)
         get_registry().counter(
             "fit_dispatch_seconds_total",
             help="host seconds dispatching the jitted train step"

@@ -145,7 +145,8 @@ def precision_value_and_grad(loss_fn, policy: "PrecisionPolicy"):
     scale = policy.loss_scale
 
     def vag(params, *args):
-        cparams = cast_floats(params, cdt)
+        with jax.named_scope("train:cast"):
+            cparams = cast_floats(params, cdt)
 
         def seamed(p, *a):
             loss, aux = loss_fn(p, *a)
@@ -159,9 +160,10 @@ def precision_value_and_grad(loss_fn, policy: "PrecisionPolicy"):
             seamed, has_aux=True)(cparams, *args)
         # the gradient seam: master-dtype the instant autodiff returns,
         # so clip/optax/sentinel math never runs in half precision
-        grads = cast_floats(grads, pdt)
-        if scale:
-            grads = jax.tree.map(lambda g: g / scale, grads)
+        with jax.named_scope("train:cast"):
+            grads = cast_floats(grads, pdt)
+            if scale:
+                grads = jax.tree.map(lambda g: g / scale, grads)
         return (loss, aux), grads
 
     return vag

@@ -30,7 +30,12 @@ diagnostics) and for the question a TPU port actually asks (where did
   spans, metrics snapshot, flight tail. ``tools/postmortem.py`` reads
   one back.
 
-No jax import at module load: the tracer/metrics/flightrec/watchdog
+- ``scopes`` — the compiled train step's own table ``{instruction:
+  op_name}``, kept by the fit loop once a compiled step, and device time
+  by (node, scope, phase) from a profile's number-named operations
+  (``fusion.14``): the join a builder used to make by hand.
+
+No jax import at module load: the tracer/metrics/flightrec/watchdog/scopes
 legs are pure stdlib and must stay importable from the lint tooling.
 """
 

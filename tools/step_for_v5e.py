@@ -18,7 +18,10 @@ tells whether two trees' texts are the same program: every line but the
 tables of source files and lines, and every Mosaic kernel's body parsed
 back to MLIR and printed without its source locations (the bytes of a body
 hold the path and the line of each operation, so they differ between two
-checkouts of one kernel). PERF.md section 7 (b).
+checkouts of one kernel). With ``--same-but-names`` in ``--same``'s place
+every ``op_name="..."`` is taken out of both texts first, which is how a
+change of ``jax.named_scope``s alone is shown to be the same program under
+other labels.
 """
 
 import base64
@@ -123,9 +126,10 @@ def main(cell_name, out=None):
 BODY = r'custom_call_config":\{"body":"([^"]*)"'
 
 
-def program(path):
+def program(path, names=True):
     """A compiled text as ``(lines, kernel bodies)`` with what names a
-    checkout and a source line taken out."""
+    checkout and a source line taken out, and without ``names`` every
+    ``op_name`` too."""
     from jax._src.interpreters import mlir
     from jax._src.lib import tpu
     from jax._src.lib.mlir import ir
@@ -141,12 +145,14 @@ def program(path):
                 body)).operation.get_asm(enable_debug_info=False))
     text = re.sub(BODY, "", text)
     text = re.sub(r"stack_frame_id=\d+", "", text)
+    if not names:
+        text = re.sub(r'op_name="[^"]*"', "", text)
     return [l for l in text.splitlines()
             if not re.match(r'^\s*\d+ ("|\{)', l)], kernels
 
 
-def same(a, b) -> int:
-    (la, ka), (lb, kb) = program(a), program(b)
+def same(a, b, names=True) -> int:
+    (la, ka), (lb, kb) = program(a, names), program(b, names)
     lines = sum(x != y for x, y in zip(la, lb)) + abs(len(la) - len(lb))
     bodies = sum(x != y for x, y in zip(ka, kb)) + abs(len(ka) - len(kb))
     print(f"{len(la):,} and {len(lb):,} lines, {lines} differ; {len(ka)} "
@@ -157,6 +163,6 @@ def same(a, b) -> int:
 
 
 if __name__ == "__main__":
-    if sys.argv[1] == "--same":
-        sys.exit(same(*sys.argv[2:4]))
+    if sys.argv[1] in ("--same", "--same-but-names"):
+        sys.exit(same(*sys.argv[2:4], names=sys.argv[1] == "--same"))
     main(*sys.argv[1:3])
