@@ -54,6 +54,20 @@ transposed. A query none of whose keys is selected is a fully masked row
 causal bounds. With ``select=None`` the three kernels lower to what they
 were before the operand (PERF.md, PR 35).
 
+Under remat (``nn/remat.checkpoint_after_cotangent``, every node of a net
+trained with ``conf.training.remat``) the forward kernel runs once: the
+node keeps ``out`` and ``lse`` as the kernel wrote them beside its inputs,
+and in the node's rebuild the call site takes ``_flash_kept``, whose primal
+is the kept ``out`` and whose backward hands the kept pair and the rebuilt
+q, k, v to the same two backward kernels. The same bits either way: the
+rebuilt forward would have written that pair from those operands. ``lse``
+is kept lane-replicated, 134 MB at ``[32, 8192, 128]`` where one lane is
+1 MB: cut to a lane and broadcast again before dq it cost 0.7 ms a layer
+and, on the sparse decoder's step, moved the compiler's placement of other
+layers' operands for 20 ms more (PERF.md, PR 38). Without a remat node
+round it (inference, a net trained with remat off, a ``jax.checkpoint`` of
+a scan) a call is ``_flash_core``.
+
 Shapes: q, k are [B, H, T, D] and v [B, H, T, Dv] (self-attention: same
 T; ``Dv`` may differ, all three are padded to one lane width). The kernel
 pads D to 128 and T to its block internally (``_padded_len``: 512, 256 or
@@ -85,6 +99,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deeplearning4j_tpu.nn.remat import kept
 from deeplearning4j_tpu.ops.pallas_kernels import (
     VMEM_GATE_BYTES, _round_up, lstm_mode, vmem_limit,
 )
@@ -492,6 +507,31 @@ def _flash_core_bwd(causal, interpret, scale, window, res, g):
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10))
+def _flash_kept(q, k, v, bias, select, out, lse, causal, interpret, scale,
+                window):
+    """``_flash_core`` of a call site whose ``out`` and ``lse`` a remat node
+    holds (``nn/remat.kept``): no forward kernel, and the backward kernels
+    read the held pair beside the rebuilt operands."""
+    return out
+
+
+def _flash_kept_fwd(q, k, v, bias, select, out, lse, causal, interpret,
+                    scale, window):
+    return out, (q, k, v, bias, select, out, lse)
+
+
+def _flash_kept_bwd(causal, interpret, scale, window, res, g):
+    # the pair is the node's residual, as select is an operand: no gradient
+    *grads, nothing = _flash_core_bwd(causal, interpret, scale, window, res,
+                                      g)
+    out, lse = res[-2:]
+    return (*grads, nothing, jnp.zeros_like(out), jnp.zeros_like(lse))
+
+
+_flash_kept.defvjp(_flash_kept_fwd, _flash_kept_bwd)
+
+
 def _count_trace(dtype, window: Optional[int], selected: bool) -> None:
     """Which products a run's kernels make, counted once per trace (not
     per step) under the operands' dtype, the window's width and whether a
@@ -548,6 +588,13 @@ def flash_attention(q, k, v, *, causal: bool = False,
         rows = jnp.pad((select != 0).astype(jnp.int8),
                        ((0, 0), (0, Tp - T), (0, Tp - T)))
         select = (rows, rows.transpose(0, 2, 1))
-    out = _flash_core(qf, kf, vf, bias, select, causal, interpret,
-                      1.0 / math.sqrt(D), window)
+    static = (causal, interpret, 1.0 / math.sqrt(D), window)
+
+    # a remat node keeps the forward's pair as the kernel writes it
+    held = kept("flash_attention", lambda: _run_fwd(qf, kf, vf, bias,
+                                                    *static, select))
+    if held is None:
+        out = _flash_core(qf, kf, vf, bias, select, *static)
+    else:
+        out = _flash_kept(qf, kf, vf, bias, select, *held, *static)
     return out.reshape(B, H, Tp, Dp)[:, :, :T, :Dv]

@@ -136,6 +136,18 @@ def test_loss_and_gradients_agree_by_the_element():
             assert gap(got[leaf], g) < 5e-5, leaf
 
 
+def test_the_step_keeps_each_flash_pair_and_is_the_rebuilt_steps_bits(
+        monkeypatch):
+    """Every layer's attention node runs its flash forward under the selection
+    once a step (``nn/remat.kept``), not again in the rebuild."""
+    from remat_reference import assert_a_models_step_keeps_its_flash_pairs
+    cfg = tiny_cfg()
+    assert_a_models_step_keeps_its_flash_pairs(
+        monkeypatch,
+        lambda: program.build_net(cfg, reference.make_weights(cfg, 2)),
+        id_batches(3, seed=2), attention_nodes=cfg["num_hidden_layers"])
+
+
 def test_remat_on_and_off_give_the_same_gradients():
     """One step each from the same weights: the first moments agree to
     float32 rounding. Under remat the attention node keeps the selection

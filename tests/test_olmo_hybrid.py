@@ -96,6 +96,19 @@ def test_three_train_steps_follow_the_reference(seed):
         assert not ok, (planted, compared)
 
 
+def test_the_step_keeps_each_flash_pair_and_is_the_rebuilt_steps_bits(
+        monkeypatch):
+    """The full-attention node behind its QK-norm runs its flash forward once
+    a step (``nn/remat.kept``), not again in the rebuild."""
+    from remat_reference import assert_a_models_step_keeps_its_flash_pairs
+    monkeypatch.setenv("DL4J_TPU_PALLAS", "interpret")
+    cfg = tiny_cfg()
+    assert_a_models_step_keeps_its_flash_pairs(
+        monkeypatch,
+        lambda: program.build_net(cfg, reference.make_weights(cfg, 2)),
+        id_batches(3, seed=2), attention_nodes=1)
+
+
 def test_remat_on_and_off_give_the_same_gradients():
     """One step each from the same weights: the first moments (the
     gradients as the optimizer got them) agree to float32 rounding, 5e-5 of

@@ -166,6 +166,18 @@ def test_the_tied_leafs_gradient_is_the_sum_of_its_two_parts(
     assert "head" in net.params and net.params["head"] == {}
 
 
+def test_the_step_keeps_each_flash_pair_and_is_the_rebuilt_steps_bits(
+        monkeypatch):
+    """The windowed, the full and the cross attention nodes each run their
+    flash forward once a step (``nn/remat.kept``), not again in the rebuild."""
+    from remat_reference import assert_a_models_step_keeps_its_flash_pairs
+    cfg = tiny_cfg()
+    assert_a_models_step_keeps_its_flash_pairs(
+        monkeypatch,
+        lambda: program.build_net(cfg, reference.make_weights(cfg, 2)),
+        id_batches(3, seed=2), attention_nodes=6)
+
+
 def test_remat_on_and_off_give_the_same_gradients():
     """One step each from the same weights: the first moments agree to
     float32 rounding, 5e-5 of each leaf's norm. Under remat a reader keeps

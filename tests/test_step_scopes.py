@@ -287,6 +287,13 @@ def test_a_fusion_that_holds_a_product_is_a_product():
      "/jvp(flash_attention_dkv)/pallas_call", ("b3_mix", None, "bwd")),
     ("jit(train_step)/transpose(jvp(b3_mix))/jvp(flash_attention_fwd)/"
      "pallas_call", ("b3_mix", None, "remat")),
+    # the backward kernels of a call site whose pair the node kept (PR 38)
+    # carry the names they had: no forward kernel stands before them
+    ("jit(train_step)/transpose(jvp(b3_mix))/transpose(transpose(jvp(b3_mix)))"
+     "/jvp(flash_attention_dq)/pallas_call", ("b3_mix", None, "bwd")),
+    ("jit(train_step)/transpose(jvp(b0_mix))/transpose(transpose(jvp(b0_mix)))"
+     "/jvp(flash_attention_dkv)/while/body/dynamic_slice",
+     ("b0_mix", None, "bwd")),
     # a scope inside a loop's body, and the loop itself
     ("jit(step)/jvp(b0_mix)/closed_call/while/body/closed_call/"
      "gdn:chunk_scan/sin", ("b0_mix", "gdn:chunk_scan", "fwd")),
@@ -323,6 +330,40 @@ def test_a_fusion_that_holds_a_product_is_a_product():
 ])
 def test_split(op_name, want):
     assert scopes.split(op_name) == want
+
+
+@pytest.mark.parametrize("form", ["kept", "rebuilt_whole"])
+def test_no_flash_forward_is_left_under_remat(registry, monkeypatch, form):
+    """A node under remat keeps the flash kernel's output and logsumexp
+    (``nn/remat.kept``), so its compiled step holds the forward kernel in
+    the forward phase alone and the backward kernels under ``bwd``; the
+    wrapper that rebuilt the node whole ran it again under ``remat``."""
+    from deeplearning4j_tpu.nn import netcommon
+    from deeplearning4j_tpu.nn.layers.attention import SelfAttentionLayer
+    from remat_reference import rebuilt_whole
+    monkeypatch.setenv("DL4J_TPU_PALLAS", "interpret")
+    if form == "rebuilt_whole":
+        monkeypatch.setattr(netcommon, "checkpoint_after_cotangent",
+                            rebuilt_whole)
+    g = (NeuralNetConfiguration.builder().seed(7).updater("nesterovs")
+         .learning_rate(0.05).gradient_checkpointing().graph_builder()
+         .add_inputs("in")
+         .add_layer("b0_mix", SelfAttentionLayer(n_heads=2, head_dim=4,
+                                                 causal=True), "in")
+         .add_layer("out", RnnOutputLayer(n_out=C, activation="softmax"),
+                    "b0_mix")
+         .set_outputs("out").set_input_types(InputType.recurrent(F)))
+    ComputationGraph(g.build()).init().fit(_data("tbptt"))
+    phases = {part: {scopes.split(name) for name in
+                     scopes.step_table(STEP).values()
+                     if f"flash_attention_{part}" in name}
+              for part in ("fwd", "dq", "dkv")}
+    forward = {scopes.Scope("b0_mix", None, "fwd")}
+    if form == "rebuilt_whole":
+        forward.add(scopes.Scope("b0_mix", None, "remat"))
+    assert phases == dict(fwd=forward,
+                          dq={scopes.Scope("b0_mix", None, "bwd")},
+                          dkv={scopes.Scope("b0_mix", None, "bwd")})
 
 
 def test_by_scope_counts_no_time_twice_and_adds_up_to_the_busy_time():
