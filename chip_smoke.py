@@ -20,7 +20,8 @@ weights from a seed), and checks what comes out by the repo's own means:
                 against the token-by-token one, at the SambaY cell's shapes
                 in bfloat16 and at lengths that are no multiple of a block
                 in float32; the selection by the counting threshold
-                against the sort's at the sparse cell's shape, equal
+                against the sort's at the sparse cell's shape, equal;
+                SmallThinker's four layers trained past one window
   P4 multichip  ResNet-50 over ``ParallelTrainer`` on four chips, when the
                 machine has them
 
@@ -732,6 +733,54 @@ def selective_scan_parity(B, T, D, N, dtype) -> dict:
     return out
 
 
+def smallthinker_probe(T, vocab) -> dict:
+    """SmallThinker's four-layer period at published widths (8 of 64
+    experts held) trained four steps through ``net.fit`` at length ``T``,
+    remat on, bfloat16 policy: the windowed and the full flash kernels in
+    the step, no layer on the XLA side of a gate, finite losses; and how
+    the tokens route, by layer, with the embedding's rows at the model's
+    own 0.02 and scaled to 1.0 (the benchmark's): the largest share of one
+    held expert among the held assignments, and the assignments held of
+    the ``6 T`` made."""
+    import jax
+
+    from deeplearning4j_tpu.datasets.dataset import DataSet
+    from deeplearning4j_tpu.models.smallthinker import (
+        smallthinker, smallthinker_tiny)
+    from deeplearning4j_tpu.nn.graph import ComputationGraph
+    from deeplearning4j_tpu.profiling.metrics import get_registry
+
+    build = smallthinker_tiny if DRY else smallthinker
+    kw = {} if DRY else dict(n_layers=4, held_experts=8)
+    conf = build(vocab, T, remat=True, precision="bf16",
+                 learning_rate=2e-5, **kw)
+    rng = np.random.default_rng(31)
+    ids = rng.integers(0, vocab, (4, 1, T + 1), dtype=np.int32)
+    batches = [DataSet(i[:, :-1], i[:, 1:]) for i in ids]
+    gated = get_registry().labeled_counter("pallas_gate_fallbacks_total")
+    before = gated.value
+    rec = {}
+    for std in (0.02, 1.0):
+        net = ComputationGraph(conf).init()
+        net.params["embed"]["W"] = net.params["embed"]["W"] * (std / 0.02)
+        run = _fit_steps(net, batches, _prefetched(net, None))
+        spread = {}
+        for node, state in net.states.items():
+            if "assigned" in state:
+                a = np.asarray(jax.device_get(state["assigned"]))
+                spread[node] = [round(float(a.max() / max(a.sum(), 1)), 3),
+                                int(a.sum())]
+        run["routing"] = spread
+        rec[f"embedding_std {std}"] = run
+    calls = _lowered_step_text(net, batches[0]).count("tpu_custom_call")
+    check(DRY or calls > 0, "SmallThinker: no Mosaic custom call in the "
+          "lowered train step")
+    check(gated.value == before, f"SmallThinker: a shape gate sent "
+          f"{gated.value - before:.0f} layer(s) to the XLA path")
+    rec["tpu_custom_calls"] = calls
+    return rec
+
+
 def _lowered_step_text(net, batch) -> str:
     from deeplearning4j_tpu.profiling.cost import step_example_args
     return net._train_step_fn.lower(
@@ -843,6 +892,13 @@ def p3_kernels() -> dict:
     say("P3 kernels: routed experts, grouped products against the loop "
         "over experts, error over the largest entry "
         f"{rec['routed_experts_rel_err']}")
+
+    # SmallThinker's period past one window of 4,096 keys (a window of 8
+    # at the tiny size)
+    T, vocab = (40, 64) if DRY else (6144, 4096)
+    rec["smallthinker"] = smallthinker_probe(T, vocab)
+    say(f"P3 kernels: SmallThinker's four layers at T={T} via net.fit, "
+        f"losses and routing by the embedding's std {rec['smallthinker']}")
 
     hidden, T, K, B = (32, 8, 16, 4) if DRY else (256, 64, 96, 32)
     lstm = MultiLayerNetwork(

@@ -10,6 +10,7 @@ parallel/sequence.py distributes as ring attention over a mesh axis.
 
 from __future__ import annotations
 
+import contextlib
 import math
 from dataclasses import dataclass
 from typing import List, Optional, Tuple
@@ -753,10 +754,8 @@ def attention_selected(q: Array, k: Array, v: Array, select: Array,
 @register_layer
 @dataclass
 class GroupedQueryAttentionLayer(SelfAttentionLayer):
-    """Causal grouped-query attention with rotary positions and a norm by
-    head, over two inputs: the stream's ``u [B, T, F]`` and the selection
-    ``[B, T, T]`` of a ``SparseIndexerLayer`` (query ``t`` reads the keys
-    ``s`` its row names and no others)::
+    """Causal grouped-query attention over the stream's ``u [B, T, F]``,
+    with rotary positions and a norm by head, each of which can be off::
 
         q = rotary(RMSNorm_D(W_q u) g_q)   n_heads heads of head_dim
         k = rotary(RMSNorm_D(W_k u) g_k)   n_kv_heads heads
@@ -764,24 +763,40 @@ class GroupedQueryAttentionLayer(SelfAttentionLayer):
         y = W_o concat_h o_h
 
     with query head ``h`` reading key/value head ``h // (n_heads /
-    n_kv_heads)`` and one gain of ``head_dim`` each for q and k. No bias.
-    The selection is an operand of the flash kernels (``flash_attention(
-    ..., select=)``) behind ``SelfAttentionLayer``'s seam; where their
-    gate refuses, or the mode is off, the selected attention runs in XLA
-    in query chunks (``attention_selected``), a refusal counted under
-    ``kernel="flash_select"``.
-    Trains; no incremental decode, no sequence-parallel ring.
+    n_kv_heads)`` and one gain of ``head_dim`` each for q and k (none
+    without ``qk_norm``; no turn without ``rotate``). No bias. ``S_t`` is
+    every key ``s <= t``; with ``window`` those with ``0 <= t - s <
+    window``; with ``selected`` (the default) the node takes a second input,
+    the selection ``[B, T, T]`` of a ``SparseIndexerLayer``, and ``S_t`` is
+    the keys its row names and no others.
 
-    Params: ``Wq [F, H D]``, ``Wk, Wv [F, G D]``, ``q_gamma, k_gamma [D]``,
-    ``Wo [H D, F]``."""
+    The selection and the window are operands of the flash kernels
+    (``flash_attention(..., select=, window=)``) behind
+    ``SelfAttentionLayer``'s seam; where their gate refuses, or the mode is
+    off, a selected layer runs in XLA in query chunks
+    (``attention_selected``), a refusal counted under
+    ``kernel="flash_select"``. A windowed layer's attention runs under
+    ``jax.named_scope("attn:window")``, so its kernels are told from a full
+    layer's in a profile. Trains; no incremental decode, no
+    sequence-parallel ring.
+
+    Params: ``Wq [F, H D]``, ``Wk, Wv [F, G D]``, ``q_gamma, k_gamma [D]``
+    (with ``qk_norm``), ``Wo [H D, F]``."""
     n_kv_heads: int = 0         # default n_heads
     rope_theta: float = 10000.0
     norm_eps: float = 1e-6
     causal: bool = True
     sequence_parallel: bool = False
+    selected: bool = True
+    window: Optional[int] = None
+    rotate: bool = True
+    qk_norm: bool = True
 
-    N_INPUTS = 2
     supports_kv_cache = False
+
+    @property
+    def N_INPUTS(self) -> int:
+        return 2 if self.selected else 1
 
     def set_n_in(self, in_type: InputType) -> None:
         super().set_n_in(in_type)
@@ -792,6 +807,11 @@ class GroupedQueryAttentionLayer(SelfAttentionLayer):
                 f"GroupedQueryAttentionLayer({self.name!r}): n_heads a "
                 f"multiple of n_kv_heads and an even head_dim, got "
                 f"{self.n_heads}, {self.n_kv_heads}, {self.head_dim}")
+        if self.selected and self.window is not None:
+            raise ValueError(
+                f"GroupedQueryAttentionLayer({self.name!r}): a selection "
+                f"and a window together (attention_selected, the selected "
+                f"path's XLA side, knows no window)")
 
     def set_side_inputs(self, in_types) -> None:
         (sel,) = in_types
@@ -801,36 +821,50 @@ class GroupedQueryAttentionLayer(SelfAttentionLayer):
                 f"[B, T, T] expected as second input, got {sel}")
 
     def param_order(self) -> List[str]:
-        return ["Wq", "Wk", "Wv", "q_gamma", "k_gamma", "Wo"]
+        gains = ["q_gamma", "k_gamma"] if self.qk_norm else []
+        return ["Wq", "Wk", "Wv", *gains, "Wo"]
 
     def init_params(self, rng, dtype=jnp.float32) -> Params:
         F, D = self.n_in, self.head_dim
         HD, GD = self.n_heads * D, self.n_kv_heads * D
         ks = jax.random.split(rng, 4)
-        return {"Wq": self._init_w(ks[0], (F, HD), F, HD, dtype),
-                "Wk": self._init_w(ks[1], (F, GD), F, GD, dtype),
-                "Wv": self._init_w(ks[2], (F, GD), F, GD, dtype),
-                "q_gamma": jnp.ones((D,), dtype),
-                "k_gamma": jnp.ones((D,), dtype),
-                "Wo": self._init_w(ks[3], (HD, F), HD, F, dtype)}
+        p = {"Wq": self._init_w(ks[0], (F, HD), F, HD, dtype),
+             "Wk": self._init_w(ks[1], (F, GD), F, GD, dtype),
+             "Wv": self._init_w(ks[2], (F, GD), F, GD, dtype),
+             "Wo": self._init_w(ks[3], (HD, F), HD, F, dtype)}
+        if self.qk_norm:
+            p.update(q_gamma=jnp.ones((D,), dtype),
+                     k_gamma=jnp.ones((D,), dtype))
+        return p
 
     def apply(self, params, x, *, state, train, rng, mask=None):
-        u, select = x
+        u, select = x if self.selected else (x, None)
         u = self._dropout_input(u, train, rng)
         B, T, _ = u.shape
         H, G, D = self.n_heads, self.n_kv_heads, self.head_dim
         heads = lambda a, n: a.reshape(B, T, n, D).transpose(0, 2, 1, 3)
-        with jax.named_scope("attn:rope"):
-            turned = lambda a, g: rotary(
-                (rms_normalize(a, self.norm_eps)
-                 * params[g].astype(jnp.promote_types(a.dtype, jnp.float32))
-                 ).astype(u.dtype), jnp.arange(T), self.rope_theta)
+
+        def turned(a, g):
+            if self.qk_norm:
+                a = (rms_normalize(a, self.norm_eps)
+                     * params[g].astype(jnp.promote_types(a.dtype,
+                                                          jnp.float32))
+                     ).astype(u.dtype)
+            if self.rotate:
+                a = rotary(a, jnp.arange(T), self.rope_theta)
+            return a
+
+        with (jax.named_scope("attn:rope") if self.rotate or self.qk_norm
+              else contextlib.nullcontext()):
             q = turned(heads(u @ params["Wq"], H), "q_gamma")
             k = turned(heads(u @ params["Wk"], G), "k_gamma")
         v = heads(u @ params["Wv"], G)
         # query head h reads key/value head h // (H / G)
         k, v = (jnp.repeat(a, H // G, axis=1) for a in (k, v))
-        out = self._attend_heads(q, k, v, mask, select=select)
+        with (jax.named_scope("attn:window") if self.window is not None
+              else contextlib.nullcontext()):
+            out = self._attend_heads(q, k, v, mask, window=self.window,
+                                     select=select)
         out = out.transpose(0, 2, 1, 3).reshape(B, T, H * D) @ params["Wo"]
         if mask is not None:
             out = out * mask[..., None]

@@ -120,7 +120,12 @@ class RoutedExpertsLayer(BaseLayerConf):
     with ``E_t`` the ``top_k`` experts of largest ``softmax(W_r u_t)`` over
     all ``n_experts`` and ``g`` their probabilities, renormalised over the
     ``top_k`` chosen (``norm_topk_prob``). No bias, no capacity, no dropped
-    token, no auxiliary loss.
+    token, no auxiliary loss. With ``route_from_side`` the node takes two
+    inputs ``(u, r)`` of one width: the experts read ``u`` and the router
+    reads ``r`` (a router placed ahead of attention reads the block's input
+    to attention, while the experts read the stream after it); under remat
+    the node keeps both, so the rebuild routes from what the forward
+    routed from. With one input the router reads ``u``.
 
     The ``N top_k`` assignments ``(token, expert)`` are sorted by expert (a
     stable sort; those to absent experts sort last), the tokens gathered in
@@ -142,6 +147,11 @@ class RoutedExpertsLayer(BaseLayerConf):
     first: int = 0              # the held range of experts
     count: int = 0              # default: all of them
     norm_topk_prob: bool = True
+    route_from_side: bool = False
+
+    @property
+    def N_INPUTS(self) -> int:
+        return 2 if self.route_from_side else 1
 
     def set_n_in(self, in_type: InputType) -> None:
         self.n_in = (in_type.size if in_type.kind == "rnn"
@@ -157,6 +167,14 @@ class RoutedExpertsLayer(BaseLayerConf):
                 f"RoutedExpertsLayer({self.name!r}): experts {self.first} "
                 f"to {self.first + self.count} of {self.n_experts}, "
                 f"{self.top_k} a token")
+
+    def set_side_inputs(self, in_types) -> None:
+        (r,) = in_types
+        width = r.size if r.kind == "rnn" else r.flat_size()
+        if width != self.n_in:
+            raise ValueError(
+                f"RoutedExpertsLayer({self.name!r}): the router's input "
+                f"must be {self.n_in} wide, as the experts' is; got {r}")
 
     def infer_output_type(self, in_type: InputType) -> InputType:
         return in_type
@@ -177,9 +195,11 @@ class RoutedExpertsLayer(BaseLayerConf):
 
     def apply(self, params, x, *, state, train, rng, mask=None):
         from deeplearning4j_tpu.profiling.metrics import get_registry
+        x, side = x if self.route_from_side else (x, None)
         x = self._dropout_input(x, train, rng)
         shape = x.shape
         u = x.reshape(-1, shape[-1])
+        r = u if side is None else side.reshape(-1, shape[-1])
         N, K, C = u.shape[0], self.top_k, self.count
         act = get_activation(self.activation or "silu")
         get_registry().labeled_counter(
@@ -188,7 +208,7 @@ class RoutedExpertsLayer(BaseLayerConf):
             "products (per trace)").labels(path="ragged_dot").inc()
         with jax.named_scope("moe:route"):
             # the router's product and softmax in float32
-            logits = jnp.dot(u.astype(jnp.float32),
+            logits = jnp.dot(r.astype(jnp.float32),
                              params["W_r"].astype(jnp.float32),
                              precision=jax.lax.Precision.HIGHEST)
             weights, experts = route_top_k(logits, K, self.norm_topk_prob)
