@@ -603,11 +603,12 @@ def selection_threshold_parity(Q, T, topk) -> dict:
 
 
 def routed_experts_parity(N, F, M, E, K, first, count, dtype) -> dict:
-    """The expert layer (sorted assignments, grouped products, one
-    scatter-add) against a loop over its held experts, each run on every
-    token and weighted token by token, in float32: the output and the
-    gradients of the input and the four parameters, the largest error over
-    the largest entry; and no assignment to a held expert dropped."""
+    """The expert layer (sorted assignments, grouped products over chunks
+    of the held rows, gathers back) against a loop over its held experts,
+    each run on every token and weighted token by token, in float32: the
+    output and the gradients of the input and the four parameters, the
+    largest error over the largest entry; no assignment to a held expert
+    dropped; and the chunks the layer ran."""
     import jax
     import jax.numpy as jnp
 
@@ -653,7 +654,8 @@ def routed_experts_parity(N, F, M, E, K, first, count, dtype) -> dict:
     with jax.default_matmul_precision("highest"):
         ref = run(plain, params, u)
         got = run(lambda p, u: kernel(p, u)[0], cast(params), cast(u))
-        assigned = jax.jit(kernel)(cast(params), cast(u))[1]["assigned"]
+        state = jax.jit(kernel)(cast(params), cast(u))[1]
+        assigned = state["assigned"]
         prob = jax.nn.softmax(u @ params["W_r"], axis=-1)
         held = jax.lax.top_k(prob, K)[1]
         want = int(jnp.sum((held >= first) & (held < first + count)))
@@ -662,8 +664,10 @@ def routed_experts_parity(N, F, M, E, K, first, count, dtype) -> dict:
           f"assignments multiplied of {want} made")
     rec = _rel_errors("y du dW_r dW_gate dW_up dW_down", got, ref)
     rec["assigned"] = int(assigned.sum())
+    rec["chunks"] = int(state["chunks"])
     tol = 4e-2 if dtype == "bfloat16" else 1e-3
-    check(max(v for k, v in rec.items() if k != "assigned") < tol,
+    check(max(v for k, v in rec.items() if k not in ("assigned", "chunks"))
+          < tol,
           f"{name}: layer against the loop over experts {rec} (tol "
           f"{tol:.0e} of the largest entry)")
     return rec
@@ -740,8 +744,8 @@ def smallthinker_probe(T, vocab) -> dict:
     the step, no layer on the XLA side of a gate, finite losses; and how
     the tokens route, by layer, with the embedding's rows at the model's
     own 0.02 and scaled to 1.0 (the benchmark's): the largest share of one
-    held expert among the held assignments, and the assignments held of
-    the ``6 T`` made."""
+    held expert among the held assignments, the assignments held of the
+    ``6 T`` made, and the chunks of held rows the layer ran."""
     import jax
 
     from deeplearning4j_tpu.datasets.dataset import DataSet
@@ -769,7 +773,7 @@ def smallthinker_probe(T, vocab) -> dict:
             if "assigned" in state:
                 a = np.asarray(jax.device_get(state["assigned"]))
                 spread[node] = [round(float(a.max() / max(a.sum(), 1)), 3),
-                                int(a.sum())]
+                                int(a.sum()), int(state["chunks"])]
         run["routing"] = spread
         rec[f"embedding_std {std}"] = run
     calls = _lowered_step_text(net, batches[0]).count("tpu_custom_call")

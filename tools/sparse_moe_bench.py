@@ -13,7 +13,11 @@ Parts: ``index`` (the indexer's selection: index scores and top-k),
 ``chip_smoke.py`` P3 compares the selections),
 ``flash`` (the flash kernels with the selection and without), ``experts``
 (the expert layer; and its grouped products at 8,192 rows, what the held
-share needs). The readings land in ``chiprun_out/pr36/sparse_moe_bench.json``.
+share needs), ``experts_st`` (the expert layer at
+``smallthinker_train_seq16k_1chip``'s shape: 16,384 tokens of 2,560, 8
+held of 64 experts of 768, 6 a token, ReLU). Each expert layer prints the
+assignments it held and the chunks of held rows it ran. The readings land
+in ``chiprun_out/pr36/sparse_moe_bench.json``.
 No test and no run of the benchmark calls this.
 """
 
@@ -35,6 +39,10 @@ from deeplearning4j_tpu.ops.topk_threshold import topk_threshold
 
 B, T, F = 1, 8192, 2048
 CALLS = 5
+KEYE = dict(tokens=T, width=F, n_experts=128, top_k=8, count=16, hidden=768,
+            activation="silu")
+SMALLTHINKER = dict(tokens=16384, width=2560, n_experts=64, top_k=6, count=8,
+                    hidden=768, activation="relu")
 
 
 def timed(fn, *args):
@@ -50,12 +58,36 @@ def timed(fn, *args):
             "compile_s": compile_s}
 
 
-def built(layer, key):
-    layer.set_n_in(InputType.recurrent(F, T))
+def built(layer, key, width=F, tokens=T):
+    layer.set_n_in(InputType.recurrent(width, tokens))
     params = layer.init_params(key, jnp.float32)
     return layer, jax.tree.map(lambda a: (0.02 * jax.random.normal(
         key, a.shape)).astype(jnp.bfloat16) if a.ndim > 1 else
         a.astype(jnp.bfloat16), params)
+
+
+def expert_layer(out, name, u, key, tokens, width, n_experts, top_k, count,
+                 hidden, activation):
+    """Time one expert layer forward and forward with backward at a cell's
+    shape, and print how many assignments it held (``assigned``) and how
+    many chunks of held rows it ran (``chunks``, where the layer keeps it)."""
+    moe, moe_p = built(RoutedExpertsLayer(
+        n_experts=n_experts, top_k=top_k, n_hidden=hidden, first=0,
+        count=count, activation=activation), key, width, tokens)
+    fwd = lambda p, x: moe.apply(p, x, state=moe.init_state(), train=True,
+                                 rng=None)
+    both = jax.grad(lambda p, x: fwd(p, x)[0].astype(jnp.float32).sum(),
+                    argnums=(0, 1))
+    out[f"{name}_fwd"] = timed(fwd, moe_p, u)
+    out[f"{name}_bwd"] = timed(both, moe_p, u)
+    state = jax.jit(fwd)(moe_p, u)[1]
+    out[f"{name}_assigned"] = int(state["assigned"].sum())
+    out[f"{name}_chunks"] = (int(state["chunks"]) if "chunks" in state
+                             else None)
+    print(name, "assigned", out[f"{name}_assigned"], "chunks",
+          out[f"{name}_chunks"], out[f"{name}_fwd"], out[f"{name}_bwd"],
+          flush=True)
+    return moe_p, fwd
 
 
 def main(parts):
@@ -99,16 +131,7 @@ def main(parts):
             print(name, out[f"flash_{name}_fwd"], out[f"flash_{name}_bwd"],
                   flush=True)
     if "experts" in parts:
-        moe, moe_p = built(RoutedExpertsLayer(
-            n_experts=128, top_k=8, n_hidden=768, first=0, count=16,
-            activation="silu"), key)
-        fwd = lambda p, x: moe.apply(p, x, state=moe.init_state(),
-                                     train=True, rng=None)
-        both = jax.grad(lambda p, x: fwd(p, x)[0].astype(jnp.float32).sum(),
-                        argnums=(0, 1))
-        out["experts_fwd"] = timed(fwd, moe_p, u)
-        out["experts_bwd"] = timed(both, moe_p, u)
-        print("assigned", fwd(moe_p, u)[1]["assigned"], flush=True)
+        moe_p, fwd = expert_layer(out, "experts", u, key, **KEYE)
         uneven = fwd(moe_p, u)[1]["assigned"]
         for rows in (8192, 16384, 65536):
             x = jax.random.normal(key, (rows, F), jnp.bfloat16)
@@ -123,8 +146,11 @@ def main(parts):
                     lambda x, w, s: jax.lax.ragged_dot(
                         x, w.astype(jnp.bfloat16), s), x,
                     moe_p["W_gate"].astype(jnp.float32), sizes)
-        print({k: v for k, v in out.items() if "experts" in k or "ragged"
-               in k}, flush=True)
+        print({k: v for k, v in out.items() if "ragged" in k}, flush=True)
+    if "experts_st" in parts:
+        T_st, F_st = SMALLTHINKER["tokens"], SMALLTHINKER["width"]
+        u_st = jax.random.normal(key, (B, T_st, F_st), jnp.bfloat16)
+        expert_layer(out, "experts_st", u_st, key, **SMALLTHINKER)
     os.makedirs("chiprun_out/pr36", exist_ok=True)
     with open("chiprun_out/pr36/sparse_moe_bench.json", "w") as f:
         json.dump(out, f, indent=1)
@@ -132,4 +158,4 @@ def main(parts):
 
 
 if __name__ == "__main__":
-    main(sys.argv[1:] or ["index", "topk", "flash", "experts"])
+    main(sys.argv[1:] or ["index", "topk", "flash", "experts", "experts_st"])
