@@ -57,8 +57,8 @@ def assert_a_models_step_keeps_its_flash_pairs(monkeypatch, build, batches,
             net.fit(DataSet(x, y))
             losses.append(np.asarray(net.score_value))
         x, y = batches[0]
-        calls = kernel_calls(
-            net._build_train_step(), net.params, net.opt_state, net.states,
+        calls = kernel_calls(       # the step fit ran: its trace is cached
+            net._train_step_fn, net.params, net.opt_state, net.states,
             {"tokens": jnp.asarray(x)}, {"head": jnp.asarray(y)}, None, None,
             jax.random.PRNGKey(0))
         return calls, losses, jax.device_get(net.params)

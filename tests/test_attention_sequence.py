@@ -96,7 +96,7 @@ def test_ring_attention_gradients_flow():
                                   seq_axis="sp", causal=True, block_size=4)
         return jnp.sum(out ** 2)
 
-    g = jax.grad(loss)(params)
+    g = jax.jit(jax.grad(loss))(params)
     for leaf in jax.tree_util.tree_leaves(g):
         assert np.all(np.isfinite(np.asarray(leaf)))
 

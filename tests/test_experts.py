@@ -79,9 +79,9 @@ def reference_and_grads(w, u, first, count, dtype=jnp.float32):
     with jax.enable_x64(dtype == jnp.float64):
         w, u = jax.tree.map(lambda a: jnp.asarray(a, dtype), (w, u))
         layer = lambda ww, uu: reference_layer(ww, uu, first, count)
-        y = layer(w, u)
-        gw, gu = jax.grad(lambda ww, uu: layer(ww, uu).sum(),
-                          argnums=(0, 1))(w, u)
+        y, (gw, gu) = jax.jit(lambda ww, uu: (layer(ww, uu), jax.grad(
+            lambda ww, uu: layer(ww, uu).sum(), argnums=(0, 1))(ww, uu)))(
+                w, u)
         return jax.tree.map(np.asarray, (y, gw, gu))
 
 
@@ -126,8 +126,8 @@ def test_no_token_is_dropped_under_a_router_that_leans_on_one_expert(
     plain = reference_and_grads(w, u, 0, count)
     exact = reference_and_grads(w, u, 0, count, jnp.float64)
     assert_exact(y, plain[0], exact[0], 2e-5, "y")
-    g, gu = jax.grad(lambda p, u: run(layer, p, u)[0].sum(),
-                     argnums=(0, 1))(share(w, 0, count), u)
+    g, gu = jax.jit(jax.grad(lambda p, u: run(layer, p, u)[0].sum(),
+                             argnums=(0, 1)))(share(w, 0, count), u)
     for leaf in ("W_gate", "W_up", "W_down", "W_r"):
         cut = slice(None) if leaf == "W_r" else slice(count)
         assert_exact(g[leaf], plain[1][f"moe/{leaf}"][cut],
@@ -173,8 +173,9 @@ def test_the_written_out_transposes_are_the_gathers_own():
     assert float(jnp.abs(mine(u, weights, *ws) - plain(u, weights, *ws)
                          ).max()) < 1e-5
     loss = lambda fn: lambda *a: jnp.sum(fn(*a) * cot)
-    got = jax.grad(loss(mine), argnums=(0, 1, 2, 3, 4))(u, weights, *ws)
-    want = jax.grad(loss(plain), argnums=(0, 1, 2, 3, 4))(u, weights, *ws)
+    grads = lambda fn: jax.jit(jax.grad(loss(fn), argnums=(0, 1, 2, 3, 4)))
+    got = grads(mine)(u, weights, *ws)
+    want = grads(plain)(u, weights, *ws)
     for a, b in zip(got, want):
         assert float(jnp.abs(a - b).max()) < 1e-5
 

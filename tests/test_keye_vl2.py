@@ -34,6 +34,7 @@ from deeplearning4j_tpu.nn.layers.attention import (
     attention_selected, rotary, top_keys)
 from deeplearning4j_tpu.profiling import MetricsRegistry, Tracer, set_tracer
 from deeplearning4j_tpu.profiling.metrics import set_registry
+from model_reference import SoundRuns, three_steps
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 V, T, B = 64, 100, 2
@@ -78,27 +79,21 @@ TINY_LIMITS = {"loss1_gap": 2e-6, "loss2_gap": 2e-6, "loss3_gap": 2e-6,
                "delta_norm_gap": 2e-3, "delta_norm_gap_median": 1e-4}
 
 
+@pytest.fixture(scope="module")
+def sound():
+    return SoundRuns(tiny_cfg(), reference, lambda seed: id_batches(3, seed))
+
+
 @pytest.mark.parametrize("seed", [0, 3])
-def test_three_train_steps_follow_the_reference(seed):
+def test_three_train_steps_follow_the_reference(sound, seed):
     cfg = tiny_cfg()
-    weights = reference.make_weights(cfg, seed)
-    start = jax.device_get(weights)
-    net = program.build_net(cfg, weights)
+    start, batches, ref = sound(seed)
+    net, prog = three_steps(cfg, start, batches)
     assert net.conf.training.remat and net.num_params() == sum(
         int(np.prod(s)) for s in reference.param_shapes(cfg).values())
-    batches = id_batches(3, seed)
-    prog = {"losses": []}
-    for i, (x, y) in enumerate(batches):
-        net.fit(DataSet(x, y))
-        prog["losses"].append(float(net.score_value))
-        if i == 0:
-            prog["grad_norm"] = program.leaf_norms(
-                program.first_moment(net.opt_state))
-    after = program.flatten(net.params)
-    prog["delta_norm"] = program.change_norms(after, start)
-    ref = reference.train_steps(cfg, weights, batches)
     # the reference hands back the trained leaves and no others; the
     # program's frozen ones neither moved nor gathered momentum
+    after = program.flatten(net.params)
     frozen = index_leaves(prog["grad_norm"])
     assert len(frozen) == 5 * cfg["num_hidden_layers"]
     assert set(prog["grad_norm"]) - frozen == set(ref["grad_norm"])
@@ -108,15 +103,20 @@ def test_three_train_steps_follow_the_reference(seed):
     ok, compared = compare.decide(compare.training_numbers(prog, ref),
                                   TINY_LIMITS)
     assert ok, compared
-    # the planted faults, the mechanisms' own two among them, and the
-    # control in the precision below come out
-    for planted in (dict(fault="half_batch"), dict(fault="dense"),
-                    dict(fault="raw_weights"), dict(precision="fp8")):
-        bad = reference.train_steps(cfg, reference.make_weights(cfg, seed),
-                                    batches, **planted)
-        ok, compared = compare.decide(compare.training_numbers(bad, ref),
-                                      TINY_LIMITS)
-        assert not ok, (planted, compared)
+
+
+@pytest.mark.parametrize("planted", [
+    dict(fault="half_batch"), dict(fault="dense"), dict(fault="raw_weights"),
+    dict(precision="fp8")], ids=["half_batch", "dense", "raw_weights", "fp8"])
+def test_the_planted_faults_and_the_control_come_out(sound, planted):
+    """The planted faults, the mechanisms' own two among them, and the
+    control in the precision below, each run by the reference from seed 0's
+    weights and batches, fail the check against the sound run under the
+    same limits."""
+    bad = sound.planted(0, **planted)
+    ok, compared = compare.decide(
+        compare.training_numbers(bad, sound(0)[2]), TINY_LIMITS)
+    assert not ok, (planted, compared)
 
 
 def test_loss_and_gradients_agree_by_the_element():
@@ -127,7 +127,8 @@ def test_loss_and_gradients_agree_by_the_element():
     net.fit(DataSet(x, y))
     got = jax.device_get(program.first_moment(net.opt_state))
     frozen = reference.FrozenCfg(cfg)
-    loss, want = jax.value_and_grad(reference.loss_fn)(w, x, y, frozen)
+    loss, want = jax.jit(jax.value_and_grad(reference.loss_fn),
+                         static_argnums=3)(w, x, y, frozen)
     assert abs(float(net.score_value) - float(loss)) < 2e-6 * float(loss)
     for leaf, g in jax.device_get(want).items():
         if "_index/" in leaf:

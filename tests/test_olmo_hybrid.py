@@ -31,6 +31,7 @@ from deeplearning4j_tpu.nn.layers.attention import attention_reference
 from deeplearning4j_tpu.nn.layers.normalization import rms_normalize
 from deeplearning4j_tpu.profiling import MetricsRegistry, Tracer, set_tracer
 from deeplearning4j_tpu.profiling.metrics import set_registry
+from model_reference import SoundRuns, three_steps
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 V, T, B = 64, 100, 2
@@ -65,35 +66,34 @@ TINY_LIMITS = {"loss1_gap": 2e-6, "loss2_gap": 2e-6, "loss3_gap": 2e-6,
                "delta_norm_gap": 2e-3, "delta_norm_gap_median": 1e-4}
 
 
+@pytest.fixture(scope="module")
+def sound():
+    return SoundRuns(tiny_cfg(), reference, lambda seed: id_batches(3, seed))
+
+
 @pytest.mark.parametrize("seed", [0, 3])
-def test_three_train_steps_follow_the_reference(seed):
+def test_three_train_steps_follow_the_reference(sound, seed):
     cfg = tiny_cfg()
-    weights = reference.make_weights(cfg, seed)
-    start = jax.device_get(weights)
-    net = program.build_net(cfg, weights)
+    start, batches, ref = sound(seed)
+    net, prog = three_steps(cfg, start, batches)
     assert net.conf.training.remat and net.num_params() == sum(
         int(np.prod(s)) for s in reference.param_shapes(cfg).values())
-    batches = id_batches(3, seed)
-    prog = {"losses": []}
-    for i, (x, y) in enumerate(batches):
-        net.fit(DataSet(x, y))
-        prog["losses"].append(float(net.score_value))
-        if i == 0:
-            prog["grad_norm"] = program.leaf_norms(
-                program.first_moment(net.opt_state))
-    prog["delta_norm"] = program.change_norms(
-        program.flatten(net.params), start)
-    ref = reference.train_steps(cfg, weights, batches)
     ok, compared = compare.decide(compare.training_numbers(prog, ref),
                                   TINY_LIMITS)
     assert ok, compared
-    # the planted fault and the control in the precision below come out
-    for planted in (dict(fault="half_batch"), dict(precision="fp8")):
-        bad = reference.train_steps(cfg, reference.make_weights(cfg, seed),
-                                    batches, **planted)
-        ok, compared = compare.decide(compare.training_numbers(bad, ref),
-                                      TINY_LIMITS)
-        assert not ok, (planted, compared)
+
+
+@pytest.mark.parametrize("planted", [dict(fault="half_batch"),
+                                     dict(precision="fp8")],
+                         ids=["half_batch", "fp8"])
+def test_the_planted_fault_and_the_control_come_out(sound, planted):
+    """The planted fault and the control in the precision below, each run
+    by the reference from seed 0's weights and batches, fail the check
+    against the sound run under the same limits."""
+    bad = sound.planted(0, **planted)
+    ok, compared = compare.decide(
+        compare.training_numbers(bad, sound(0)[2]), TINY_LIMITS)
+    assert not ok, (planted, compared)
 
 
 def test_the_step_keeps_each_flash_pair_and_is_the_rebuilt_steps_bits(

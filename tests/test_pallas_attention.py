@@ -51,9 +51,9 @@ def _assert_close(got, ref, tol, bf16_tol, err_msg=""):
 
 
 def _grads(fn, cot, *args):
-    return jax.grad(lambda *a: jnp.sum(
+    return jax.jit(jax.grad(lambda *a: jnp.sum(
         fn(*a).astype(jnp.float32) * cot.astype(jnp.float32)),
-        argnums=(0, 1, 2))(*args)
+        argnums=(0, 1, 2)))(*args)
 
 
 @DTYPES
@@ -346,9 +346,11 @@ def test_flash_with_a_selection_is_the_written_out_mask(T, topk):
     heads = lambda a: jnp.repeat(a, H // G, axis=1)
 
     def run(attend):
-        fn = lambda q, k, v: attend(q, heads(k), heads(v))
-        out, vjp = jax.vjp(fn, q, k, v)
-        return (out,) + vjp(cot)
+        def both(q, k, v):
+            fn = lambda q, k, v: attend(q, heads(k), heads(v))
+            out, vjp = jax.vjp(fn, q, k, v)
+            return (out,) + vjp(cot)
+        return jax.jit(both)(q, k, v)
 
     got = run(lambda q, k, v: flash_attention(
         q, k, v, causal=True, interpret=True, select=select))
@@ -366,10 +368,12 @@ def test_a_query_that_keeps_no_key_gives_zeros_and_no_gradient():
                                          interpret=True, select=select)
     out = fn(q, k, v)
     assert not np.asarray(out[:, :, 7]).any()
-    dq, dk, dv = jax.grad(lambda *a: fn(*a).sum(), argnums=(0, 1, 2))(q, k, v)
+    dq, dk, dv = jax.jit(jax.grad(lambda *a: fn(*a).sum(),
+                                  argnums=(0, 1, 2)))(q, k, v)
     assert not np.asarray(dq[:, :, 7]).any()
-    want = jax.grad(lambda *a: _written_out_selection(*a, select).sum(),
-                    argnums=(0, 1, 2))(q, k, v)
+    want = jax.jit(jax.grad(
+        lambda *a: _written_out_selection(*a, select).sum(),
+        argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip((dq, dk, dv), want):
         assert float(jnp.max(jnp.abs(a - b))) < 2e-5
 

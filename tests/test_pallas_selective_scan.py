@@ -2,12 +2,16 @@
 (``ops/pallas_selective_scan.py``), interpreted on the CPU, against the
 recurrence written token by token (``selective_scan_recurrent``) and, beside
 it, the chunked XLA path they stand in for (``selective_scan_chunked``):
-the output and the gradients of all five inputs by the element, at 1,024
-and 2,048 channels of 16 states (one tile of channels and two), a batch of
-two, at lengths of one short block (70 tokens as 80) and of three (300 as
-384), with a stretch of masked tokens in the middle, with ``x`` in
-bfloat16, and with decays that underflow to zero; then the seam: what the
-gate refuses takes the XLA path and counts, and a trace counts one path.
+the output and the gradients of all five inputs by the element, at 128
+and 384 channels of 16 states (one tile of channels and three: the
+kernel's rule walks down from ``BLOCK_D`` to tiles of 128) and at 1,024
+(the production tile, ``BLOCK_D`` whole), a batch of two, at lengths of one
+short block (70 tokens as 80) and of three (300 as 384), with a stretch of
+masked tokens in the middle, with ``x`` in bfloat16, and with decays that
+underflow to zero; then the seam: what the gate refuses takes the XLA path
+and counts, and a trace counts one path. A tile of 128 channels is one
+lane-width of the kernels' loops, so the cases of a kind other than the
+tile's own run at it and cost an eighth of the interpreted program.
 
 Tolerances. Both sides are float32 and take the same steps in the same
 order, so they part by the order of the sums over ``N`` and over channels
@@ -72,14 +76,17 @@ def assert_same(got, want, tol=2e-5, tol_dx=None):
             (name, gap(a, b))
 
 
-@pytest.mark.parametrize("D", [1024, 2048])
-@pytest.mark.parametrize("T", [70, 300])
-def test_kernels_are_the_recurrence_and_the_chunked_path(T, D):
-    """One short block and three, one tile of channels and two, a batch of
-    two: by the element against token by token, and the XLA path beside
-    it."""
+@pytest.mark.parametrize("T,D,tiles", [(70, 128, 1), (70, 384, 3),
+                                        (300, 384, 3), (300, 1024, 1)],
+                         ids=["70-128", "70-384", "300-384", "300-1024"])
+def test_kernels_are_the_recurrence_and_the_chunked_path(T, D, tiles):
+    """One short block and three, one tile of channels and three, a batch
+    of two, and the production tile of ``BLOCK_D`` channels over three
+    blocks of ``BLOCK_T``: by the element against token by token, and the
+    XLA path beside it."""
     args = inputs(T, D)
     assert pss.selective_scan_ok(T, D, N, jnp.float32, jnp.float32)
+    assert D // pss.channel_block(D) == tiles
     got = with_gradients(kernels, args)
     assert got[0].dtype == jnp.float32 and got[1].dtype == jnp.float32
     assert_same(got, with_gradients(selective_scan_recurrent, args))
@@ -90,7 +97,7 @@ def test_a_masked_stretch_passes_the_state_unchanged():
     """``Delta = 0`` over 90 tokens across a block's edge: the tokens after
     the stretch read what they would read with the stretch cut out, and the
     masked tokens' ``x``, ``B`` get no gradient."""
-    T, D, lo, hi = 300, 1024, 100, 190
+    T, D, lo, hi = 300, 128, 100, 190
     x, delta, a, b, c = inputs(T, D)
     delta = delta.at[:, lo:hi].set(0.0)
     args = (x, delta, a, b, c)
@@ -104,7 +111,7 @@ def test_a_masked_stretch_passes_the_state_unchanged():
 
 
 def test_x_in_bfloat16_is_widened_and_its_cotangent_narrowed():
-    args = inputs(300, 1024, x_dtype="bfloat16")
+    args = inputs(300, 128, x_dtype="bfloat16")
     got = with_gradients(kernels, args)
     assert got[0].dtype == jnp.float32 and got[1].dtype == jnp.bfloat16
     assert_same(got, with_gradients(selective_scan_recurrent, args),
@@ -115,7 +122,7 @@ def test_decays_that_underflow_harm_nothing():
     """``Delta A`` of -60 to -240 a token: decays underflow to zero (and
     their inverses would overflow), and every number stays finite and the
     recurrence's."""
-    args = inputs(300, 1024, decay="steep")
+    args = inputs(300, 128, decay="steep")
     assert float(jnp.min(jnp.exp(args[1][..., None, :] * args[2]))) == 0.0
     assert_same(with_gradients(kernels, args),
                 with_gradients(selective_scan_recurrent, args))
@@ -134,7 +141,7 @@ def _counters(monkeypatch, mode, fn, *args):
 
 
 def test_a_trace_counts_one_path(monkeypatch):
-    args = inputs(70, 1024)
+    args = inputs(70, 128)
     for mode, path, other in (("interpret", "kernel", "xla"),
                               ("off", "xla", "kernel")):
         fn = jax.jit(functools.partial(selective_scan))     # a trace a mode
@@ -147,7 +154,7 @@ def test_a_trace_counts_one_path(monkeypatch):
 @pytest.mark.parametrize("refused", ["float64", "d_in_96", "states_4"])
 def test_what_the_gate_refuses_takes_the_xla_path_and_counts(monkeypatch,
                                                              refused):
-    D = 96 if refused == "d_in_96" else 1024
+    D = 96 if refused == "d_in_96" else 128
     args = inputs(70, D)
     if refused == "states_4":
         args = (args[0], args[1], args[2][:4], args[3][..., :4],
@@ -227,7 +234,7 @@ def test_the_kernels_rule_waits_for_the_cotangent(monkeypatch):
     """At the seam the kernels' own rule stands between the two barriers
     of ``nn/remat`` (``ops/`` holds none of its own)."""
     monkeypatch.setenv("DL4J_TPU_PALLAS", "interpret")
-    args = inputs(70, 1024)
+    args = inputs(70, 128)
     loss = lambda *a: jnp.sum(selective_scan(*a))
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
         *args).as_text()

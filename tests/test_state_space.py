@@ -41,11 +41,12 @@ def scan_inputs(T, decay, dtype, seed=0, B=2):
 
 
 def with_gradients(fn, args, seed=1):
+    """``fn(*args)`` and the gradient of all its inputs, in one program."""
     cot = jnp.asarray(np.random.default_rng(seed).normal(
         size=args[0].shape), args[1].dtype)
     loss = lambda *a: jnp.sum(fn(*a) * cot)
-    return (fn(*args), *jax.grad(loss, argnums=tuple(range(len(args))))(
-        *args))
+    grads = jax.grad(loss, argnums=tuple(range(len(args))))
+    return jax.jit(lambda *a: (fn(*a), *grads(*a)))(*args)
 
 
 def rel(a, b):
@@ -128,9 +129,9 @@ def test_the_scan_layer_follows_the_reference_in_every_parameter(T, dtype):
             return reference.scan_output(w, "s", u, cfg, same, same)
 
         for fn in (program, plain):
-            out = fn(params, u)
-            grads = jax.grad(lambda p, u: jnp.sum(fn(p, u) * cot),
-                             argnums=(0, 1))(params, u)
+            out, grads = jax.jit(lambda p, u: (fn(p, u), jax.grad(
+                lambda p, u: jnp.sum(fn(p, u) * cot), argnums=(0, 1))(
+                    p, u)))(params, u)
             if fn is program:
                 got = (out, grads)
         assert rel(got[0], out) < TOL[dtype]

@@ -59,7 +59,8 @@ def test_windowed_flash_is_the_written_out_mask(T, window):
     def run(attend):
         fn = lambda q, k, v: attend(q, heads(k), heads(v))
         loss = lambda *a: jnp.sum(fn(*a) * cot)
-        return (fn(q, k, v), *jax.grad(loss, argnums=(0, 1, 2))(q, k, v))
+        return jax.jit(lambda *a: (fn(*a), *jax.grad(
+            loss, argnums=(0, 1, 2))(*a)))(q, k, v)
 
     got = run(lambda q, k, v: flash_attention(
         q, k, v, causal=True, window=window, interpret=True))
@@ -159,9 +160,9 @@ def test_differential_attention_is_the_references_two_dense_softmaxes(
 
     results = []
     for fn in (program, plain):
-        grads = jax.grad(lambda *a: jnp.sum(fn(*a) * cot),
-                         argnums=(0, 1, 2))(params, kv_params, u)
-        results.append((fn(params, kv_params, u), grads))
+        results.append(jax.jit(lambda *a: (fn(*a), jax.grad(
+            lambda *a: jnp.sum(fn(*a) * cot), argnums=(0, 1, 2))(*a)))(
+                params, kv_params, u))
     (out, grads), (want, want_grads) = results
     assert rel(out, want) < 2e-5
     flat = lambda g: {**{f"a/{k}": v for k, v in g[0].items()},
